@@ -2,7 +2,7 @@
 full coarse EM, sharded vs single CAGRA builds, and the distributed-build
 overhead control — one artifact, one renderer.
 
-The Round-6 study (BASELINE.md "Round-6 distributed-build overhead") named
+The Round-6 study (r06 distributed-build overhead) named
 the balanced coarse trainer's ~22 full-dataset assignment passes as the
 dominant IVF build cost (+187% warm at 1M distributed, 50.3-51.3 s of the 1M
 single-chip build). This driver measures the r07 remedies:
@@ -27,7 +27,7 @@ control):
 Render the BASELINE follow-up table FROM the artifact (stdlib only — no
 prose drift; the numbers in the doc ARE the artifact's):
 
-    python bench/build_ab.py --table BUILD_AB_r07.json >> BASELINE.md
+    python bench/build_ab.py --table BUILD_AB_r07.json
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """CAGRA graph-blocked layout experiment (VERDICT r2 #6).
 
-Hypothesis (BASELINE.md r02): hops are latency-bound row gathers; reordering
+Hypothesis (r02): hops are latency-bound row gathers; reordering
 dataset rows so graph neighbors fall in shared blocks (coarse-cluster order)
 turns the per-hop (m, width*deg) row gather into a friendlier DMA pattern.
 

@@ -1,6 +1,6 @@
 """BENCH artifact regression gate: diff two BENCH_rXX.json files.
 
-The bench trajectory (BENCH_r01..r05, and every round after) has so far
+The bench trajectory (BENCH_r03..r05, and every round after) has so far
 been compared by eye; this is the tooling: per-row QPS and recall diffs
 with tolerances, a non-zero exit on regression (CI-gateable), and a
 stdlib ``--table`` renderer for round notes.

@@ -2,7 +2,7 @@
 """BASELINE config 2: k-means fit on make_blobs(1M x 128), k=1024, one chip.
 
 Counterpart of the reference's cluster bench (cpp/bench/prims/cluster/kmeans.cu)
-at the BASELINE.md table-2 operating point. Reports fit wall time (excluding
+at the 1M x 128, k=1024 operating point. Reports fit wall time (excluding
 the first-call compile, which is timed separately), per-iteration time, and
 inertia parity against the generating blob centers (the inertia of labeling
 every point by its true generator is the achievable floor; a correct Lloyd

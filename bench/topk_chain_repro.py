@@ -4,7 +4,7 @@ failure (VERDICT r5 weak #1 / next #3).
 History: chaining TWO wide-k (k > 128 → kh=256) topk_pallas instances inside
 ONE XLA program hit "TPU backend error (Internal)" on the r05 toolchain,
 while every standalone call — and kh=128 chains 4-deep — compiled fine
-(BASELINE.md "Round-5 wide-k selector study"). The r05 kernel's one
+(r05 wide-k selector study). The r05 kernel's one
 structural feature unique to kh=256 was its 2*kh = 512-lane merge
 intermediates; r06 reformulated the merge to cap every intermediate at kh
 lanes (ops/topk.py wide_merge="half") and lifted the select_k dispatch to

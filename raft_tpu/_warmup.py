@@ -6,7 +6,7 @@ explicit-instantiation machinery, SURVEY.md R1/R2; compile-mode matrix
 device-code compilation. The TPU analogue is the persistent XLA compilation
 cache (``config.enable_compilation_cache``) — but the cache only helps a
 *second* process; a fresh host still pays minutes of cold jit on the flagship
-path (1M ivf_pq: 103.6 s cold vs 7.3 s warm, BASELINE.md r04 harness).
+path (1M ivf_pq: 103.6 s cold vs 7.3 s warm, r04 harness).
 
 ``warmup`` closes that first-touch gap: run it once per host — at deploy
 time, in a provisioning step, off the serving path — with the shapes you will
@@ -82,9 +82,9 @@ def warm_buckets(searcher, *, dim: int, buckets, k: int = 10,
     bucket queries from real data instead of uniform noise. Compilation
     does not depend on VALUES, so any sample warms the same programs — but
     data-dependent execution time does (CAGRA's hop loop runs ~3.7x longer
-    on uniform data than clustered, BASELINE.md "Round-6 warmup data
-    sample"), so a sample makes publish-time warms cheaper and their
-    reported walls representative.
+    on uniform data than clustered, r06 warmup data-sample study), so a
+    sample makes publish-time warms cheaper and their reported walls
+    representative.
 
     Returns ``{bucket: {wall_s, compile_s, trace_s, programs, cache_hits,
     cache_misses}}`` via the obs compile-attribution subscription — all-warm
@@ -130,11 +130,13 @@ def warmup(kind: str, n: int, d: int, *, k: int = 10, queries: int = 10_000,
            seed: int = 0) -> dict:
     """Compile-warm one index kind at (n, d) build / (queries, d) search.
 
-    Enables the persistent compilation cache (``cache_dir`` or the default
-    ``~/.cache/raft_tpu/jit``), builds the index on uniform random data of
-    the target shape, runs one search of the target batch shape, and returns
-    headline walls (``build_s``/``search_s``/``cache_dir``) plus per-phase
-    compile attribution (see below). Pass the same
+    Enables the persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``cache_dir`` or ``<checkout>/.jax_cache`` — see
+    :func:`raft_tpu.config.enable_compilation_cache`), builds the index on
+    uniform random data of the target shape, runs one search of the target
+    batch shape, and returns headline walls
+    (``build_s``/``search_s``/``cache_dir``) plus per-phase compile
+    attribution (see below). Pass the same
     ``index_params``/``search_params`` you will use in production — the
     cache keys on static config (n_lists, pq_dim, itopk, ...), so a warmup
     with different params warms different programs. The same holds for
@@ -163,10 +165,8 @@ def warmup(kind: str, n: int, d: int, *, k: int = 10, queries: int = 10_000,
     each carry ``{wall_s, compile_s, trace_s, programs, cache_hits,
     cache_misses, program_compile_s}`` — ``program_compile_s`` is the
     per-program backend-compile seconds, and the cache counters say whether
-    this warmup paid cold compiles or found the cache already hot. On a jax
-    without the monitoring bus the split falls back to a cold-vs-warm wall
-    delta for the search (``attribution: "timing"``) and the per-program
-    detail is empty. A summary INFO line goes through the raft_tpu logger
+    this warmup paid cold compiles or found the cache already hot. A
+    summary INFO line goes through the raft_tpu logger
     (``core.logger.basic_config`` formats it to stderr in one call).
     """
     import jax
@@ -260,23 +260,10 @@ def warmup(kind: str, n: int, d: int, *, k: int = 10, queries: int = 10_000,
             "program_compile_s": [round(s, 3) for s in rec.program_compile_s],
         }
 
-    attribution = "jax.monitoring"
-    if not search_attr.available:  # pragma: no cover - ancient jax
-        # timing fallback (ops/_compat.jax_monitoring gate): a second,
-        # fully warm search bounds execute time; the cold-warm delta is the
-        # compile share of the first. Cache outcomes stay unknown (-1).
-        attribution = "timing"
-        t0 = time.perf_counter()
-        jax.block_until_ready(jax.tree_util.tree_leaves(searcher())[0])
-        warm_s = time.perf_counter() - t0
-        search_attr.compile_s = max(search_s - warm_s, 0.0)
-        search_attr.cache_hits = search_attr.cache_misses = -1
-        build_attr.cache_hits = build_attr.cache_misses = -1
-
     out = {
         # headline walls keep their historical keys (provisioning scripts)
         "build_s": round(build_s, 2), "search_s": round(search_s, 2),
-        "cache_dir": cache, "attribution": attribution,
+        "cache_dir": cache, "attribution": "jax.monitoring",
         "build": _phase(build_s, build_attr),
         "search": _phase(search_s, search_attr),
     }

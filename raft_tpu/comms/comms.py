@@ -189,18 +189,9 @@ class Comms:
     # -- host-side helpers --------------------------------------------------
     def shard_map(self, fn, in_specs, out_specs, check_vma: bool = False):
         """Enter the SPMD region this communicator's collectives live in."""
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(
-                fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma
-            )
-        # pre-0.6 jax: shard_map lives in jax.experimental and the
-        # replication-check knob is spelled check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma
+            check_vma=check_vma
         )
 
     def sync_stream(self, *arrays):

@@ -13,6 +13,7 @@ is importable), or any callable ``jax.Array -> Any``.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Any, Callable
 
 import jax
@@ -22,30 +23,38 @@ __all__ = ["set_output_as", "get_output_as", "auto_convert_output",
 
 _output_as: str | Callable = "jax"
 
+# the compile cache's one fixed home when JAX_COMPILATION_CACHE_DIR is unset
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
 
 def enable_compilation_cache(path: str | None = None) -> str:
     """Persist XLA compilations across processes (the warm-build story).
 
-    1M-scale index builds are dominated by cold-jit compilation (IVF-Flat
-    ~120 s, CAGRA ~320 s cold vs seconds warm — BASELINE.md); the reference
-    avoids this class of cost with ahead-of-time compiled kernels in libraft
-    (SURVEY.md R1/R2 explicit instantiations). The TPU analogue is JAX's
-    persistent compilation cache: with it enabled, a second process rebuilding
-    or re-searching the same shapes skips compilation entirely. Combine with
+    1M-scale index builds are dominated by cold-jit compilation; JAX's
+    persistent compilation cache lets a second process rebuilding or
+    re-searching the same shapes skip it. Combine with
     ``neighbors.*.save``/``load`` so repeat users pay neither compile nor
     build cost.
 
-    Returns the cache directory in effect (default
-    ``~/.cache/raft_tpu/jit``).
+    Placement: when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+    it and nothing here sets a directory (``path`` is ignored) — whoever
+    runs the process decides where the cache lives. Otherwise the cache
+    goes to ``path`` or, by default, to ``<checkout>/.jax_cache``: one
+    fixed path, because the path is part of what makes a later process
+    find the entries again.
+
+    Returns the cache directory in effect.
     """
-    import os
+    from jax.experimental.compilation_cache import compilation_cache
 
-    import jax
-
-    path = path or os.path.join(
-        os.path.expanduser("~"), ".cache", "raft_tpu", "jit")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = env
+    else:
+        path = path or DEFAULT_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache every entry, however small/fast — index pipelines are many
     # medium-sized programs, and the defaults skip anything that compiles
     # in under a second
@@ -54,12 +63,7 @@ def enable_compilation_cache(path: str | None = None) -> str:
     # jax latches "cache unused" once per task on the FIRST compile: if
     # anything compiled before this call, the new dir would silently never
     # be consulted. Reset the latch so enabling mid-process takes effect.
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - private API drift: best effort
-        pass
+    compilation_cache.reset_cache()
     from .core.logger import logger
 
     logger.info("persistent compilation cache enabled at %s", path)

@@ -194,8 +194,8 @@ def select_k(values, k: int, select_min: bool = True, indices=None):
     # silently misranked vs the exact lax.top_k path.
     # k <= 64 is the r05-measured narrow path; 64 < k <= 256 is the
     # bitonic-merge wide path (ops/topk.py) — 3.06x lax.top_k at (10k, 65k)
-    # k=128, 1.5-1.7x at k=193/256 in-process (BASELINE.md "Round-5 wide-k
-    # selector study"). r05 capped dispatch at 128 (two kh=256 instances per
+    # k=128, 1.5-1.7x at k=193/256 in-process (r05 wide-k selector
+    # study). r05 capped dispatch at 128 (two kh=256 instances per
     # program hit a Mosaic error); r06's half-width merge lifts the cap to
     # the kernel's full 256 (SELECT_K_DISPATCH_MAX_K above has the history
     # and the RAFT_TPU_WIDE_SELECT_CAP escape hatch).

@@ -77,7 +77,7 @@ def split_oversized(labels, n_lists: int, cap_target: int, order_key=None):
     differentiated coarse scores and queries probe only nearby slabs — the
     fix for Zipf-population data, where an order-split mega-cluster
     scattered every query's neighbors uniformly over ~population/cap
-    identical-score sub-lists (BASELINE.md "Round-5 heavytail family").
+    identical-score sub-lists (r05 heavytail family).
 
     Returns ``(new_labels (n,), rep (n_lists,) host int array)`` where
     ``rep[l]`` is how many sub-lists list ``l`` became (all 1 = no change);

@@ -128,8 +128,8 @@ class IndexParams:
     # 16-entry codebooks. False: the reference's joint 256-entry codebook
     # (ivf_pq_compute_similarity's LUT), ~8x slower to scan on TPU but a
     # finer quantizer. None (default) = metric-aware auto: split for L2
-    # (measured ~12% relative bare-recall cost for a 8x QPS gain,
-    # BASELINE.md), joint for inner_product (the Minkowski coarseness costs
+    # (measured ~12% relative bare-recall cost for a 8x QPS gain),
+    # joint for inner_product (the Minkowski coarseness costs
     # IP ranking far more — measured recall@5 0.375 joint vs 0.075 split on
     # tight clusters at 4x compression).
     pq8_split: bool | None = None
@@ -140,9 +140,9 @@ class IndexParams:
     # family: lognormal per-cluster residual scales) makes ONE codebook span
     # orders of magnitude of residual norm, so the codewords concentrate on
     # the large-scale clusters and small-scale lists quantize to mush
-    # (measured collapse: 0.28 recall @ 1M, BASELINE.md "Round-5 heavytail
-    # family"). True: store one f32 scale per list (RMS residual norm of the
-    # training members; global RMS for lists the trainset missed), train the
+    # (measured collapse: 0.28 recall @ 1M, r05 heavytail family). True:
+    # store one f32 scale per list (RMS residual norm of the training
+    # members; global RMS for lists the trainset missed), train the
     # codebooks on UNIT-scale residuals, encode r/s_list, and fold s back in
     # at search inside the LUT (s^2 for L2, s for IP) — exact scoring of
     # ||r - s*decode||^2, ~zero scan-time cost (the fold is one multiply on
@@ -205,7 +205,7 @@ class SearchParams:
     #              (the hardware LUT16 shuffle) + MXU lane reduction. TPU
     #              (or interpret) only; 16-wide stages (pq4 / split pq8).
     #              Measured 0.73x the onehot path at 1M — issue-overhead
-    #              bound (BASELINE.md "Round-4 PQ scan study") — kept as an
+    #              bound (r04 PQ scan study) — kept as an
     #              option and as the starting point for a grouped scan.
     #   "select" — the compare+select chain left to XLA: 0.55x onehot at 1M
     #              (XLA materializes each of the 16 passes); reference impl.
@@ -222,7 +222,7 @@ class SearchParams:
     #               tiled path's 41.2k QPS, ~61 pairs/list): the 4x operand
     #               -traffic cut bought nothing, i.e. the tiled contraction
     #               was never operand-bound — XLA fuses the one-hot producer
-    #               into the dot (BASELINE.md "Round-4 grouped scan"). Kept
+    #               into the dot (r04 grouped scan). Kept
     #               as a tested option; the balance may flip at higher
     #               pairs-per-list ratios or future XLA versions.
     #   "auto"    — tiled (measured at least as fast everywhere tried).
@@ -1215,7 +1215,7 @@ def resolve_scan_impl(params: SearchParams, index: IvfPqIndex, n_codes: int) -> 
     narrow_stages = index.pq_split or n_codes <= 16
     if scan_impl == "auto":
         # the one-hot MXU contraction everywhere: the r04 kernel study
-        # (BASELINE.md "Round-4 PQ scan study") measured every alternative
+        # (r04 PQ scan study) measured every alternative
         # slower at 1M — XLA "select" chain 0.55x, Pallas compare+select
         # 0.7x, Pallas tpu.dynamic_gather (the hardware LUT16) 0.73x — the
         # one-hot path fuses gather+compare+cast into the contraction and
@@ -1997,7 +1997,7 @@ def _pq_search_grouped(index: IvfPqIndex, queries, n_probes: int, k: int,
                        metric: DistanceType, codebook_kind: str,
                        lut_dtype: str, keep_mask=None, group_size: int = 16,
                        group_chunk: int = 32, select_impl: str = "auto"):
-    """Probe-major grouped scan (r04, BASELINE.md "Round-4 PQ scan study"):
+    """Probe-major grouped scan (r04, r04 PQ scan study):
     the per-(query, probe) one-hot contraction is an N=1 batched matvec that
     rebuilds a (cap, pq_dim*K) one-hot operand per pair. Here the (query,
     probe) pairs of the WHOLE batch are sorted by list id and split into
@@ -2258,7 +2258,7 @@ def search(params: SearchParams, index: IvfPqIndex, queries, k: int,
     if scan_order == "auto":
         # tiled: the grouped order measured neutral at 1M (its 4x operand
         # -traffic cut bought nothing — the tiled one-hot contraction is not
-        # operand-bound; BASELINE.md "Round-4 grouped scan")
+        # operand-bound; r04 grouped scan)
         scan_order = "tiled"
     if use_funnel:
         expects(scan_order == "tiled",

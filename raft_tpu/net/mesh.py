@@ -106,10 +106,19 @@ class MeshSpec:
 def _worker_main(conn, spec: dict) -> None:
     """Worker process entry (spawn target). Boots a shard replica:
     build → wrap with global ids → publish (the warm ladder) → settle →
-    open the compile-attribution window → serve. Reports ``{"port": p}``
-    (or ``{"error": tb}``) over the pipe, then blocks on it for stop."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    open the compile-attribution window → serve. Reports ``{"port": p,
+    "platform": ...}`` (or ``{"error": tb}``) over the pipe, then blocks on
+    it for stop.
+
+    Workers run on the CPU by design: the router's process may hold the
+    chip, and a chip serves one process. The platform rides the handshake
+    and ``stats`` so no reader takes a worker's number for a chip's."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+        platform = jax.devices()[0].platform
         from ..neighbors import brute_force
         from ..obs import compile as obs_compile
         from ..obs.requestlog import RequestLog
@@ -133,10 +142,12 @@ def _worker_main(conn, spec: dict) -> None:
         with obs_compile.attribution() as rec:
             srv = NetServer(svc, host=spec["host"], request_log=rlog,
                             stats=lambda: {"pid": os.getpid(),
+                                           "platform": platform,
                                            "compile_s": rec.compile_s,
                                            "cache_misses": rec.cache_misses,
                                            "rows": int(rows.shape[0])})
-            conn.send({"port": srv.port, "pid": os.getpid()})
+            conn.send({"port": srv.port, "pid": os.getpid(),
+                       "platform": platform})
             try:
                 conn.recv()  # stop signal (or EOF when the router died)
             except EOFError:
@@ -158,6 +169,7 @@ class _Worker:
     proc: object
     conn: object
     port: int = 0
+    platform: str = ""
     client: NetClient | None = None
     # breaker state (router-side; guarded by the mesh lock)
     fails: int = 0
@@ -230,6 +242,7 @@ class ProcessMesh:
                     raise RaftError(f"worker {w.label} failed to boot:\n"
                                     f"{msg['error']}")
                 w.port = int(msg["port"])
+                w.platform = msg["platform"]
                 w.client = NetClient(
                     f"http://{self.spec.host}:{w.port}")
         self._pool = ThreadPoolExecutor(
@@ -447,8 +460,11 @@ class ProcessMesh:
     def stats(self) -> dict:
         """Fleet-summed worker stats — ``compile_s``/``cache_misses``
         across every live worker is the zero-cold-compile proof for the
-        whole wire path. Fenced/dead workers are skipped (and listed)."""
+        whole wire path. Fenced/dead workers are skipped (and listed).
+        ``platforms`` lists the workers' jax platforms (``["cpu"]``)."""
         total = {"compile_s": 0.0, "cache_misses": 0, "workers": 0,
+                 "platforms": sorted({w.platform for g in self._workers
+                                      for w in g}),
                  "unreachable": []}
         for group in self._workers:
             for w in group:

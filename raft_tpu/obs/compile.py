@@ -1,7 +1,7 @@
 """Compile attribution: split every wall-clock second into compile vs execute.
 
 The two costs that dominate real deployments are invisible to a stopwatch:
-cold-jit compilation (1M ivf_pq: 103.6 s cold vs 7.3 s warm, BASELINE.md) and
+cold-jit compilation (1M ivf_pq: 103.6 s cold vs 7.3 s warm) and
 persistent-cache outcomes. jax's ``jax.monitoring`` event bus reports exactly
 these — per-program trace/lower/compile durations and compilation-cache
 hit/miss events — so this module subscribes ONCE (process-global, idempotent)
@@ -14,9 +14,6 @@ and fans the events into two sinks:
   :class:`CompileRecord` for one region of caller code (``_warmup`` and the
   instrumented entry points use this to report per-call compile seconds).
 
-Older jax without the monitoring bus: :func:`install` returns False,
-``attribution()`` yields a record with ``available=False``, and callers fall
-back to wall-time deltas (``ops/_compat.jax_monitoring`` is the gate).
 """
 
 from __future__ import annotations
@@ -27,7 +24,7 @@ import threading
 
 from . import metrics
 
-__all__ = ["install", "installed", "attribution", "CompileRecord"]
+__all__ = ["install", "attribution", "CompileRecord"]
 
 # jax event names -> our stage label (dispatch.py:60-62). "compile" is the
 # backend (XLA) compile — the cost the persistent cache saves; trace/lower are
@@ -46,7 +43,6 @@ _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 
 _lock = threading.Lock()
 _installed = False
-_available = False
 _scopes: list["CompileRecord"] = []
 
 
@@ -59,7 +55,6 @@ class CompileRecord:
     identical contents (e.g. two all-warm regions) remove each other's
     entries."""
 
-    available: bool = True
     trace_s: float = 0.0
     lower_s: float = 0.0
     compile_s: float = 0.0  # backend-compile seconds (sum over programs)
@@ -130,31 +125,21 @@ def _on_event(event: str, **kw) -> None:
             rec.cache_misses += 1
 
 
-def install() -> bool:
+def install() -> None:
     """Subscribe to jax's monitoring bus (idempotent; one registration per
     process — jax offers no unregister outside tests, so listeners stay for
-    the process lifetime and gate on ``metrics.enabled()``). Returns whether
-    event-based attribution is live."""
-    global _installed, _available
-    from ..ops._compat import jax_monitoring
+    the process lifetime and gate on ``metrics.enabled()``)."""
+    global _installed
+    from jax import monitoring
 
     # registration happens INSIDE the lock so a concurrent first caller
-    # cannot observe _installed=True with the listeners (and _available)
-    # not yet in place; registering invokes nothing, so no deadlock risk
+    # cannot observe _installed=True with the listeners not yet in place;
+    # registering invokes nothing, so no deadlock risk
     with _lock:
-        if _installed:
-            return _available
-        mon = jax_monitoring()
-        if mon is not None:
-            mon.register_event_duration_secs_listener(_on_duration)
-            mon.register_event_listener(_on_event)
-            _available = True
-        _installed = True
-        return _available
-
-
-def installed() -> bool:
-    return _installed and _available
+        if not _installed:
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            _installed = True
 
 
 @contextlib.contextmanager
@@ -170,8 +155,8 @@ def attribution():
     thread, so cross-thread noise only appears if the caller runs concurrent
     jit compiles, in which case attribute at a coarser scope.
     """
-    ok = install()
-    rec = CompileRecord(available=ok)
+    install()
+    rec = CompileRecord()
     with _lock:
         _scopes.append(rec)
     try:

@@ -70,8 +70,7 @@ def instrument(op: str, items=None, labels=None):
             except Exception:
                 lbls = {}
             _call_seconds().observe(dt, op=op, **lbls)
-            if rec.available:
-                _call_compile_seconds().observe(rec.compile_s, op=op, **lbls)
+            _call_compile_seconds().observe(rec.compile_s, op=op, **lbls)
             if items is not None:
                 try:
                     _items_total().inc(int(items(args, kwargs)), op=op)

@@ -1,6 +1,6 @@
 """Fused CAGRA hop kernel: the whole per-hop beam update in ONE Pallas pass.
 
-The r04 hop study (BASELINE.md "Round-4 CAGRA hop study" + addendum)
+The r04 hop study (r04 CAGRA hop study + addendum)
 decomposed the 1M batch-synchronous search into ~0.27 us/query/hop of
 expansion scoring (the vector gather — which XLA's gather engine serves at
 ~60 GB/s effective on overlapping beam frontiers, 15x the isolated per-row
@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["cagra_hop", "hop_backend_ok", "hop_shapes_eligible"]
 
@@ -376,7 +375,7 @@ def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
             pltpu.VMEM((qt, _POOL), jnp.int32),     # merge pool visited
             pltpu.SMEM((1,), jnp.int32),            # arena insertion gate
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(*args)

@@ -24,7 +24,7 @@ and bf16-mode operands are cast OUTSIDE the kernel (half the DMA bytes, no
 per-block VPU cast).
 
 Profiling notes (v5e, 100k x 128, k=10, 10k-query batches; details and
-QPS-with-controls in BASELINE.md "Round-3 fused-kernel engineering notes"):
+QPS-with-controls in r03 fused-kernel engineering notes):
 - the kernel is VPU-extraction-bound, not MXU-bound: k=1 runs 3.3x faster
   than k=10, while a 6x MXU-cost swing (f32 HIGHEST vs bf16) moves QPS ~20%;
 - three redesigns measured and REJECTED, kept here as negative results:
@@ -74,7 +74,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["fused_knn", "FUSED_KNN_MAX_K"]
 
@@ -258,7 +257,7 @@ def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
             pltpu.VMEM((qt, 128), jnp.int32),       # block candidates (ids)
             pltpu.SMEM((1,), jnp.int32),            # extraction gate
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(qs, ds, ynp)

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["pq_lut_scan", "pq_scan_backend_ok"]
 
@@ -142,7 +141,7 @@ def _pq_scan_impl(codes_hi, codes_lo, lut, bt: int, capb: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((pack, Bp, capp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((capb, lanes), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(*([ch, cl, lp] if split else [ch, lp]))

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["topk_pallas", "TOPK_MAX_K"]
 
@@ -264,7 +263,7 @@ def topk_pallas(x, k: int, select_min: bool = True, blk: int = 4096,
             pltpu.VMEM((qt, w), jnp.int32),
             pltpu.SMEM((2,), jnp.int32),            # extraction + merge gates
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(x)

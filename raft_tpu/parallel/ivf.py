@@ -263,7 +263,7 @@ def search_pq(comms: Comms, params, index, queries, k: int,
             "lut_dtype must be 'float32', 'bfloat16' or 'int8', got %r",
             params.lut_dtype)
     # same validation + resolution as the single-chip search (auto = the
-    # one-hot contraction, fastest measured — BASELINE.md r04 scan study),
+    # one-hot contraction, fastest measured — r04 scan study),
     # and the same clear error for a pq_split index missing its cross terms
     from ..neighbors.ivf_pq import _check_split_consts, resolve_scan_impl
 

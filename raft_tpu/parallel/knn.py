@@ -44,7 +44,7 @@ def _knn_fn(comms: Comms, k: int, mt: DistanceType, metric_arg: float,
     """Memoized jitted program per static config. The drivers used to build
     a fresh closure + jax.jit wrapper on every call, which forced a full
     retrace per search — measured as a 38-45% driver overhead on a 1-device
-    mesh (BASELINE.md "Round-5 parallel-driver overhead"); with the program
+    mesh (r05 parallel-driver overhead); with the program
     cached the overhead is the collectives' true cost."""
     key = (comms, k, mt, metric_arg, tile, inner_tile, compute, use_fused,
            shard_rows, has_keep)

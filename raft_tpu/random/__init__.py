@@ -3,7 +3,8 @@
 Reference: cpp/include/raft/random/ (L3, P9).
 """
 
-from .datagen import make_blobs, make_regression, multi_variable_gaussian
+from .datagen import (make_blobs, make_regression, make_sift_like,
+                      multi_variable_gaussian)
 from .rmat import rmat, rmat_rectangular_gen
 from .rng import (
     RngState,
@@ -39,6 +40,7 @@ __all__ = [
     "scaled_bernoulli",
     "discrete",
     "make_blobs",
+    "make_sift_like",
     "make_regression",
     "multi_variable_gaussian",
     "permute",

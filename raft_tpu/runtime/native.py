@@ -42,12 +42,22 @@ _SUFFIX_DTYPES = {
 }
 
 
+def _stale() -> bool:
+    """True when the library is missing or older than either file it is
+    built from — a leftover .so must never outlive a runtime.cpp change."""
+    if not _SO_PATH.exists():
+        return True
+    built = _SO_PATH.stat().st_mtime
+    return any((_CPP_DIR / f).stat().st_mtime > built
+               for f in ("runtime.cpp", "Makefile"))
+
+
 def _load() -> ctypes.CDLL | None:
     global _lib, _build_failed
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not _SO_PATH.exists():
+        if _stale():
             try:
                 subprocess.run(
                     ["make", "-s"], cwd=_CPP_DIR, check=True, capture_output=True

@@ -394,7 +394,7 @@ class TestScanImpls:
     """The scan formulations (SearchParams.scan_impl) must agree: the one-hot
     MXU contraction, the XLA compare+select chain, and the Pallas
     dynamic-gather kernel (interpret mode on the CPU test platform) are three
-    spellings of the same Σ_s LUT[s, code_s] (BASELINE.md r04 scan study)."""
+    spellings of the same Σ_s LUT[s, code_s] (r04 scan study)."""
 
     @pytest.mark.parametrize("bits", [4, 8])
     def test_impls_agree(self, data, bits, monkeypatch):
@@ -481,7 +481,7 @@ class TestGroupedScan:
     """scan_order='grouped' (probe-major, shared one-hot per list group) must
     agree with the tiled order across metrics, bit widths, LUT dtypes and
     filters — same candidates scored by the same quantizer, only the batching
-    differs (BASELINE.md "Round-4 grouped scan")."""
+    differs (r04 grouped scan)."""
 
     @pytest.mark.parametrize("bits", [4, 8])
     def test_matches_tiled(self, data, bits):

@@ -480,6 +480,9 @@ class TestProcessMesh:
             st = mesh.stats()
             assert st["workers"] == 4
             assert st["cache_misses"] == 0 and st["compile_s"] == 0.0
+            # workers run on the CPU by design, and say so
+            assert st["platforms"] == ["cpu"]
+            assert all(w.platform == "cpu" for g in mesh._workers for w in g)
 
             # kill one worker: strike→fence→failover, NOT an outage.
             # Per-shard round-robin alternates the group's primary, so

@@ -222,7 +222,6 @@ class TestCompileAttribution:
         x = jnp.ones((173, 59))  # unique shape: nothing else compiled it
         with obs.attribution() as cold:
             jax.jit(f)(x).block_until_ready()
-        assert cold.available
         assert cold.compile_s > 0 and cold.programs >= 1
         assert cold.cache_misses >= 1
         assert cold.cache_hits == 0

@@ -268,8 +268,6 @@ def test_canary_under_churn_tracks_oracle_with_zero_compiles(rng):
     from raft_tpu.obs import compile as obs_compile
     from raft_tpu.serve import SearchService
 
-    if not obs_compile.install():  # pragma: no cover - ancient jax
-        pytest.skip("jax.monitoring unavailable")
 
     n, d, k, cap = 600, 16, 5, 64
     x = rng.random((n, d), dtype=np.float32)

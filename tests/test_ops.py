@@ -113,8 +113,8 @@ def test_topk_pallas_two_wide_instances(rng):
     wide-k (k > 128) kernel instances chained inside ONE jit program — the
     per-chunk + final-merge composition of ivf_pq's scan at the CAGRA
     build-chunk k = gpu_top_k + 1 = 193. The r05 toolchain failed to compile
-    this on TPU (the 2*kh = 512-lane merge intermediates; BASELINE.md
-    "Round-5 wide-k selector study"); the r06 half-width merge caps every
+    this on TPU (the 2*kh = 512-lane merge intermediates; r05 wide-k
+    selector study); the r06 half-width merge caps every
     intermediate at kh lanes, and this test pins the composition so the
     select_k dispatch lift can never silently outlive a regression — on TPU
     it exercises the real Mosaic compile, on CPU the interpreter (numerics

@@ -93,6 +93,33 @@ def test_native_available_or_fallback():
     assert runtime.available() in (True, False)
 
 
+def test_native_library_rebuilds_when_stale(tmp_path, monkeypatch):
+    """A built library older than runtime.cpp or the Makefile is stale: a
+    leftover .so must not outlive a change to the files it is built from."""
+    import os
+    import shutil
+
+    from raft_tpu.runtime import native
+
+    cpp = tmp_path / "cpp"
+    cpp.mkdir()
+    for f in ("runtime.cpp", "Makefile"):
+        shutil.copy(native._CPP_DIR / f, cpp / f)
+    so = cpp / native._SO_PATH.name
+    monkeypatch.setattr(native, "_CPP_DIR", cpp)
+    monkeypatch.setattr(native, "_SO_PATH", so)
+    assert native._stale()  # missing
+    so.write_bytes(b"")
+    os.utime(cpp / "runtime.cpp", (100, 100))
+    os.utime(cpp / "Makefile", (100, 100))
+    os.utime(so, (200, 200))
+    assert not native._stale()
+    for f in ("runtime.cpp", "Makefile"):
+        os.utime(cpp / f, (300, 300))
+        assert native._stale(), f
+        os.utime(cpp / f, (100, 100))
+
+
 def test_make_fbin_roundtrip(tmp_path):
     """bench/ann/make_fbin.py writes chunked big-ANN files the native loader
     reads back intact (the no-network stand-in for downloading SIFT-1M)."""

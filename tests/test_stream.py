@@ -595,8 +595,6 @@ def test_warm_delta_ladder_keeps_hot_path_compile_free(data, queries):
 
     from raft_tpu.obs import compile as obs_compile
 
-    if not obs_compile.install():  # pragma: no cover - ancient jax
-        pytest.skip("jax.monitoring unavailable")
     clock = FakeClock()
     m = wrap_bf(data, delta_capacity=32, clock=clock)
     svc = SearchService(max_batch=4, clock=clock, start_workers=False)

@@ -551,8 +551,6 @@ def test_zero_cold_compile_warm_ladder_across_the_flip(data, queries):
     warmed through the registry's pre-flip seam, never on the hot path."""
     from raft_tpu.obs import compile as obs_compile
 
-    if not obs_compile.install():  # pragma: no cover - ancient jax
-        pytest.skip("jax.monitoring unavailable")
     clock = FakeClock()
 
     def run(name):

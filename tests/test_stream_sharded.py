@@ -370,8 +370,6 @@ def test_warm_ladder_keeps_sharded_hot_path_compile_free(data, queries):
     Device-pinned, so placement is part of what the warm must cover."""
     from raft_tpu.obs import compile as obs_compile
 
-    if not obs_compile.install():  # pragma: no cover - ancient jax
-        pytest.skip("jax.monitoring unavailable")
     clock = FakeClock()
     devs = jax.devices()[:2]
 

@@ -460,7 +460,7 @@ def _drift_check(name, tol=0.03):
         assert abs(got - want) <= tol, (
             f"{name} drifted: {params} measured {got:.4f} vs committed "
             f"{want:.4f} — regenerate TUNE_r08.json (bench/tune_sweep.py "
-            "--cpu-mesh) and record why in BASELINE.md")
+            "--cpu-mesh) and record why in CHANGES.md")
         if params == entry.params:
             assert got >= entry.evidence["recall_target"] - tol
 

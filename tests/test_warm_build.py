@@ -41,8 +41,7 @@ def test_load_is_much_faster_than_build(tmp_path, rng):
 def test_warmup_entry_point(tmp_path):
     """raft_tpu.warmup must run the real build+search pipeline at the given
     shapes under the persistent cache and report timings (VERDICT r4 #6 —
-    the AOT first-touch story; small shapes here, 1M measured in
-    BASELINE.md's cold/warm table). The warmup itself runs in a subprocess:
+    the AOT first-touch story; small shapes here). The warmup itself runs in a subprocess:
     enable_compilation_cache permanently redirects this process's jax cache
     config, and the cache dir is a tmp_path deleted after the test."""
     import subprocess
@@ -116,6 +115,79 @@ print('CACHE_OK', len(entries))
     assert r2.returncode == 0, r2.stdout + r2.stderr
     n_entries2 = int(r2.stdout.split("CACHE_OK")[1].split()[0])
     assert n_entries2 == n_entries, (n_entries, n_entries2)
+
+
+def _record_cache_config(monkeypatch):
+    """Route enable_compilation_cache's jax.config writes into a list
+    instead of this process's config."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setattr(compilation_cache, "reset_cache", lambda: None)
+    return calls
+
+
+def test_cache_env_var_sets_no_directory(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR belongs to whoever runs the process: with it
+    set, no cache directory is set in code, and the one in effect is its."""
+    import raft_tpu.config
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outer"))
+    calls = _record_cache_config(monkeypatch)
+    got = raft_tpu.config.enable_compilation_cache(str(tmp_path / "inner"))
+    assert got == str(tmp_path / "outer")
+    assert "jax_compilation_cache_dir" not in [name for name, _ in calls]
+    assert not (tmp_path / "inner").exists()
+
+
+def test_cache_default_is_one_fixed_checkout_path(monkeypatch):
+    """Without the variable the cache goes to <checkout>/.jax_cache — the
+    same path on every call (the path is part of what finds entries)."""
+    from pathlib import Path
+
+    import raft_tpu.config
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    calls = _record_cache_config(monkeypatch)
+    first = raft_tpu.config.enable_compilation_cache()
+    second = raft_tpu.config.enable_compilation_cache()
+    want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert first == second == want
+    assert [v for n, v in calls if n == "jax_compilation_cache_dir"] == [
+        want, want]
+
+
+def test_cache_lands_in_env_dir_only(tmp_path):
+    """End to end in a fresh interpreter: with the variable set, compiled
+    entries land there and the checkout default stays untouched."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    outer = tmp_path / "outer"
+    code = f"""
+import sys
+sys.path.insert(0, {str(repo)!r})
+from raft_tpu.core.platform import force_virtual_cpu
+force_virtual_cpu(1)
+import raft_tpu.config
+p = raft_tpu.config.enable_compilation_cache({str(tmp_path / 'inner')!r})
+import jax, jax.numpy as jnp
+jax.jit(lambda x: x * 5 - 3)(jnp.ones((77, 31))).block_until_ready()
+print('DIR', p)
+"""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(outer))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=240, env=env)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert f"DIR {outer}" in r.stdout
+    assert [f for f in os.listdir(outer) if not f.startswith(".")]
+    assert not (tmp_path / "inner").exists()
 
 
 def test_warmup_warms_the_callers_k(tmp_path):
