@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import tracing
 from ..core.errors import expects
 from ..obs.instrument import instrument, nrows
 
@@ -118,6 +119,7 @@ def select_k_impl(values, in_idx, k: int, select_min: bool,
     lax.top_k; "pallas" forces the Pallas kernel (float inputs only — the
     kernel ranks after an f32 cast) and is the A/B lever
     ``bench/cagra_build_select_ab.py`` uses at the CAGRA build-chunk shapes.
+    Device stage ``select_k``.
     """
     expects(impl in ("auto", "xla", "pallas"),
             "select impl must be 'auto', 'xla' or 'pallas', got %r", impl)
@@ -132,36 +134,39 @@ def select_k_impl(values, in_idx, k: int, select_min: bool,
                 values.dtype)
         from ..ops.topk import topk_pallas
 
-        out_v, pos = topk_pallas(values, int(k), select_min=bool(select_min))
-        out_i = (pos if in_idx is None
-                 else jnp.take_along_axis(in_idx, pos, axis=1))
-        return out_v, out_i.astype(jnp.int32)
+        with tracing.scope("select_k"):
+            out_v, pos = topk_pallas(values, int(k),
+                                     select_min=bool(select_min))
+            out_i = (pos if in_idx is None
+                     else jnp.take_along_axis(in_idx, pos, axis=1))
+            return out_v, out_i.astype(jnp.int32)
     return _select_k(values, in_idx, int(k), bool(select_min))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "select_min"))
 def _select_k(values, in_idx, k: int, select_min: bool):
-    if jnp.issubdtype(values.dtype, jnp.integer):
-        # integer scores (e.g. exact int32 distances from the s8 MXU search
-        # paths): ~v is the wrap-free order flip in the SAME dtype for both
-        # families (unsigned: max - v; signed: -v - 1 — unlike negation,
-        # which wraps at INT_MIN, and unlike a python-int `max - v`, which
-        # overflows the weak-typed i32 scalar path for uint32). Output
-        # values are gathered from the input, so they keep the caller's
-        # dtype and exact magnitudes.
-        key = ~values if select_min else values
-        _, top_i = lax.top_k(key, k)
-        top_v = jnp.take_along_axis(values, top_i, axis=1)
+    with tracing.scope("select_k"):
+        if jnp.issubdtype(values.dtype, jnp.integer):
+            # integer scores (e.g. exact int32 distances from the s8 MXU search
+            # paths): ~v is the wrap-free order flip in the SAME dtype for both
+            # families (unsigned: max - v; signed: -v - 1 — unlike negation,
+            # which wraps at INT_MIN, and unlike a python-int `max - v`, which
+            # overflows the weak-typed i32 scalar path for uint32). Output
+            # values are gathered from the input, so they keep the caller's
+            # dtype and exact magnitudes.
+            key = ~values if select_min else values
+            _, top_i = lax.top_k(key, k)
+            top_v = jnp.take_along_axis(values, top_i, axis=1)
+            if in_idx is not None:
+                top_i = jnp.take_along_axis(in_idx, top_i, axis=1)
+            return top_v, top_i.astype(jnp.int32)
+        v = -values if select_min else values
+        top_v, top_i = lax.top_k(v, k)  # ties resolved by lowest index, like the ref
+        if select_min:
+            top_v = -top_v
         if in_idx is not None:
             top_i = jnp.take_along_axis(in_idx, top_i, axis=1)
         return top_v, top_i.astype(jnp.int32)
-    v = -values if select_min else values
-    top_v, top_i = lax.top_k(v, k)  # ties resolved by lowest index, like the ref
-    if select_min:
-        top_v = -top_v
-    if in_idx is not None:
-        top_i = jnp.take_along_axis(in_idx, top_i, axis=1)
-    return top_v, top_i.astype(jnp.int32)
 
 
 @instrument("matrix.select_k",

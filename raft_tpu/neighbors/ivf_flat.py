@@ -633,7 +633,7 @@ def _ivf_search(index: IvfFlatIndex, queries, n_probes: int, k: int,
     inner = metric == DistanceType.InnerProduct
 
     # ---- stage 1: coarse scoring (ref: ivf_flat_search-inl.cuh:130) ----
-    with tracing.range("ivf_flat.search.coarse"):
+    with tracing.scope("ivf_flat.search.coarse"):
         cscore = qf @ index.centers.T  # (m, L) MXU
         if not inner:
             cn = jnp.sum(index.centers * index.centers, axis=1)
@@ -694,7 +694,7 @@ def _ivf_search(index: IvfFlatIndex, queries, n_probes: int, k: int,
         ci = jnp.moveaxis(ci, 0, 1).reshape(query_tile, n_chunks * k)
         return _select_k(cv, ci, k, not inner)
 
-    with tracing.range("ivf_flat.search.scan"):
+    with tracing.scope("ivf_flat.search.scan"):
         dists, idx = lax.map(per_tile, (qt, pt))
     dists = dists.reshape(num * query_tile, k)[:m]
     idx = idx.reshape(num * query_tile, k)[:m]

@@ -1630,35 +1630,31 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
     n_codes = index.codebooks.shape[-2]
 
     # ---- stage 1: coarse clusters (ref select_clusters :68) ----
-    with tracing.range("ivf_pq.search.coarse"):
+    with tracing.scope("ivf_pq.search.coarse"):
         cscore = qf @ index.centers.T
         if not inner:
             cn = jnp.sum(index.centers * index.centers, axis=1)
             cscore = cn[None, :] - 2.0 * cscore
         _, probes = _select_k(cscore, None, n_probes, not inner)  # (m, p)
 
-    # rotated queries
-    qrot = qf @ index.rotation.T  # (m, d_rot)
-
     num = -(-m // query_tile)
     pad = num * query_tile - m
-    qp = jnp.pad(qrot, ((0, pad), (0, 0))) if pad else qrot
-    pp = jnp.pad(probes, ((0, pad), (0, 0))) if pad else probes
-    qt = qp.reshape(num, query_tile, index.rot_dim)
-    pt = pp.reshape(num, query_tile, n_probes)
+    with tracing.scope("ivf_pq.search.lut"):
+        # rotated queries
+        qrot = qf @ index.rotation.T  # (m, d_rot)
+        qp = jnp.pad(qrot, ((0, pad), (0, 0))) if pad else qrot
+        pp = jnp.pad(probes, ((0, pad), (0, 0))) if pad else probes
+        qt = qp.reshape(num, query_tile, index.rot_dim)
+        pt = pp.reshape(num, query_tile, n_probes)
+        # codebook norms (for LUT via ‖c‖² - 2·r·c)
+        cb = index.codebooks.astype(jnp.float32)
+        cb_n2 = jnp.sum(cb * cb, axis=-1)  # (B?, n_codes) matching codebook layout
 
     n_chunks = n_probes // probe_chunk
     cap = index.capacity
 
-    # codebook norms (for LUT via ‖c‖² - 2·r·c)
-    cb = index.codebooks.astype(jnp.float32)
-    cb_n2 = jnp.sum(cb * cb, axis=-1)  # (B?, n_codes) matching codebook layout
-
-    def per_tile(args):
-        q, pr = args  # (T, d_rot), (T, p)
-
-        def per_chunk(c, _):
-            pc = lax.dynamic_slice_in_dim(pr, c * probe_chunk, probe_chunk, axis=1)  # (T, pc)
+    def chunk_lut(q, pc):
+        with tracing.scope("ivf_pq.search.lut"):
             crot = index.centers_rot[pc]  # (T, pc, d_rot)
 
             # ---- LUT (ref ivfpq_search_worker :419 lut computation) ----
@@ -1701,7 +1697,10 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
                     lut = cb_n2[pc][:, :, None, :] - 2.0 * dots
                 if sc is not None:
                     lut = lut * (sc * sc)[:, :, None, None]
+            return lut, bias
 
+    def chunk_scan(lut, bias, pc):
+        with tracing.scope("ivf_pq.search.scan"):
             # ---- scan: score = Σ_s LUT[s, code_s] (ref compute_similarity) ----
             # One-hot MXU formulation: Σ_s LUT[s, c_s] = onehot(codes)·LUTflat.
             # An elementwise take_along_axis gather is ~4x slower on TPU
@@ -1785,6 +1784,15 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
             if index.pq_split and not inner:
                 scores = scores + index.list_consts[pc]  # (T, pc, cap)
             scores = jnp.where(ids >= 0, scores, -jnp.inf if inner else jnp.inf)
+            return scores, ids
+
+    def per_tile(args):
+        q, pr = args  # (T, d_rot), (T, p)
+
+        def per_chunk(c, _):
+            pc = lax.dynamic_slice_in_dim(pr, c * probe_chunk, probe_chunk, axis=1)  # (T, pc)
+            lut, bias = chunk_lut(q, pc)
+            scores, ids = chunk_scan(lut, bias, pc)
             if keep_mask is not None:
                 from .sample_filter import apply_id_filter
 
@@ -1803,8 +1811,7 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
         ci = jnp.moveaxis(ci, 0, 1).reshape(query_tile, n_chunks * k)
         return select_k_impl(cv, ci, k, not inner, impl=select_impl)
 
-    with tracing.range("ivf_pq.search.scan"):
-        dists, idx = lax.map(per_tile, (qt, pt))
+    dists, idx = lax.map(per_tile, (qt, pt))
     dists = dists.reshape(num * query_tile, k)[:m]
     idx = idx.reshape(num * query_tile, k)[:m]
     if not inner and metric in (DistanceType.L2SqrtExpanded, DistanceType.L2SqrtUnexpanded):
@@ -1849,7 +1856,7 @@ def _pq_search_funnel(index: IvfPqIndex, queries, n_probes: int, k: int,
     n_codes = index.codebooks.shape[-2]
 
     # ---- stage 1: coarse clusters (shared with the classic scan) ----
-    with tracing.range("ivf_pq.search.coarse"):
+    with tracing.scope("ivf_pq.search.coarse"):
         cscore = qf @ index.centers.T
         if not inner:
             cn = jnp.sum(index.centers * index.centers, axis=1)
@@ -1976,7 +1983,7 @@ def _pq_search_funnel(index: IvfPqIndex, queries, n_probes: int, k: int,
         ci = jnp.moveaxis(ci, 0, 1).reshape(query_tile, n_chunks * k)
         return select_k_impl(cv, ci, k, not inner, impl=select_impl)
 
-    with tracing.range("ivf_pq.search.funnel"):
+    with tracing.scope("ivf_pq.search.funnel"):
         dists, idx = lax.map(per_tile, (qt, pt))
     dists = dists.reshape(num * query_tile, k)[:m]
     idx = idx.reshape(num * query_tile, k)[:m]

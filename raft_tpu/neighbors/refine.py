@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import tracing
 from ..core.errors import expects
 from ..core.resources import Resources, default_resources
 from ..distance.types import DistanceType, resolve_metric
@@ -52,15 +53,17 @@ def _score_candidates(cand_vecs, queries, candidates, k: int,
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _refine(dataset, queries, candidates, k: int, metric: DistanceType):
-    safe = jnp.maximum(candidates, 0)
-    cand_vecs = jnp.take(dataset, safe, axis=0)  # (m, k0, d)
-    return _score_candidates(cand_vecs, queries, candidates, k, metric)
+    with tracing.scope("refine"):
+        safe = jnp.maximum(candidates, 0)
+        cand_vecs = jnp.take(dataset, safe, axis=0)  # (m, k0, d)
+        return _score_candidates(cand_vecs, queries, candidates, k, metric)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _refine_gathered(cand_vecs, queries, candidates, k: int,
                      metric: DistanceType):
-    return _score_candidates(cand_vecs, queries, candidates, k, metric)
+    with tracing.scope("refine"):
+        return _score_candidates(cand_vecs, queries, candidates, k, metric)
 
 
 def refine(dataset, queries, candidates, k: int, metric="sqeuclidean", res: Resources | None = None):

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..core import tracing
+
 __all__ = ["NoFilter", "BitsetFilter", "resolve_filter", "apply_id_filter"]
 
 
@@ -62,9 +64,11 @@ def validate_filter_covers(index, keep_mask) -> None:
 def apply_id_filter(scores, ids, keep_mask, select_min: bool):
     """Fused mask epilogue: invalidate scores whose candidate id is filtered.
 
-    ``ids`` may contain −1 padding, which stays invalid.
+    ``ids`` may contain −1 padding, which stays invalid. Device stage
+    ``sample_filter``.
     """
-    bad = -jnp.inf if not select_min else jnp.inf
-    valid = ids >= 0
-    kept = jnp.take(keep_mask, jnp.clip(ids, 0), axis=0) & valid
-    return jnp.where(kept, scores, bad)
+    with tracing.scope("sample_filter"):
+        bad = -jnp.inf if not select_min else jnp.inf
+        valid = ids >= 0
+        kept = jnp.take(keep_mask, jnp.clip(ids, 0), axis=0) & valid
+        return jnp.where(kept, scores, bad)

@@ -11,7 +11,7 @@ benchmark.hpp:111-200 — here it is first-class in the library):
 
 Wall time is HOST time through dispatch: jax is async, so a call that
 returns un-materialized arrays records its dispatch cost, not device time
-(device-side stages are carved by ``tracing.range`` names in xprof instead).
+(device-side stages are carved by ``tracing.scope`` names in xprof instead).
 For cold calls the compile share dominates and is reported separately.
 
 Disabled mode (``obs.disable()``) reduces the wrapper to one module-flag

@@ -11,13 +11,15 @@ version for the serve+stream stack:
   :meth:`~raft_tpu.serve.SearchService.submit` admits a request;
 - the batcher records the **queue** span (admission → flush pickup) and
   the **flush** span (flush_fn wall) per request;
-- inside the flush, :func:`add_span`/:func:`annotate` accumulate into a
-  thread-local collector (:func:`collect`): the service's flush function
-  records ``serve/lease`` and ``serve/search``, and
-  ``stream.MutableIndex`` carves the search into ``stream/sealed`` /
-  ``stream/delta`` / ``stream/merge`` dispatch walls plus the registry
-  version the flush leased — so a slow or wrong answer is attributable to
-  a specific queue, flush, index epoch, or stream stage;
+- inside the flush, every host span (:func:`raft_tpu.core.tracing.range`)
+  adds its wall to a thread-local collector (:func:`collect`): the
+  service's flush function records ``serve/lease`` / ``serve/dispatch`` /
+  ``serve/search``, the completion stage ``serve/complete``, and
+  ``stream.MutableIndex`` notes the state epoch it searched — so a slow or
+  wrong answer is attributable to a specific queue, flush, index epoch or
+  host step. The device stages of the search (``stream.merge``,
+  ``refine``, ...) are ``tracing.scope`` names in the profiler trace, not
+  walls here: a host wall around async dispatch times the enqueue;
 - completed requests land in a bounded ring served at ``/debug/requests``
   (``obs.start_http_exporter(port, request_log=log)``), with the
   slowest-recent requests and a per-latency-bucket **exemplar** map — each
@@ -26,11 +28,10 @@ version for the serve+stream stack:
   turns into a concrete trace to read.
 
 Requests in one flush batch share the flush-level spans (they WERE served
-by the same dispatch) and keep per-request queue spans. Span walls inside
-a jax pipeline are host dispatch walls — jax is async — so the flush span
-(which materializes) bounds them; the decomposition is still the right
-attribution order-of-magnitude on the host side, and the device side
-belongs to xprof (docs/observability.md).
+by the same dispatch) and keep per-request queue spans. Span walls are
+host walls — jax is async — so the flush span (which materializes) bounds
+them; the device side belongs to the profiler trace
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -76,9 +77,9 @@ class _Collector:
 
 class collect:
     """Context manager opening a span collector on the current thread —
-    the batcher wraps each flush_fn call in one; :func:`add_span` and
-    :func:`annotate` anywhere below (service flush, registry lease, stream
-    search) accumulate into it. Reentrant-safe (inner scopes shadow) and a
+    the batcher wraps each flush_fn call in one; every
+    :func:`raft_tpu.core.tracing.range` and :func:`annotate` anywhere below
+    (service flush, registry lease, stream search) accumulate into it. Reentrant-safe (inner scopes shadow) and a
     no-op-cost check when no scope is open.
 
     ``resume=`` re-opens an EXISTING collector instead of a fresh one —
@@ -115,8 +116,10 @@ class collect:
 
 def add_span(name: str, seconds: float) -> None:
     """Accumulate a span wall into the active collector (no-op without
-    one — the stream/serve call sites pay one getattr when tracing is
-    off)."""
+    one: one getattr when no request is traced). The sink behind
+    :func:`raft_tpu.core.tracing.range`; call it directly only for a wall
+    that starts on one thread and ends on another, which no ``with``
+    block can wrap (the pipelined ``serve/search``)."""
     c = getattr(_tls, "collector", None)
     if c is not None:
         name = c.pre + name
@@ -133,11 +136,11 @@ def annotate(key: str, value) -> None:
 
 class prefix:
     """Scope a span/note name prefix on the active collector — how the
-    sharded stream tier turns one code path's ``stream/sealed`` /
-    ``stream/delta`` spans into per-shard ``stream/shard<i>/...`` entries,
-    so ``/debug/requests`` attributes tail latency to the straggler shard.
-    Nests (inner prefixes append) and costs one getattr when no collector
-    is open."""
+    sharded stream tier turns one code path's ``stream_epoch`` note into
+    per-shard ``stream/shard<i>/stream_epoch`` entries, so
+    ``/debug/requests`` names the epoch each shard answered from. Nests
+    (inner prefixes append) and costs one getattr when no collector is
+    open."""
 
     def __init__(self, p: str):
         self._p = str(p)

@@ -378,5 +378,6 @@ def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name="cagra_hop",
     )(*args)
     return tuple(o[:m] for o in outs)

@@ -74,6 +74,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import tracing
+
 
 __all__ = ["fused_knn", "FUSED_KNN_MAX_K"]
 
@@ -218,18 +220,19 @@ def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
     # (measured bf16 SLOWER than f32 with in-kernel casts), and bf16 operands
     # also halve the per-step DMA bytes
     io_t = {"bf16": jnp.bfloat16, "s8": jnp.int8}.get(mode, jnp.float32)
-    ds = jnp.pad(dataset.astype(io_t), ((0, n_pad - n), (0, d_pad - d)))
-    qs = jnp.pad(queries.astype(io_t), ((0, m_pad - m), (0, d_pad - d)))
-    base = yn if yn is not None else jnp.zeros((n,), jnp.float32)
-    if keep is not None:
-        # clamp: |y|^2 + penalty would overflow f32 to +inf for rows with
-        # |y|^2 beyond ~4e37, and an inf norm turns the kernel's masked
-        # arithmetic into NaN — pin filtered rows at the finite sentinel so
-        # masking stays magnitude-independent
-        base = jnp.minimum(base + jnp.where(keep, 0.0, _MASK_PENALTY),
-                           _MASK_PENALTY)
-    ynp = jnp.pad(base, (0, n_pad - n),
-                  constant_values=_MASK_PENALTY).reshape(1, n_pad)
+    with tracing.scope("fused_knn.pad"):
+        ds = jnp.pad(dataset.astype(io_t), ((0, n_pad - n), (0, d_pad - d)))
+        qs = jnp.pad(queries.astype(io_t), ((0, m_pad - m), (0, d_pad - d)))
+        base = yn if yn is not None else jnp.zeros((n,), jnp.float32)
+        if keep is not None:
+            # clamp: |y|^2 + penalty would overflow f32 to +inf for rows
+            # with |y|^2 beyond ~4e37, and an inf norm turns the kernel's
+            # masked arithmetic into NaN — pin filtered rows at the finite
+            # sentinel so masking stays magnitude-independent
+            base = jnp.minimum(base + jnp.where(keep, 0.0, _MASK_PENALTY),
+                               _MASK_PENALTY)
+        ynp = jnp.pad(base, (0, n_pad - n),
+                      constant_values=_MASK_PENALTY).reshape(1, n_pad)
     grid = (m_pad // qt, n_pad // nblk)
 
     kern = _make_kernel(k, nblk, qt, mode, l2)
@@ -260,6 +263,7 @@ def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name="fused_knn",
     )(qs, ds, ynp)
     return out_v[:m], out_i[:m]
 
@@ -299,7 +303,12 @@ def fused_knn(dataset, queries, k, *, metric="l2", mode="f32", keep_mask=None,
                 "mode='s8' requires int8 operands (shift uint8 by -128 "
                 "first), got %s/%s", dataset.dtype, queries.dtype)
     l2 = metric == "l2"
-    yn = (jnp.sum(dataset.astype(jnp.float32) ** 2, axis=1) if l2 else None)
+    # device stage brute_force.norms. Called eagerly, these are programs of
+    # their own (jit_integer_pow, jit__reduce_sum) that carry no scope;
+    # benchmark/stages.py maps them to the stage by program name
+    with tracing.scope("brute_force.norms"):
+        yn = (jnp.sum(dataset.astype(jnp.float32) ** 2, axis=1) if l2
+              else None)
     if row_bias is not None:
         rb = jnp.asarray(row_bias, jnp.float32)
         expects(rb.shape == (n,), "row_bias must be (n,)")

@@ -144,6 +144,7 @@ def _pq_scan_impl(codes_hi, codes_lo, lut, bt: int, capb: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name="pq_scan",
     )(*([ch, cl, lp] if split else [ch, lp]))
     return out[:, :B, :capP]
 

@@ -266,6 +266,7 @@ def topk_pallas(x, k: int, select_min: bool = True, blk: int = 4096,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name="topk",
     )(x)
     pos = jnp.minimum(out_i[:m], n - 1)        # _BIG only when a row is degenerate
     vals = jnp.take_along_axis(x, pos, axis=1)  # exact values, infs included

@@ -249,12 +249,12 @@ def _build_cagra_search_fn(comms: Comms, k: int, itopk: int, max_iter: int,
     inner = metric == DistanceType.InnerProduct
 
     def step(data, graph, q, key):
-        with tracing.range("parallel.cagra.local_search"):
+        with tracing.scope("parallel.cagra.local_search"):
             shard = CagraIndex(dataset=data[0], graph=graph[0], metric=metric)
             d_loc, i_loc = _cagra_search(shard, q, key, k, itopk,
                                          max_iter, width, sqrt_out, seed_pool,
                                          hop_impl)
-        with tracing.range("parallel.cagra.merge"):
+        with tracing.scope("parallel.cagra.merge"):
             i_glob = jnp.where(i_loc >= 0,
                                i_loc + comms.rank().astype(jnp.int32) * rows, i_loc)
             d_all = comms.allgather(d_loc)

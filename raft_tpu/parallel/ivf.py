@@ -103,7 +103,7 @@ def _build_flat_search_fn(comms: Comms, n_probes: int, k: int, metric,
     inner = metric == DistanceType.InnerProduct
 
     def step(centers, data, ids, norms, sizes, q):
-        with tracing.range("parallel.ivf.local_search"):
+        with tracing.scope("parallel.ivf.local_search"):
             shard = IvfFlatIndex(centers, data, ids, norms, sizes, metric,
                                  split_factor, data_kind)
             d_loc, i_loc = _ivf_search(
@@ -111,7 +111,7 @@ def _build_flat_search_fn(comms: Comms, n_probes: int, k: int, metric,
                 query_tile=min(256, q.shape[0]), probe_chunk=n_probes,
                 metric=metric,
             )
-        with tracing.range("parallel.ivf.merge"):
+        with tracing.scope("parallel.ivf.merge"):
             d_all = comms.allgather(d_loc)  # (S, m, k) over ICI
             i_all = comms.allgather(i_loc)
             m = q.shape[0]
@@ -324,7 +324,7 @@ def _build_pq_search_fn(comms: Comms, n_probes: int, k: int, query_tile: int,
 
     def step(centers, centers_rot, rotation, codebooks, codes, ids, sizes,
              consts, q):
-        with tracing.range("parallel.ivf.local_search_pq"):
+        with tracing.scope("parallel.ivf.local_search_pq"):
             shard = IvfPqIndex(
                 centers, centers_rot, rotation, codebooks, codes, ids, sizes,
                 list_consts=consts,
@@ -336,7 +336,7 @@ def _build_pq_search_fn(comms: Comms, n_probes: int, k: int, query_tile: int,
                 query_tile=query_tile, probe_chunk=probe_chunk,
                 metric=metric, codebook_kind=codebook_kind,
                 lut_dtype=lut_dtype, scan_impl=scan_impl)
-        with tracing.range("parallel.ivf.merge"):
+        with tracing.scope("parallel.ivf.merge"):
             d_all = comms.allgather(d_loc)
             i_all = comms.allgather(i_loc)
             m = q.shape[0]

@@ -60,7 +60,7 @@ def _build_knn_fn(comms: Comms, k: int, mt: DistanceType, metric_arg: float,
     select_min = mt != DistanceType.InnerProduct
 
     def local_search(x_shard, q, keep_shard):
-        with tracing.range("parallel.knn.local_search"):
+        with tracing.scope("parallel.knn.local_search"):
             if use_fused:
                 return _bf_knn_fused(x_shard, q, k, mt, compute, keep_shard)
             comp = "float32" if compute == "float32x3" else compute
@@ -69,7 +69,7 @@ def _build_knn_fn(comms: Comms, k: int, mt: DistanceType, metric_arg: float,
                            compute=comp)
 
     def merge(d_loc, i_loc, m):
-        with tracing.range("parallel.knn.merge"):
+        with tracing.scope("parallel.knn.merge"):
             i_glob = jnp.where(i_loc >= 0,
                                i_loc + comms.rank().astype(jnp.int32) * shard_rows,
                                -1)

@@ -19,8 +19,10 @@ users" (ROADMAP north star) needs the host-side half of the story:
 
 Observability rides on :mod:`raft_tpu.obs` (queue-depth gauge, wait/occupancy
 histograms, swap/overload/deadline counters — catalogue in
-docs/observability.md) and flushes are tracing-annotated as
-``serve/flush/<bucket>`` for xprof. ONLINE quality hooks thread through the
+docs/observability.md) and every host step of a flush is a
+``core.tracing.range`` span on the profiler timeline (``serve/stage``,
+``serve/flush/<bucket>``, ``serve/handoff``, ``serve/complete``,
+``serve/resolve``). ONLINE quality hooks thread through the
 same layer: ``SearchService(canary=, slo=, request_log=)`` wires the live
 recall canary's flush tap, the SLO burn-rate tracker's admission/latency
 feeds, and request-level tracing (``raft_tpu.obs.quality`` /

@@ -96,6 +96,15 @@ def _flush_seconds():
 
 
 @functools.lru_cache(maxsize=None)
+def _flush_host_seconds():
+    return metrics.histogram(
+        "raft_tpu_serve_flush_host_seconds",
+        "flush worker busy wall per flush, drain pickup to the searcher's "
+        "dispatch returning (hand-off wait excluded) — the host's share "
+        "of the flush", unit="seconds")
+
+
+@functools.lru_cache(maxsize=None)
 def _occupancy():
     return metrics.histogram(
         "raft_tpu_serve_batch_occupancy",
@@ -434,6 +443,7 @@ class MicroBatcher:
         # size combination, breaking the serving path's zero-cold-compile
         # property (the warmed program set must be exactly the bucket
         # shapes). The device sees only the padded (bucket, d) array.
+        t_host = time.perf_counter()
         self._flush_expired(drained, now)
         batch = drained.batch
         if not batch:
@@ -456,25 +466,29 @@ class MicroBatcher:
             # already pinned (set_running_or_notify_cancel), so any escape
             # here would kill the worker and strand them unresolved
             staged_dispatches = 0
-            if self._staging is not None:
-                # reusable per-bucket staging: rows written in place, pad
-                # zeroed, device upload started at drain time (the H2D for
-                # this flush overlaps the previous flush's compute). The
-                # upload is a counted dispatch site, but the flush_fn's
-                # counter is not open yet — meter it here and fold it into
-                # this flush's dispatch observation below
-                with obs_dispatch.count() as sdc:
-                    q_host, q = self._staging.stage(
-                        [np.asarray(r.rows) for r in batch], n_valid,
-                        bucket)
-                staged_dispatches = sdc.total
-            else:
-                q = (np.asarray(batch[0].rows) if len(batch) == 1
-                     else np.concatenate([np.asarray(r.rows) for r in batch]))
-                if n_valid < bucket:
-                    pad = np.zeros((bucket - n_valid,) + q.shape[1:], q.dtype)
-                    q = np.concatenate([q, pad])
-                q_host = q
+            with tracing.range("serve/stage"):
+                if self._staging is not None:
+                    # reusable per-bucket staging: rows written in place,
+                    # pad zeroed, device upload started at drain time (the
+                    # H2D for this flush overlaps the previous flush's
+                    # compute). The upload is a counted dispatch site, but
+                    # the flush_fn's counter is not open yet — meter it
+                    # here and fold it into this flush's dispatch
+                    # observation below
+                    with obs_dispatch.count() as sdc:
+                        q_host, q = self._staging.stage(
+                            [np.asarray(r.rows) for r in batch], n_valid,
+                            bucket)
+                    staged_dispatches = sdc.total
+                else:
+                    q = (np.asarray(batch[0].rows) if len(batch) == 1
+                         else np.concatenate([np.asarray(r.rows)
+                                              for r in batch]))
+                    if n_valid < bucket:
+                        pad = np.zeros((bucket - n_valid,) + q.shape[1:],
+                                       q.dtype)
+                        q = np.concatenate([q, pad])
+                    q_host = q
             with tracing.range("serve/flush/%d", bucket):
                 t_flush = self._clock()
                 # span collector: the flush fn (and anything below it —
@@ -496,6 +510,8 @@ class MicroBatcher:
                                 outcome="error")
             return n_valid
         if metrics._enabled:
+            _flush_host_seconds().observe(time.perf_counter() - t_host,
+                                          stream=self.stream)
             d = getattr(res, "dispatches", None)
             if d is not None:
                 _dispatches_hist().observe(d + staged_dispatches,
@@ -506,7 +522,8 @@ class MicroBatcher:
             # async dispatch: the device result rides to the bounded
             # completion stage and THIS thread immediately drains the next
             # batch — consecutive flushes overlap under jax async dispatch
-            self._hand_off(entry)
+            with tracing.range("serve/handoff"):
+                self._hand_off(entry)
         else:
             self._complete_entry(entry)
         return n_valid
@@ -520,14 +537,18 @@ class MicroBatcher:
         batch = e.batch
         try:
             # resume the dispatch-time span collector so completion-side
-            # spans (serve/search) land on the same batch's trace
+            # spans (serve/complete, serve/search) land on the same batch's
+            # trace
             collector = (requestlog.collect(resume=e.col)
                          if e.col is not None else contextlib.nullcontext())
-            with collector:
+            with collector, tracing.range("serve/complete"):
                 res = e.result
-                if isinstance(res, PendingFlush):
-                    res = res.materialize()
-                out = tuple(np.asarray(a) for a in res)
+                # a PendingFlush hands back host arrays already (the
+                # service's materialize converts them); a plain result is
+                # still device arrays
+                out = (tuple(res.materialize())
+                       if isinstance(res, PendingFlush)
+                       else tuple(np.asarray(a) for a in res))
             flush_dt = self._clock() - e.t_flush
             if metrics._enabled:
                 # flush share = dispatch -> materialized (includes any wait
@@ -544,6 +565,13 @@ class MicroBatcher:
             self._observe_batch(batch, e.now, e.bucket, flush_dt, spans,
                                 notes, outcome="error")
             return
+        with tracing.range("serve/resolve"):
+            self._resolve(e, out, flush_dt)
+
+    def _resolve(self, e: _InFlight, out: tuple, flush_dt: float) -> None:
+        """Scatter one materialized flush to its futures, then run the
+        observability taps."""
+        batch = e.batch
         off = 0
         for r in batch:
             r.future.set_result(tuple(a[off:off + r.n] for a in out))

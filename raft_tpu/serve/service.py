@@ -323,22 +323,19 @@ class SearchService:
             def flush(padded_queries):
                 import jax
 
-                t0 = time.perf_counter()
-                with self.registry.lease(name) as v:
-                    # span collector no-ops unless this flush is traced;
+                with contextlib.ExitStack() as stack:
+                    with tracing.range("serve/lease"):
+                        v = stack.enter_context(self.registry.lease(name))
                     # the leased version pins which index epoch answered
-                    requestlog.add_span("serve/lease",
-                                        time.perf_counter() - t0)
+                    # (a no-op unless this flush is traced)
                     requestlog.annotate("version", v.version)
-                    t1 = time.perf_counter()
-                    out = v.searcher(padded_queries, k)
-                    # materialize before scattering: a future that resolves
-                    # is a result the caller can use at memcpy cost, and
-                    # the latency histograms measure real work, not async
-                    # dispatch
-                    jax.block_until_ready(out)
-                    requestlog.add_span("serve/search",
-                                        time.perf_counter() - t1)
+                    with tracing.range("serve/search"):
+                        out = v.searcher(padded_queries, k)
+                        # materialize before scattering: a future that
+                        # resolves is a result the caller can use at
+                        # memcpy cost, and the latency histograms measure
+                        # real work, not async dispatch
+                        jax.block_until_ready(out)
                 return out
 
             return flush
@@ -349,17 +346,15 @@ class SearchService:
             # registry lease is held until materialization — an in-flight
             # flush still finishes on the version it leased, and
             # retire-after-drain waits for it exactly like a blocking flush
-            t0 = time.perf_counter()
             stack = contextlib.ExitStack()
-            v = stack.enter_context(self.registry.lease(name))
             try:
-                requestlog.add_span("serve/lease", time.perf_counter() - t0)
+                with tracing.range("serve/lease"):
+                    v = stack.enter_context(self.registry.lease(name))
                 requestlog.annotate("version", v.version)
                 t1 = time.perf_counter()
-                with obs_dispatch.count() as dc:
+                with tracing.range("serve/dispatch"), \
+                        obs_dispatch.count() as dc:
                     out = v.searcher(padded_queries, k)
-                requestlog.add_span("serve/dispatch",
-                                    time.perf_counter() - t1)
             except BaseException:
                 # a dispatch that raises fails only its own batch — and
                 # must not strand the lease (the version could never
@@ -370,6 +365,8 @@ class SearchService:
             def materialize(_out=out, _t1=t1, _stack=stack):
                 try:
                     res = tuple(np.asarray(a) for a in _out)
+                    # dispatch (flush worker) -> materialized (completion
+                    # worker): a wall across threads, so no host span
                     requestlog.add_span("serve/search",
                                         time.perf_counter() - _t1)
                     return res

@@ -238,20 +238,23 @@ def _jits():
     import jax
     import jax.numpy as jnp
 
+    from ..core import tracing
     from ..matrix.select_k import _select_k
 
     @jax.jit
     def map_ids(ids, id_map):
-        g = jnp.take(id_map, jnp.clip(ids, 0), axis=0)
-        return jnp.where(ids >= 0, g, -1)
+        with tracing.scope("stream.map_ids"):
+            g = jnp.take(id_map, jnp.clip(ids, 0), axis=0)
+            return jnp.where(ids >= 0, g, -1)
 
     @functools.partial(jax.jit, static_argnames=("k", "select_min"))
     def merge(sealed_d, sealed_i, delta_d, delta_i, k: int, select_min: bool):
-        d = jnp.concatenate([sealed_d, delta_d], axis=1)
-        i = jnp.concatenate([sealed_i, delta_i], axis=1)
-        dv, iv = _select_k(d, i, k, select_min)
-        # underfilled slots keep the shared sentinel: id -1 at ±inf
-        return dv, jnp.where(jnp.isinf(dv), -1, iv)
+        with tracing.scope("stream.merge"):
+            d = jnp.concatenate([sealed_d, delta_d], axis=1)
+            i = jnp.concatenate([sealed_i, delta_i], axis=1)
+            dv, iv = _select_k(d, i, k, select_min)
+            # underfilled slots keep the shared sentinel: id -1 at ±inf
+            return dv, jnp.where(jnp.isinf(dv), -1, iv)
 
     return map_ids, merge
 
@@ -393,11 +396,11 @@ def _scan_state(st: _StreamState, queries, k: int, res=None,
     sets and merge them through ONE dispatch (the knn_merge_parts contract
     generalized to mixed sealed+delta parts). All device handles are
     snapshotted up front, so a concurrent write (which replaces handles,
-    never mutates them) cannot tear this call. Stage walls are recorded as
-    ``stream/sealed`` / ``stream/delta`` request-log spans (host dispatch
-    walls — jax is async; no-op unless a collector is open on this thread)
-    plus the state epoch, so a traced flush attributes to a concrete index
-    epoch and stream stage (the sharded tier prefixes them per shard).
+    never mutates them) cannot tear this call. The state epoch is noted on
+    the request-log collector (the sharded tier prefixes it per shard); the
+    stages' device time is in the profiler trace under their
+    :func:`raft_tpu.core.tracing.scope` names (``stream.delta``,
+    ``stream.map_ids``, the sealed index's own).
 
     Returns ``(sealed_d (m, k), sealed_i, delta_d (m, kd), delta_i)`` with
     global ids and the shared ``-1 / ±inf`` sentinel in unfillable slots.
@@ -406,7 +409,6 @@ def _scan_state(st: _StreamState, queries, k: int, res=None,
     merge pads the rest; the single-device path keeps its k-≤-rows
     contract untouched.
     """
-    from ..neighbors import brute_force
     from ..obs import requestlog
 
     jnp = _jnp()
@@ -432,33 +434,36 @@ def _scan_state(st: _StreamState, queries, k: int, res=None,
         queries = queries.astype(jnp.float32)
     k = int(k)
     ks = k if k_sealed is None else int(k_sealed)
-    t0 = time.perf_counter()
     sd, si = _sealed_search(cfg, sealed, queries, ks, skeep, res=res)
     si = _map_ids(si, imap)
-    t1 = time.perf_counter()
-    kd = min(k, delta.shape[0])
-    dd, di = brute_force.knn(delta, queries, kd, cfg.metric, cfg.metric_arg,
-                             sample_filter=dkeep, res=res)
+    dd, di = _delta_search(cfg, delta, queries, min(k, delta.shape[0]),
+                           dkeep, res)
     di = _map_ids(di, dids)
-    t2 = time.perf_counter()
     # the dispatch meter (obs/dispatch.py): sealed search + delta scan +
     # the two id maps = 4 instrumented sites per epoch scan
     obs_dispatch.note(4)
-    requestlog.add_span("stream/sealed", t1 - t0)
-    requestlog.add_span("stream/delta", t2 - t1)
     return sd, si, dd, di
+
+
+def _delta_search(cfg, delta, queries, kd: int, dkeep, res=None):
+    """The delta memtable's exact scan, as the ``stream.delta`` stage. The
+    scope names its operations where the search is traced inside a jit;
+    called eagerly, the scan's own top-level program carries no scope, and
+    ``benchmark/stages.py`` maps that program (``jit__bf_knn``) to this
+    stage."""
+    from ..core import tracing
+    from ..neighbors import brute_force
+
+    with tracing.scope("stream.delta"):
+        return brute_force.knn(delta, queries, kd, cfg.metric,
+                               cfg.metric_arg, sample_filter=dkeep, res=res)
 
 
 def _search_state(st: _StreamState, queries, k: int, res=None):
     """Unified search over one state epoch: the sealed+delta scan
-    (:func:`_scan_state`) merged through select_k (``stream/merge`` span)."""
-    from ..obs import requestlog
-
+    (:func:`_scan_state`) merged through select_k (``stream.merge``)."""
     sd, si, dd, di = _scan_state(st, queries, k, res=res)
-    t0 = time.perf_counter()
-    out = _merge(sd, si, dd, di, int(k), st.cfg.select_min)
-    requestlog.add_span("stream/merge", time.perf_counter() - t0)
-    return out
+    return _merge(sd, si, dd, di, int(k), st.cfg.select_min)
 
 
 # -- the mutable index -------------------------------------------------------
@@ -1091,14 +1096,9 @@ class MutableIndex:
 
     def _search_refined_state(self, st: _StreamState, queries, k: int,
                               refine_ratio: int, res=None):
-        from ..obs import requestlog
-
         rd, ri, dd, di = self._refined_scan(queries, k, refine_ratio,
                                             res=res, st=st)
-        t0 = time.perf_counter()
-        out = _merge(rd, ri, dd, di, int(k), self._cfg.select_min)
-        requestlog.add_span("stream/merge", time.perf_counter() - t0)
-        return out
+        return _merge(rd, ri, dd, di, int(k), self._cfg.select_min)
 
     def _refined_scan(self, queries, k: int, refine_ratio: int, res=None,
                       st: _StreamState | None = None):
@@ -1109,7 +1109,6 @@ class MutableIndex:
         (delta view before the sealed keep-mask). ``st`` pins an explicit
         state epoch (the :meth:`refined_searcher` hook's lease-drain
         contract); None reads the live state."""
-        from ..neighbors import brute_force
         from ..neighbors.refine import refine_gathered
         from ..obs import requestlog
         from . import tiered as _tiered
@@ -1137,12 +1136,10 @@ class MutableIndex:
             queries = queries.astype(jnp.float32)
         k = int(k)
         kr = min(k * r, st.id_map.shape[0])
-        t0 = time.perf_counter()
         # PQ candidates at the widened width — approximate distances are
         # DISCARDED; only the slot ids feed the exact re-rank
         _, slots = cfg.module.search(cfg.search_params, st.sealed, queries,
                                      kr, sample_filter=skeep, res=res)
-        t1 = time.perf_counter()
         ts = st.store if isinstance(st.store, TieredStore) else None
         if ts is not None:
             cand = ts.fetch(slots, res=res)
@@ -1152,16 +1149,10 @@ class MutableIndex:
         rd, rslots = refine_gathered(cand, queries, slots, ks,
                                      metric=cfg.metric)
         ri = _map_ids(rslots, imap)
-        t2 = time.perf_counter()
-        kd = min(k, delta.shape[0])
-        dd, di = brute_force.knn(delta, queries, kd, cfg.metric,
-                                 cfg.metric_arg, sample_filter=dkeep,
-                                 res=res)
+        dd, di = _delta_search(cfg, delta, queries, min(k, delta.shape[0]),
+                               dkeep, res)
         di = _map_ids(di, dids)
         obs_dispatch.note(5)
-        requestlog.add_span("stream/sealed", t1 - t0)
-        requestlog.add_span("tier/refine", t2 - t1)
-        requestlog.add_span("stream/delta", time.perf_counter() - t2)
         return rd, ri, dd, di
 
     def refined_searcher(self, refine_ratio: int = 4):

@@ -43,8 +43,8 @@ Serve integration is duck-typed end to end: ``serve.publish`` /
 :meth:`exact_search` composes the shard-local exact scans through the same
 one-dispatch merge so ``obs.quality.exact_oracle`` — and therefore the
 RecallCanary and SLOTracker — work unchanged over the mesh, and
-``obs.requestlog`` spans are prefixed ``stream/shard<i>/`` so a traced
-flush attributes tail latency to the straggler shard.
+``obs.requestlog`` notes are prefixed ``stream/shard<i>/`` so a traced
+flush names the epoch each shard answered from.
 
 Consistency: per-shard reads/writes keep MutableIndex's guarantees
 (read-your-writes, kill-then-reveal upserts); a cross-shard search
@@ -117,6 +117,7 @@ def _shard_jits():
     import jax
     import jax.numpy as jnp
 
+    from ..core import tracing
     from ..matrix.select_k import _select_k
 
     @functools.partial(jax.jit, static_argnames=("k", "select_min"))
@@ -135,10 +136,11 @@ def _shard_jits():
         # the knn_merge_parts contract over 2S mixed sealed+delta parts,
         # every part pre-padded to width k so this program is keyed on
         # (m, 2S·k) alone — ONE _select_k dispatch per (bucket, k)
-        d = jnp.concatenate(ds, axis=1)
-        i = jnp.concatenate(is_, axis=1)
-        dv, iv = _select_k(d, i, k, select_min)
-        return dv, jnp.where(jnp.isinf(dv), -1, iv)
+        with tracing.scope("stream.merge"):
+            d = jnp.concatenate(ds, axis=1)
+            i = jnp.concatenate(is_, axis=1)
+            dv, iv = _select_k(d, i, k, select_min)
+            return dv, jnp.where(jnp.isinf(dv), -1, iv)
 
     return pad, merge
 
@@ -686,13 +688,11 @@ class ShardedMutableIndex:
                     d, i = _pad_part(d, i, k, self._select_min)
                 parts_d.append(d)
                 parts_i.append(i)
-        t0 = time.perf_counter()
         # the gather: ONLY the (m, k) candidate tuples cross devices, and
         # only the non-resident ones move
         parts_d, parts_i, moved = _gather_parts(parts_d, parts_i,
                                                 self._merge_device)
         out = _merge_parts(parts_d, parts_i, k, self._select_min)
-        requestlog.add_span("stream/merge", time.perf_counter() - t0)
         requestlog.annotate("stream_shards", len(states))
         requestlog.annotate("stream_moved_parts", moved)
         return out

@@ -186,12 +186,15 @@ def _tier_jits():
     import jax
     import jax.numpy as jnp
 
+    from ..core import tracing
+
     @jax.jit
     def gather(rows, slots):
         # device-mirror gather: negative (sentinel) slots read row 0; the
         # refine epilogue masks them by candidate id, so the value never
-        # surfaces
-        return jnp.take(rows, jnp.clip(slots, 0), axis=0)
+        # surfaces. The first half of the ``refine`` device stage
+        with tracing.scope("refine"):
+            return jnp.take(rows, jnp.clip(slots, 0), axis=0)
 
     @jax.jit
     def shift(ids, base):
