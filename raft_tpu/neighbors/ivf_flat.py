@@ -678,8 +678,11 @@ def _ivf_search(index: IvfFlatIndex, queries, n_probes: int, k: int,
             if inner:
                 scores = jnp.where(ids >= 0, dots, -jnp.inf)
             else:
+                # +inf padding norms stay +inf; the id test also drops slots
+                # whose id a caller set to -1 (stream's list-layout
+                # tombstones), whose norms are finite
                 norms = index.list_norms[pc]
-                scores = norms - 2.0 * dots  # +inf padding stays +inf
+                scores = jnp.where(ids >= 0, norms - 2.0 * dots, jnp.inf)
             if keep_mask is not None:
                 from .sample_filter import apply_id_filter
 

@@ -12,11 +12,15 @@ memtable/compaction shape of LSM-trees (O'Neil et al. 1996) applied to ANN:
   — the same shape discipline as :mod:`raft_tpu.serve.batcher`'s query
   buckets), so delta growth never compiles on the hot path once
   :meth:`MutableIndex.warm` has touched the ladder.
-- **Tombstone bitsets** — deletes flip per-slot alive bits: the sealed
-  index is filtered through its module's ``sample_filter=`` epilogue (the
-  reason every neighbors module grew one), the delta through the same mask
-  applied to its exact scan. ``upsert`` = tombstone-the-old-slot +
-  insert-new, so an id is live in exactly one physical slot at a time.
+- **Tombstones** — deletes flip per-slot alive bits. IVF kinds carry them
+  in the index's own list layout: a per-epoch live view of the sealed
+  index whose ``list_ids`` hold -1 at every dead slot, which the scan's
+  padding mask (``ids < 0`` → ±inf) already drops — no per-candidate
+  gather. Brute-force and CAGRA (stored ids are row offsets) filter the
+  sealed index through its module's ``sample_filter=`` epilogue; the delta
+  applies the alive mask to its exact scan. ``upsert`` =
+  tombstone-the-old-slot + insert-new, so an id is live in exactly one
+  physical slot at a time.
 - **Unified search** — sealed(filtered) and delta scans merge through the
   existing ``select_k`` dispatch; slot-local ids translate to stable global
   ids through a device-resident id map. Results are indistinguishable from
@@ -41,6 +45,7 @@ pre-compaction state while new flushes pick up the published successor.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 import time
@@ -158,6 +163,14 @@ def _c_delta_full():
                            "writes refused because the delta memtable is full")
 
 
+@functools.lru_cache(maxsize=None)
+def _c_sealed_filter():
+    return metrics.counter(
+        "raft_tpu_stream_sealed_filter_total",
+        "sealed scans by how tombstones were applied: layout=list (-1 ids "
+        "in the live list view) or ids (keep-mask over slot ids)")
+
+
 # -- per-kind dispatch -------------------------------------------------------
 
 def _resolve_kind(sealed):
@@ -213,9 +226,16 @@ def _recover_store(kind, sealed, data_kind):
     return None
 
 
-def _sealed_search(cfg, sealed, queries, k, keep_mask, res=None):
+def _sealed_search(cfg, view, queries, k, res=None):
+    """Search the sealed side through its published view (see
+    :func:`_publish_sealed`): ``(live list view, None)`` for IVF kinds,
+    ``(sealed, slot keep-mask)`` otherwise."""
     from ..neighbors import brute_force
 
+    sealed, keep_mask = view
+    if metrics._enabled:
+        _c_sealed_filter().inc(1, name=cfg.name,
+                               layout="list" if keep_mask is None else "ids")
     if cfg.kind == "brute_force":
         return brute_force.knn(sealed.dataset, queries, k, cfg.metric,
                                cfg.metric_arg, sample_filter=keep_mask,
@@ -313,8 +333,9 @@ class _StreamState:
 
     __slots__ = ("cfg", "sealed", "id_map", "sealed_alive", "sealed_dead_n",
                  "store", "delta", "delta_ids", "delta_alive", "delta_n",
-                 "delta_oldest_at", "epoch", "id_map_dev", "sealed_keep_dev",
-                 "delta_view", "store_dev", "mem", "__weakref__")
+                 "delta_oldest_at", "epoch", "id_map_dev", "sealed_view",
+                 "live_ids", "slot_pos", "delta_view", "store_dev", "mem",
+                 "__weakref__")
 
     def __init__(self, cfg: _Config):
         self.cfg = cfg
@@ -343,8 +364,61 @@ def _np_dtype(query_dtype: str):
             "uint8": np.uint8}[query_dtype]
 
 
-def _refresh_sealed_keep(st: _StreamState) -> None:
-    st.sealed_keep_dev = _dev_put(st.cfg, st.sealed_alive)
+def _list_layout(sealed, n: int):
+    """``(flat list_ids host copy, slot → flat position)`` of an IVF
+    index — the per-epoch base of its live view — or None for kinds whose
+    stored ids are row offsets (brute force, CAGRA). Sealed ids are the
+    dense slot range ``0..n-1`` (the wrap contract), so the map is one
+    O(n) scatter."""
+    ids = getattr(sealed, "list_ids", None)
+    if ids is None:
+        return None
+    import jax
+
+    flat = np.array(jax.device_get(ids), np.int32).reshape(-1)
+    valid = np.flatnonzero(flat >= 0)
+    pos = np.empty(n, np.int64)
+    pos[flat[valid]] = valid
+    return flat, pos
+
+
+def _init_sealed_view(st: _StreamState, layout) -> None:
+    """Build and publish one epoch's sealed search view from ``st.sealed``
+    and ``st.sealed_alive`` (wrap and compaction swap); ``layout`` is
+    :func:`_list_layout` of ``st.sealed``, which the swap computes off the
+    write lock."""
+    if layout is None:
+        st.live_ids = st.slot_pos = None
+    else:
+        st.live_ids, st.slot_pos = layout
+        st.live_ids[st.slot_pos[~st.sealed_alive]] = -1
+    _publish_sealed(st)
+
+
+def _kill_listed(st: _StreamState, slots) -> None:
+    """Write -1 at dead slots' positions of the host live ids. The array
+    the published view was uploaded from is never written again (on the
+    CPU an upload may alias it; on the chip a transfer may still read it):
+    the kill lands in a fresh copy, which the caller publishes."""
+    if st.live_ids is not None:
+        st.live_ids = st.live_ids.copy()
+        st.live_ids[st.slot_pos[slots]] = -1
+
+
+def _publish_sealed(st: _StreamState) -> None:
+    """Publish the sealed side as ONE handle, ``st.sealed_view``: for IVF
+    kinds ``(live view, None)`` — the sealed index with ``list_ids``
+    replaced by the live ids, a pytree of the same shapes, so it runs the
+    sealed index's own compiled program and the scan's ``ids < 0`` mask
+    drops the tombstones; otherwise ``(sealed, keep-mask over slots)``
+    through the module's ``sample_filter=``. ``st.sealed`` itself stays
+    untouched (compaction and serialization read it)."""
+    cfg = st.cfg
+    if st.live_ids is None:
+        st.sealed_view = (st.sealed, _dev_put(cfg, st.sealed_alive))
+        return
+    ids = _dev_put(cfg, st.live_ids.reshape(st.sealed.list_ids.shape))
+    st.sealed_view = (dataclasses.replace(st.sealed, list_ids=ids), None)
 
 
 def _refresh_delta(st: _StreamState, capacity: int,
@@ -414,18 +488,18 @@ def _scan_state(st: _StreamState, queries, k: int, res=None,
     jnp = _jnp()
     cfg = st.cfg
     requestlog.annotate("stream_epoch", st.epoch)
-    # handle snapshot — one consistent view (delta_view is assigned as one
-    # tuple, sealed/id_map are frozen per epoch, sealed_keep only changes
-    # VALUES within an epoch, never shape). ORDER MATTERS: the delta view
-    # is read BEFORE the sealed keep-mask, pairing with upsert's
-    # kill-then-reveal publish order (sealed mask first, delta second) — a
-    # reader that sees an upserted id's new delta copy is then guaranteed
-    # to also see the old sealed copy's tombstone; the reverse read order
-    # could surface BOTH copies of one id in a single result row. (The
-    # benign anomaly — an id briefly absent — is the one the design
-    # accepts, like any read racing a write.)
+    # handle snapshot — one consistent view (delta_view and sealed_view are
+    # each assigned as one tuple, id_map is frozen per epoch, the sealed
+    # view only changes VALUES within an epoch, never shape). ORDER
+    # MATTERS: the delta view is read BEFORE the sealed view, pairing with
+    # upsert's kill-then-reveal publish order (sealed view first, delta
+    # second) — a reader that sees an upserted id's new delta copy is then
+    # guaranteed to also see the old sealed copy's tombstone; the reverse
+    # read order could surface BOTH copies of one id in a single result
+    # row. (The benign anomaly — an id briefly absent — is the one the
+    # design accepts, like any read racing a write.)
     delta, dkeep, dids, _ = st.delta_view
-    sealed, skeep, imap = st.sealed, st.sealed_keep_dev, st.id_map_dev
+    sview, imap = st.sealed_view, st.id_map_dev
 
     queries = jnp.asarray(queries)
     expects(queries.ndim == 2 and queries.shape[1] == cfg.dim,
@@ -434,7 +508,7 @@ def _scan_state(st: _StreamState, queries, k: int, res=None,
         queries = queries.astype(jnp.float32)
     k = int(k)
     ks = k if k_sealed is None else int(k_sealed)
-    sd, si = _sealed_search(cfg, sealed, queries, ks, skeep, res=res)
+    sd, si = _sealed_search(cfg, sview, queries, ks, res=res)
     si = _map_ids(si, imap)
     dd, di = _delta_search(cfg, delta, queries, min(k, delta.shape[0]),
                            dkeep, res)
@@ -663,7 +737,7 @@ class MutableIndex:
         st.delta_ids = np.zeros(self.delta_capacity, np.int32)
         st.delta_alive = np.zeros(self.delta_capacity, bool)
         st.id_map_dev = _dev_put(cfg, st.id_map.astype(np.int32))
-        _refresh_sealed_keep(st)
+        _init_sealed_view(st, _list_layout(sealed, n))
         _refresh_delta(st, self.delta_capacity)
         self._state = st
         self._loc = _build_loc(st)
@@ -775,18 +849,23 @@ class MutableIndex:
 
     def _account_state(self, st: _StreamState) -> None:
         """(Re)account this epoch's stream-owned arrays in the obs.mem
-        ledger: device = the published delta view + masks + id map (+ the
-        lazy store copy), host = the preallocated memtable, bitsets and
-        retained store. Keyed on the STATE object, so a compaction swap
-        leaves the old epoch's entry to auto-release at drain — exactly
-        what the retirement audit watches."""
+        ledger: device = the published delta view + the sealed keep-mask
+        or live list ids + id map (+ the lazy store copy), host = the
+        preallocated memtable, bitsets, live-id layout and retained store.
+        Keyed on the STATE object, so a compaction swap leaves the old
+        epoch's entry to auto-release at drain — exactly what the
+        retirement audit watches."""
         if not metrics._enabled:
             return
-        dev = [st.id_map_dev, st.sealed_keep_dev, *st.delta_view[:3]]
+        sview, skeep = st.sealed_view
+        dev = [st.id_map_dev, sview.list_ids if skeep is None else skeep,
+               *st.delta_view[:3]]
         if st.store_dev is not None:
             dev.append(st.store_dev)
         host = [st.delta, st.delta_ids, st.delta_alive, st.sealed_alive,
                 st.id_map]
+        if st.live_ids is not None:
+            host += [st.live_ids, st.slot_pos]
         # a TieredStore carries its own "tier" ledger entry (rows + mirror
         # + gather slots) — ONE attribution, not a second copy here
         if st.store is not None and not isinstance(st.store, TieredStore):
@@ -882,10 +961,11 @@ class MutableIndex:
             if st.delta_n == 0:
                 st.delta_oldest_at = self._clock()
             st.delta_n += r
-            # tombstone-before-reveal: the old copy's mask lands first so a
-            # lock-free reader can never see both copies of an upserted id
+            # tombstone-before-reveal: the old copy's tombstone lands first
+            # so a lock-free reader can never see both copies of an
+            # upserted id
             if sealed_dirty:
-                _refresh_sealed_keep(st)
+                _publish_sealed(st)
             _refresh_delta(st, self.delta_capacity)
             if metrics._enabled:
                 _c_upserts().inc(r, name=self._cfg.name)
@@ -894,8 +974,8 @@ class MutableIndex:
 
     def _tombstone_locked(self, st, gids) -> bool:
         """Mark the live occurrence of each id dead; returns whether a
-        SEALED slot changed (the caller refreshes that device mask)."""
-        sealed_dirty = False
+        SEALED slot changed (the caller publishes the sealed view)."""
+        dead_sealed = []
         killed = 0
         for g in gids:
             loc = self._loc.pop(int(g), None)
@@ -905,12 +985,14 @@ class MutableIndex:
             if loc[0] == "s":
                 st.sealed_alive[loc[1]] = False
                 st.sealed_dead_n += 1
-                sealed_dirty = True
+                dead_sealed.append(loc[1])
             else:
                 st.delta_alive[loc[1]] = False
+        if dead_sealed:
+            _kill_listed(st, dead_sealed)
         if killed and metrics._enabled:
             _c_deletes().inc(killed, name=self._cfg.name)
-        return sealed_dirty
+        return bool(dead_sealed)
 
     def delete(self, ids) -> int:
         """Tombstone ids; returns how many were live. Deletes are visible to
@@ -927,7 +1009,7 @@ class MutableIndex:
             sealed_dirty = self._tombstone_locked(st, arr.tolist())
             n = before - len(self._loc)
             if sealed_dirty:
-                _refresh_sealed_keep(st)
+                _publish_sealed(st)
             # delta tombstones ride the keep mask; rows/ids are untouched
             # by a delete, so only the mask re-uploads
             _refresh_delta(st, self.delta_capacity, mask_only=True)
@@ -944,8 +1026,8 @@ class MutableIndex:
     def exact_search(self, queries, k: int, res=None):
         """EXACT fused kNN over the live corpus — the recall canary's
         shadow oracle (:func:`raft_tpu.obs.quality.exact_oracle`). The
-        sealed side scans the retained raw row store through the same
-        tombstone keep-mask the serving path filters with; the delta side
+        sealed side scans the retained raw row store through a keep-mask
+        of the same tombstone bitset the serving path reads; the delta side
         is the usual exact bucket scan; both merge through ``select_k``
         and map to global ids. Needs the retained store (``dataset=`` /
         ``retain_vectors=True`` — PQ codes cannot reconstruct rows).
@@ -972,9 +1054,9 @@ class MutableIndex:
         st = self._state
         cfg = self._cfg
         # same snapshot discipline and ORDER as _search_state: delta view
-        # before the sealed keep-mask (pairs with upsert's kill-then-reveal)
+        # before the sealed view (pairs with upsert's kill-then-reveal)
         delta, dkeep, dids, _ = st.delta_view
-        skeep, imap = st.sealed_keep_dev, st.id_map_dev
+        skeep, imap = st.sealed_view[1], st.id_map_dev
         queries = jnp.asarray(queries)
         expects(queries.ndim == 2 and queries.shape[1] == cfg.dim,
                 "queries must be (rows, %d)", cfg.dim)
@@ -1004,6 +1086,11 @@ class MutableIndex:
         else:
             store_dev = (mirror if mirror is not None
                          else self._store_device(st))
+            if skeep is None:
+                # a list layout publishes no slot mask: the store scan
+                # uploads a copy of the bitset taken after the delta view
+                # (off the serving path, like the store copy itself)
+                skeep = _dev_put(cfg, st.sealed_alive.copy())
             ks = min(k, store_dev.shape[0])
             sd, si = brute_force.knn(store_dev, queries, ks, cfg.metric,
                                      cfg.metric_arg, sample_filter=skeep,
@@ -1106,7 +1193,7 @@ class MutableIndex:
         part + exact delta part, global ids, BEFORE the merge — so the
         sharded tier composes per-shard refined scans through its one
         ``select_k`` dispatch. Snapshot order matches :func:`_scan_state`
-        (delta view before the sealed keep-mask). ``st`` pins an explicit
+        (delta view before the sealed view). ``st`` pins an explicit
         state epoch (the :meth:`refined_searcher` hook's lease-drain
         contract); None reads the live state."""
         from ..neighbors.refine import refine_gathered
@@ -1128,7 +1215,7 @@ class MutableIndex:
         jnp = _jnp()
         requestlog.annotate("stream_epoch", st.epoch)
         delta, dkeep, dids, _ = st.delta_view
-        skeep, imap = st.sealed_keep_dev, st.id_map_dev
+        sview, imap = st.sealed_view, st.id_map_dev
         queries = jnp.asarray(queries)
         expects(queries.ndim == 2 and queries.shape[1] == cfg.dim,
                 "queries must be (rows, %d)", cfg.dim)
@@ -1138,8 +1225,7 @@ class MutableIndex:
         kr = min(k * r, st.id_map.shape[0])
         # PQ candidates at the widened width — approximate distances are
         # DISCARDED; only the slot ids feed the exact re-rank
-        _, slots = cfg.module.search(cfg.search_params, st.sealed, queries,
-                                     kr, sample_filter=skeep, res=res)
+        _, slots = _sealed_search(cfg, sview, queries, kr, res=res)
         ts = st.store if isinstance(st.store, TieredStore) else None
         if ts is not None:
             cand = ts.fetch(slots, res=res)
@@ -1414,6 +1500,8 @@ class MutableIndex:
             else:
                 jax.block_until_ready(jax.tree_util.tree_leaves(new_sealed))
             id_map_dev = _dev_put(cfg, new_id_map.astype(np.int32))
+            # the successor's list layout (an O(n) host pass) off the lock
+            layout = _list_layout(new_sealed, len(new_id_map))
 
             # ---- atomic swap ---------------------------------------------
             with self._lock:
@@ -1456,7 +1544,7 @@ class MutableIndex:
                 nd.delta_oldest_at = self._clock() if rem else None
                 nd.epoch = st.epoch + 1
                 nd.id_map_dev = id_map_dev
-                _refresh_sealed_keep(nd)
+                _init_sealed_view(nd, layout)
                 _refresh_delta(nd, self.delta_capacity)
                 # location map: every live id points at its new slot
                 self._loc = _build_loc(nd)
@@ -1614,7 +1702,10 @@ def load(path: str, *, search_params=None, index_params=None,
         st = m._state
         st.id_map = id_map.astype(np.int64)
         st.sealed_alive = sealed_alive
-        st.sealed_dead_n = int(sealed_alive.size - sealed_alive.sum())
+        dead = np.flatnonzero(~sealed_alive)
+        st.sealed_dead_n = int(dead.size)
+        # the wrap built the all-alive view; the saved tombstones join it
+        _kill_listed(st, dead)
         st.delta[:delta_n] = delta
         st.delta_ids[:delta_n] = delta_ids
         st.delta_alive[:delta_n] = delta_alive
@@ -1626,7 +1717,7 @@ def load(path: str, *, search_params=None, index_params=None,
         st.delta_oldest_at = clock() if delta_n else None
         m._next_id = next_id
         st.id_map_dev = _dev_put(st.cfg, st.id_map.astype(np.int32))
-        _refresh_sealed_keep(st)
+        _publish_sealed(st)
         _refresh_delta(st, capacity)
         m._loc = _build_loc(st)
         m._update_gauges(st)

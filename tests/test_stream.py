@@ -659,3 +659,232 @@ def test_builder_kind_mismatch_rejected(data):
     m.upsert(data[:1] + 1e-3)
     with pytest.raises(RaftError, match="builder returned"):
         m.compact(mode="rebuild")
+
+
+# -- list-layout tombstones (IVF kinds) --------------------------------------
+
+def _id_space(m, fn):
+    """Run ``fn`` with the live epoch's sealed side put back on the id-space
+    path — the sealed index itself filtered by ``BitsetFilter`` over its
+    slot bitset — so the list-layout answers can be compared against it."""
+    from raft_tpu.neighbors import sample_filter
+
+    st = m._state
+    saved = st.sealed_view
+    st.sealed_view = (st.sealed,
+                      sample_filter.BitsetFilter(st.sealed_alive.copy()).mask)
+    try:
+        return fn()
+    finally:
+        st.sealed_view = saved
+
+
+def _churn(m, data, rng):
+    """Deletes and upserts of varying batch sizes, kill-then-reveal
+    included (existing sealed ids upserted into the delta); returns the
+    ids that must never surface."""
+    gids = m.upsert(rng.standard_normal((6, 16)).astype(np.float32))
+    assert m.delete([3, 17, 44, int(gids[1])]) == 4
+    m.upsert(data[[5, 60]] + 50.0, ids=[5, 60])  # kill-then-reveal, far off
+    assert m.delete([7]) == 1
+    assert m.delete(np.arange(100, 107)) == 7
+    return {3, 17, 44, int(gids[1]), 7, *range(100, 107)}
+
+
+def _assert_same(got, want):
+    """Bit for bit on distances (inf included), id for id on ids."""
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+_LIST_KINDS = {
+    "ivf_pq.tiled": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0),
+                     dict(n_probes=4)),
+    "ivf_pq.grouped": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0),
+                       dict(n_probes=4, scan_order="grouped")),
+    "ivf_pq.funnel": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0,
+                                   fast_scan="1bit"),
+                      dict(n_probes=4, funnel_widen=4)),
+    "ivf_flat": (ivf_flat, dict(n_lists=8, seed=0), dict(n_probes=4)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LIST_KINDS))
+def test_list_tombstones_match_id_space_filter(case, data, rng, tmp_path):
+    """IVF kinds carry tombstones as -1 in a live ``list_ids`` view. Their
+    answers equal the sealed index filtered through ``BitsetFilter`` over
+    the same bitset: after a churn script, after an extend compaction and
+    after save/load, for search and (IVF-PQ) search_refined."""
+    mod, ip, sp = _LIST_KINDS[case]
+    idx = mod.build(mod.IndexParams(**ip), jnp.asarray(data))
+    m = stream.MutableIndex(idx, search_params=mod.SearchParams(**sp),
+                            delta_capacity=32, dataset=data)
+    dead = _churn(m, data, rng)
+    # queries on top of the dead rows: an unmasked tombstone would win
+    q = data[sorted(dead)[:6]] + 1e-3
+
+    def check(mi):
+        calls = [lambda: mi.search(q, 10)]
+        if mod is ivf_pq:
+            calls.append(lambda: mi.search_refined(q, 10, 4))
+        for call in calls:
+            got = call()
+            _assert_same(got, _id_space(mi, call))
+            assert not dead & set(np.asarray(got[1]).ravel().tolist())
+        return calls[0]()
+
+    check(m)
+    assert m.compact(mode="extend")["mode"] == "extend"
+    # 11 sealed deletes + 2 sealed copies upserted away stay masked
+    assert m.stats()["sealed_dead"] == 13
+    check(m)
+    assert m.delete([8, 9]) == 2  # through the successor's layout
+    dead |= {8, 9}
+    check(m)
+    p = str(tmp_path / "m.stream")
+    stream.save(m, p)
+    m2 = stream.load(p, search_params=mod.SearchParams(**sp))
+    _assert_same(check(m2), m.search(q, 10))
+    m2.delete([10])
+    dead.add(10)
+    check(m2)
+
+
+def _sealed_filter_counts(name):
+    from raft_tpu.stream import mutable
+
+    out = {}
+    for key, v in mutable._c_sealed_filter().series().items():
+        labels = dict(key)
+        if labels["name"] == name:
+            out[labels["layout"]] = v
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(_LIST_KINDS))
+def test_list_layout_scan_gathers_no_keep_mask(case, data, rng, monkeypatch):
+    """An IVF sealed scan with tombstones runs no id-space filter: the
+    per-candidate gather (``apply_id_filter``) is never traced, and every
+    sealed scan counts under ``layout="list"``."""
+    import jax
+
+    from raft_tpu.neighbors import sample_filter
+
+    mod, ip, sp = _LIST_KINDS[case]
+    idx = mod.build(mod.IndexParams(**ip), jnp.asarray(data))
+    name = f"list-{case}"
+    m = stream.MutableIndex(idx, search_params=mod.SearchParams(**sp),
+                            delta_capacity=32, dataset=data, name=name)
+    _churn(m, data, rng)
+
+    def no_gather(*a, **kw):
+        raise AssertionError("id-space keep-mask gathered in a list scan")
+
+    monkeypatch.setattr(sample_filter, "apply_id_filter", no_gather)
+    jax.clear_caches()  # trace afresh, so a gather would be seen
+    scans = 0
+    for b in (1, 5):
+        jax.block_until_ready(m.search(data[:b] + 1e-3, 10))
+        scans += 1
+        if mod is ivf_pq:
+            jax.block_until_ready(m.search_refined(data[:b] + 1e-3, 10, 4))
+            scans += 1
+    assert _sealed_filter_counts(name) == {"list": scans}
+
+
+def test_row_offset_kinds_keep_id_space_filter(data, queries):
+    """Brute force and CAGRA store row offsets, not lists: their sealed
+    scans keep the keep-mask and count under ``layout="ids"``."""
+    for name, idx, sp in (
+            ("ids-bf", brute_force.BruteForce().build(jnp.asarray(data)),
+             None),
+            ("ids-cagra", cagra.build(cagra.IndexParams(seed=0),
+                                      jnp.asarray(data)),
+             cagra.SearchParams(itopk_size=32))):
+        m = stream.MutableIndex(idx, search_params=sp, delta_capacity=16,
+                                name=name)
+        m.delete([0, 1])
+        m.search(queries, 5)
+        m.search(queries, 5)
+        assert _sealed_filter_counts(name) == {"ids": 2}
+        assert m._state.sealed_view[1] is not None
+
+
+def test_list_layout_writes_compile_nothing_after_warm(data, rng):
+    """Deletes and upserts of varying batch sizes only re-publish fixed
+    shapes: after warm() and one served flush per bucket, a churning
+    IVF-PQ index (plain and refined hooks) compiles nothing."""
+    import jax
+
+    from raft_tpu.obs import compile as obs_compile
+
+    clock = FakeClock()
+    idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=8, pq_dim=8, seed=0),
+                       jnp.asarray(data))
+    m = stream.MutableIndex(idx, search_params=ivf_pq.SearchParams(n_probes=4),
+                            delta_capacity=32, dataset=data, clock=clock)
+    svc = SearchService(max_batch=4, clock=clock, start_workers=False)
+    svc.publish("m", m, k=5)
+    m.warm(svc.buckets, ks=(5,))
+    m.warm_refined(svc.buckets, ks=(5,), refine_ratio=4)
+    svc.publish("r", m.refined_searcher(4), k=5)
+    with obs_compile.attribution() as rec:
+        gid = 1000
+        for step, (nd, nu) in enumerate(((1, 2), (3, 1), (7, 5), (2, 3))):
+            m.delete(np.arange(10 * step, 10 * step + nd))
+            # upserts of fresh rows and of live sealed ids (kill-then-reveal)
+            m.upsert(rng.standard_normal((nu, 16)).astype(np.float32),
+                     ids=np.arange(gid, gid + nu))
+            gid += nu
+            m.upsert(data[[200 + step]] + 0.5, ids=[200 + step])
+            for name in ("m", "r"):
+                fut = svc.submit(name, data[:3] + 1e-3, 5)
+                clock.advance(1.0)
+                svc.pump()
+                jax.block_until_ready(fut.result(timeout=0))
+    svc.shutdown()
+    assert rec.compile_s == 0.0 and rec.programs == 0
+
+
+def test_list_layout_upsert_never_shows_both_copies(data):
+    """Lock-free readers racing kill-then-reveal upserts on a list layout:
+    the sealed view is published before the delta, and read after it, so
+    no result row ever holds both copies of an upserted id."""
+    import os
+    import sys
+
+    idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=8, pq_dim=8, seed=0),
+                       jnp.asarray(data))
+    m = stream.MutableIndex(idx, search_params=ivf_pq.SearchParams(n_probes=64),
+                            delta_capacity=64)
+    q = data[:48]
+    m.search(q, 10)  # compile before the race
+    stop = threading.Event()
+    bad, done = [], []
+
+    def reader():
+        while not stop.is_set():
+            ids = np.asarray(m.search(q, 10)[1])
+            for row in ids:
+                live = row[row >= 0]
+                if live.size != np.unique(live).size:
+                    bad.append(row.tolist())
+            done.append(1)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=reader)
+               for _ in range((os.cpu_count() or 4) + 1)]
+    try:
+        for t in threads:
+            t.start()
+        for j in range(48):  # each moves a sealed row, unchanged, to the delta
+            m.upsert(data[[j]], ids=[j])
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(60)
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads), "reader wedged"
+    assert done and bad == []
+    assert m.stats()["sealed_dead"] == 48
