@@ -11,7 +11,9 @@ a file of its own, found by the name ``BENCHMARK.json`` gives it:
   ``control(cfg, corpus)``, each ``-> {"hook", "build_s", "work"}``;
 - ``work/<kind>.py``          ``flush_work(cfg, info, queries) ->
   (flops, bytes)``, the least work of one flush;
-- ``reference/<name>.py``     ``exact_topk`` and ``judge``;
+- ``reference/<name>.py``     ``exact_topk`` and ``judge``, or
+  ``judge_record`` where the corpus changes in the window (a
+  configuration's ``stream`` block holds rows out for the writes);
 - ``metrics/<metric>.py``     ``read(ctx) -> number | None``; ``None``
   leaves the metric out of the result line.
 
@@ -222,9 +224,14 @@ class Cell:
         serve = cfg["serve"]
         self.svc = SearchService(max_batch=serve["max_batch"],
                                  max_wait_us=serve["max_wait_us"])
+        # a stream block holds the corpus's last rows out for the writes
+        self.base_n = cfg["data"]["rows"] - int(
+            cfg.get("stream", {}).get("held_out", 0))
+        held = ((self.base_n, self.corpus_host()[self.base_n:])
+                if "stream" in cfg else None)
         # the wire generator's child starts now, while the index builds
         self.gen = loadgen.make(self.traffic, self.svc, cfg["name"],
-                                cfg["k"], self.queries, seed)
+                                cfg["k"], self.queries, seed, held=held)
         self.built = (self.index.control(cfg, self.corpus) if control else
                       self.index.build(cfg, self.corpus, self.corpus_host))
         self.svc.publish(cfg["name"], self.built["hook"], k=cfg["k"])
@@ -296,9 +303,36 @@ class Cell:
         self.gen = self.svc = self.built = None
         gc.collect()
 
+    def write_info(self, rec) -> dict | None:
+        """What the window's writes did, for the result's ``info``; read
+        before teardown (the live delta rows come from the index)."""
+        w = rec.writes
+        if w is None:
+            return None
+        last = np.maximum(w.ins_ack, w.del_ack)
+        stats = getattr(getattr(self.built["hook"], "mutable", None),
+                        "stats", None)
+        st = stats() if stats is not None else None
+        return {"steps": len(w.due),
+                "steps_done": int((~np.isnan(last)).sum()),
+                "steps_done_in_window": int((last <= rec.t1).sum()),
+                "step_late_ms_max": 1e3 * float(np.nanmax(
+                    w.ins_call - w.due, initial=0.0)),
+                "delta_live_rows": None if st is None else int(
+                    st["live"] - st["sealed_rows"] + st["sealed_dead"]),
+                "sealed_republishes": int(((w.del_ids < self.base_n).any(1)
+                                           & ~np.isnan(w.del_ack)).sum()),
+                "errors": w.errors[:3]}
+
     def judge(self, rec) -> dict:
-        """The comparison numbers of every judged answer in ``rec``."""
+        """The comparison numbers of every judged answer in ``rec``: by the
+        reference's own ``judge_record`` where it has one (a corpus that
+        changes in the window), else against one fixed corpus."""
         cfg = self.cfg
+        if hasattr(self.reference, "judge_record"):
+            return self.reference.judge_record(
+                cfg, self.corpus, self.corpus_host(), self.queries,
+                self.judged, rec)
         ref_ids, ref_d = self.reference.exact_topk(
             self.corpus, self.queries[self.judged], cfg["k"])
         keep = np.isin(rec.qidx, self.judged)
@@ -354,6 +388,18 @@ class Context:
                                              / self.trace_flushes)
 
 
+def failed(rec) -> int:
+    """Requests never answered, and write calls that raised or never ran."""
+    n = int(np.isnan(rec.done).sum())
+    return n if rec.writes is None else n + rec.writes.failed()
+
+
+def attempted(rec) -> int:
+    """Requests, and write calls (two a step)."""
+    n = int(len(rec.done))
+    return n if rec.writes is None else n + 2 * len(rec.writes.due)
+
+
 def percentile(values: np.ndarray, q: float) -> float:
     """Nearest-rank percentile (an inf stays inf)."""
     v = np.sort(np.asarray(values, np.float64))
@@ -376,9 +422,10 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     peaks = trace_mod.peaks(d0.device_kind) if platform == "tpu" else None
     least = cell.least_flush_s(rec, peaks) if trace else None
     build_s = (cell.built or {}).get("build_s")
+    writes = cell.write_info(rec)
     cell.teardown()
     numbers = cell.judge(rec)
-    numbers["failed"] = int(np.isnan(rec.done).sum())
+    numbers["failed"] = failed(rec)
     reduced = None
     if trace:
         files = [os.path.join(dp, f) for dp, _, fs in os.walk(traced["dir"])
@@ -404,7 +451,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         c["value"] <= c["limit"] for c in check.values())
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": len(cell.devices), "memory_peak_bytes": memory_peak}
-    out = {"correct": bool(correct), "attempted": int(len(rec.done)),
+    out = {"correct": bool(correct), "attempted": attempted(rec),
            "failed": numbers["failed"], "metrics": metrics, "device": device}
     if reduced is not None:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
@@ -423,5 +470,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                    "answers_per_second": np.histogram(
                        done, bins=int(seconds), range=(rec.t0, rec.t1))[0]
                    .tolist()}
+    if writes is not None:
+        out["info"]["writes"] = dict(writes,
+                                     full_rows=numbers.get("full_rows"))
     out["check"] = check
     return out

@@ -82,6 +82,31 @@ def bf16_search(corpus):
     return lambda queries, k: search(xb, xn, jnp.asarray(queries), int(k))
 
 
+def served(corpus, queries, qidx, ids, dists, chunk: int = 4096):
+    """What every served entry is, row by row: ``(d64, scale, valid,
+    bad)``. ``d64`` (m, k) is the float64 distance of each served id (of
+    row 0 where the id lies outside the corpus), ``scale`` (m,) the row's
+    ``|q|^2 + max |x|^2``, ``valid`` (m, k) whether the id lies inside the
+    corpus, and ``bad`` (m,) a row with an id outside it, a repeated id or
+    a distance that is not finite."""
+    ids = np.asarray(ids, np.int64)
+    m, k = ids.shape
+    valid = (ids >= 0) & (ids < corpus.shape[0])
+    srt = np.sort(ids, axis=1)
+    bad = (~valid.all(1) | (srt[:, 1:] == srt[:, :-1]).any(1)
+           | ~np.isfinite(dists).all(1))
+    safe = np.where(valid, ids, 0)
+    d64 = np.empty((m, k))
+    scale = np.empty(m)
+    for s in range(0, m, chunk):
+        e = slice(s, s + chunk)
+        q = queries[qidx[e]].astype(np.float64)
+        v = corpus[safe[e]].astype(np.float64)
+        d64[e] = ((v - q[:, None, :]) ** 2).sum(-1)
+        scale[e] = (q * q).sum(1) + (v * v).sum(-1).max(1)
+    return d64, scale, valid, bad
+
+
 def judge(corpus, queries, judged, ref_ids, ref_d, qidx, ids, dists,
           chunk: int = 4096) -> dict:
     """Numbers for the answers ``(ids, dists)`` (m, k) served to query
@@ -91,25 +116,13 @@ def judge(corpus, queries, judged, ref_ids, ref_d, qidx, ids, dists,
     ids = np.asarray(ids, np.int64)
     dists = np.asarray(dists, np.float64)
     m, k = ids.shape
-    n = corpus.shape[0]
     pos = np.searchsorted(judged, qidx)
     if m == 0 or not np.array_equal(judged[np.minimum(pos, len(judged) - 1)],
                                     qidx):
         raise ValueError("every judged answer needs a reference row")
-    srt = np.sort(ids, axis=1)
-    bad = ((ids < 0) | (ids >= n)).any(1) | (srt[:, 1:] == srt[:, :-1]).any(1)
-    bad |= ~np.isfinite(dists).all(1)
-    safe = np.where((ids >= 0) & (ids < n), ids, 0)
-    err = np.empty(m)
-    gap = np.empty(m)
-    for s in range(0, m, chunk):
-        e = slice(s, s + chunk)
-        q = queries[qidx[e]].astype(np.float64)
-        v = corpus[safe[e]].astype(np.float64)
-        dx = ((v - q[:, None, :]) ** 2).sum(-1)
-        scale = (q * q).sum(1) + (v * v).sum(-1).max(1)
-        err[e] = (np.abs(dists[e] - dx).max(1)) / scale
-        gap[e] = np.maximum(dx - ref_d[pos[e], k - 1:k], 0.0).max(1) / scale
+    d64, scale, _, bad = served(corpus, queries, qidx, ids, dists, chunk)
+    err = np.abs(dists - d64).max(1) / scale
+    gap = np.maximum(d64 - ref_d[pos, k - 1:k], 0.0).max(1) / scale
     err[bad] = np.inf
     gap[bad] = np.inf
     hits = (ids[:, :, None] == ref_ids[pos][:, None, :k]).any(2).sum(1)

@@ -20,9 +20,17 @@ SMALL = {"rows": 20000, "queries": 500}
 SMALL_LIMITS = {"sift1m-ivfpq": {"dist_err": 1e-6, "failed": 0},
                 "sift1m-exact": {"dist_err": 1e-6, "rank_gap": 1e-6,
                                  "failed": 0}}
+# ``stream=True`` turns a configuration into one written to while served:
+# the last 320 rows held out of the build (20 steps of CHURN insert them),
+# a delta ladder of 8..512 that warms in a few seconds, and the live-set
+# judge with its limits
+SMALL_STREAM = {"held_out": 320, "delta_capacity": 512, "miss_margin": 2e-5}
+STREAM_LIMITS = {"dist_err": 1e-6, "dist_err_fresh": 2e-5, "stale_served": 0,
+                 "missed_fresh": 0, "failed": 0}
 
 
-def small_root(tmp_path, base: str, traffic: dict, index=None) -> str:
+def small_root(tmp_path, base: str, traffic: dict, index=None,
+               judged: int = 100, stream: bool = False) -> str:
     """A benchmark root holding one new cell ``small.<loop>``: the
     configuration ``base`` (a file of ``benchmark/configs``) cut to a CPU
     size, under a traffic mix of its own. Nothing under ``benchmark/`` is
@@ -31,9 +39,12 @@ def small_root(tmp_path, base: str, traffic: dict, index=None) -> str:
         cfg = json.load(f)
     cfg["name"] = "small"
     cfg["data"].update(SMALL)
-    cfg["judged"] = 100
+    cfg["judged"] = judged
     cfg["index"].update(index or {})
     cfg["limits"] = SMALL_LIMITS[base]
+    if stream:
+        cfg.update(reference="exact_l2_live", stream=SMALL_STREAM,
+                   limits=STREAM_LIMITS)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
     cell = f"small.{traffic['loop']}"
@@ -56,6 +67,10 @@ def small_root(tmp_path, base: str, traffic: dict, index=None) -> str:
 CLOSED = {"loop": "closed", "clients": 2, "rows": 16, "warm_requests": 4}
 OPEN = {"loop": "open", "rate": 40, "rows": 1, "senders": 4,
         "warm_requests": 8}
+CHURN = dict(CLOSED, writes={"steps": 20, "inserts_per_step": 16,
+                             "base_deletes_per_step": 40,
+                             "fresh_deletes_per_step": 8,
+                             "fresh_age_steps": 3})
 
 
 @pytest.fixture

@@ -7,7 +7,12 @@ import pytest
 
 from benchmark import harness
 
-from conftest import CLOSED, small_root
+from conftest import CHURN, CLOSED, small_root
+
+
+def _root(tmp_path, config, index, stream):
+    return small_root(tmp_path, config, CHURN if stream else CLOSED,
+                      index=index, stream=stream)
 
 
 def _broken(monkeypatch, root, kind, fault):
@@ -21,6 +26,7 @@ def _broken(monkeypatch, root, kind, fault):
         hook = built["hook"]
         built["hook"] = make_hook(lambda q, k: fault(*hook(q, k)),
                                   hook.kind, hook.dim)
+        built["hook"].mutable = hook.mutable  # the write path stays open
         return built
 
     monkeypatch.setattr(mod, "build", broken_build)
@@ -37,26 +43,28 @@ def half_left_out(d, i):
     return d.at[h:2 * h].set(d[:h]), i.at[h:2 * h].set(i[:h])
 
 
-CASES = [("sift1m-ivfpq", "ivf_pq", {"n_lists": 32}),
-         ("sift1m-exact", "brute_force", {})]
+CASES = [("sift1m-ivfpq", "ivf_pq", {"n_lists": 32}, False),
+         ("sift1m-exact", "brute_force", {}, False),
+         ("sift1m-ivfpq", "ivf_pq", {"n_lists": 32}, True)]
 
 
-@pytest.mark.parametrize("config,kind,index", CASES)
+@pytest.mark.parametrize("config,kind,index,stream", CASES)
 @pytest.mark.parametrize("fault", [None, answer_altered, half_left_out])
 def test_broken_path_is_not_correct(tmp_path, monkeypatch, run_small,
-                                    config, kind, index, fault):
-    root = small_root(tmp_path, config, CLOSED, index=index)
+                                    config, kind, index, stream, fault):
+    root = _root(tmp_path, config, index, stream)
     if fault is not None:
         _broken(monkeypatch, root, kind, fault)
     out = run_small(root, "small.closed")
     assert out["correct"] == (fault is None), out["check"]
 
 
-@pytest.mark.parametrize("config,index", [("sift1m-ivfpq", {"n_lists": 32}),
-                                          ("sift1m-exact", {})])
-def test_control_is_not_correct(tmp_path, run_small, config, index):
+@pytest.mark.parametrize("config,kind,index,stream", CASES)
+def test_control_is_not_correct(tmp_path, run_small, config, kind, index,
+                                stream):
     # sift1m-ivfpq's control is the reference, computed in bf16, in the
-    # program's place; sift1m-exact's is the program's own bf16 scan
+    # program's place (over the live set, taking the writes, for the
+    # stream); sift1m-exact's is the program's own bf16 scan
     # (compute="bfloat16"), which only a TPU computes in bf16 — the CPU
     # backend ignores the precision, so there it is read on the chip
     # (benchmark/calibrate.py --control; PERF.md)
@@ -64,6 +72,7 @@ def test_control_is_not_correct(tmp_path, run_small, config, index):
 
     if config == "sift1m-exact" and jax.default_backend() != "tpu":
         pytest.skip("the CPU backend computes compute='bfloat16' in f32")
-    root = small_root(tmp_path, config, CLOSED, index=index)
+    root = _root(tmp_path, config, index, stream)
     out = run_small(root, "small.closed", control=True)
     assert not out["correct"], out["check"]
+    assert out["failed"] == 0, out["info"]
