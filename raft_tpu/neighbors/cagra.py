@@ -672,7 +672,6 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
     deg = index.graph_degree
     qf = queries.astype(jnp.float32)
     data = index.dataset
-    dn2 = jnp.sum(data.astype(jnp.float32) ** 2, axis=1)  # (n,) vector norms
     width = search_width
     exp_per_hop = width * deg
 
@@ -682,46 +681,6 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
         dots = jnp.einsum("md,med->me", q, vecs.astype(jnp.float32),
                           precision=lax.Precision.HIGHEST)
         return dn2[ids] - 2.0 * dots  # + ‖q‖² added at the end
-
-    # ---- init beam: entry points (ref: search_plan random_samplings) ----
-    n_init = min(max(itopk, exp_per_hop), n)
-    pool = min(int(seed_pool), n)  # small datasets: score every point
-    if pool > n_init:
-        # score a sampled pool with one MXU GEMM, seed per-query best entries
-        pool_ids = jax.random.choice(key, n, (pool,), replace=False).astype(jnp.int32)
-        pool_vecs = data[pool_ids].astype(jnp.float32)  # (S, d)
-        pool_d = dn2[pool_ids][None, :] - 2.0 * jnp.einsum(
-            "md,sd->ms", qf, pool_vecs, precision=lax.Precision.DEFAULT
-        )  # (m, S)
-        if keep_mask is not None:
-            # mask the entry pool too: the n_init seeds must be the best
-            # SURVIVING pool candidates, or a heavy filter could leave a
-            # query with an all-filtered beam while kept rows exist
-            pool_d = jnp.where(keep_mask[pool_ids][None, :], pool_d, jnp.inf)
-        _, best = lax.top_k(-pool_d, n_init)
-        init_ids = pool_ids[best]  # (m, n_init), per-query seeds
-        # re-score selected seeds exactly: the bf16 pool scores only pick
-        # entries; beam/output distances must match the expanded nodes'
-        # HIGHEST-precision scale or near-tie dedup keeps the wrong copy
-        init_d = dist_to(qf, init_ids)
-    else:
-        init_ids = jax.random.choice(key, n, (n_init,), replace=False)
-        init_ids = jnp.broadcast_to(init_ids[None, :], (m, n_init)).astype(jnp.int32)
-        init_d = dist_to(qf, init_ids)
-    if keep_mask is not None:
-        # mask epilogue on the entry candidates (same contract as the
-        # ivf_pq/ivf_flat scan epilogues): filtered seeds carry +inf scores
-        # and can never win a beam slot. Like those scans — and unlike
-        # FreshDiskANN's traverse-through-deletes — filtered nodes are not
-        # expanded either (each hop's candidates are masked below), so heavy
-        # filtering should widen itopk to keep beam coverage.
-        init_d = jnp.where(jnp.take(keep_mask, init_ids, axis=0),
-                           init_d, jnp.inf)
-
-    pad = itopk + exp_per_hop - n_init
-    beam_ids = jnp.pad(init_ids, ((0, 0), (0, max(pad, 0))), constant_values=-1)[:, : itopk + exp_per_hop]
-    beam_d = jnp.pad(init_d, ((0, 0), (0, max(pad, 0))), constant_values=jnp.inf)[:, : itopk + exp_per_hop]
-    beam_visited = jnp.zeros(beam_ids.shape, jnp.bool_)
 
     def dedup_sort(ids, dists, visited):
         """Distance-sorted beam with duplicate ids killed (keep closest) —
@@ -737,7 +696,53 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
         sd2, sid2, sv2 = lax.sort((sd, sid, sv), dimension=1, num_keys=1)
         return sid2, sd2, sv2
 
-    beam_ids, beam_d, beam_visited = dedup_sort(beam_ids, beam_d, beam_visited)
+    # three device stages: the seeds (corpus norms, pool GEMM, re-score),
+    # the hop loop (gathers and the hop kernel or XLA body), and the
+    # closing select with the output epilogue
+    with tracing.scope("cagra.search.seed"):
+        dn2 = jnp.sum(data.astype(jnp.float32) ** 2, axis=1)  # (n,) vector norms
+
+        # ---- init beam: entry points (ref: search_plan random_samplings) ----
+        n_init = min(max(itopk, exp_per_hop), n)
+        pool = min(int(seed_pool), n)  # small datasets: score every point
+        if pool > n_init:
+            # score a sampled pool with one MXU GEMM, seed per-query best entries
+            pool_ids = jax.random.choice(key, n, (pool,), replace=False).astype(jnp.int32)
+            pool_vecs = data[pool_ids].astype(jnp.float32)  # (S, d)
+            pool_d = dn2[pool_ids][None, :] - 2.0 * jnp.einsum(
+                "md,sd->ms", qf, pool_vecs, precision=lax.Precision.DEFAULT
+            )  # (m, S)
+            if keep_mask is not None:
+                # mask the entry pool too: the n_init seeds must be the best
+                # SURVIVING pool candidates, or a heavy filter could leave a
+                # query with an all-filtered beam while kept rows exist
+                pool_d = jnp.where(keep_mask[pool_ids][None, :], pool_d, jnp.inf)
+            _, best = lax.top_k(-pool_d, n_init)
+            init_ids = pool_ids[best]  # (m, n_init), per-query seeds
+            # re-score selected seeds exactly: the bf16 pool scores only pick
+            # entries; beam/output distances must match the expanded nodes'
+            # HIGHEST-precision scale or near-tie dedup keeps the wrong copy
+            init_d = dist_to(qf, init_ids)
+        else:
+            init_ids = jax.random.choice(key, n, (n_init,), replace=False)
+            init_ids = jnp.broadcast_to(init_ids[None, :], (m, n_init)).astype(jnp.int32)
+            init_d = dist_to(qf, init_ids)
+        if keep_mask is not None:
+            # mask epilogue on the entry candidates (same contract as the
+            # ivf_pq/ivf_flat scan epilogues): filtered seeds carry +inf scores
+            # and can never win a beam slot. Like those scans — and unlike
+            # FreshDiskANN's traverse-through-deletes — filtered nodes are not
+            # expanded either (each hop's candidates are masked below), so heavy
+            # filtering should widen itopk to keep beam coverage.
+            init_d = jnp.where(jnp.take(keep_mask, init_ids, axis=0),
+                               init_d, jnp.inf)
+
+        pad = itopk + exp_per_hop - n_init
+        beam_ids = jnp.pad(init_ids, ((0, 0), (0, max(pad, 0))), constant_values=-1)[:, : itopk + exp_per_hop]
+        beam_d = jnp.pad(init_d, ((0, 0), (0, max(pad, 0))), constant_values=jnp.inf)[:, : itopk + exp_per_hop]
+        beam_visited = jnp.zeros(beam_ids.shape, jnp.bool_)
+
+        beam_ids, beam_d, beam_visited = dedup_sort(beam_ids, beam_d, beam_visited)
 
     if hop_impl in ("fused", "fused_arena", "fused_arena_smem"):
         # one Pallas launch per hop: scoring+dedup+merge+pick with beam state
@@ -749,24 +754,7 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
         _, interpret = hop_backend_ok()
         merge = {"fused": "extract", "fused_arena": "arena",
                  "fused_arena_smem": "arena_smem"}[hop_impl]
-        qn = jnp.sum(qf * qf, axis=1, keepdims=True)
-        P = 128
-        bd = jnp.full((m, P), jnp.inf, jnp.float32
-                      ).at[:, :itopk].set(
-                          jnp.maximum(beam_d[:, :itopk] + qn, 0.0))
-        bi = jnp.full((m, P), -1, jnp.int32).at[:, :itopk].set(
-            beam_ids[:, :itopk])
-        bv = jnp.ones((m, P), jnp.int32).at[:, :itopk].set(
-            beam_visited[:, :itopk].astype(jnp.int32))
-        # prime: candidates masked (valid=0) — merge is a no-op re-sort, and
-        # the kernel emits the first hop's picks
         cw = width * deg
-        zero_nbrs = jnp.full((m, cw), -1, jnp.int32)
-        zero_vecs = jnp.zeros((m, cw, d), data.dtype)
-        bd, bi, bv, pick, nocand = cagra_hop(
-            qf, bd, bi, bv, zero_nbrs, zero_vecs,
-            jnp.zeros((m, cw), jnp.int32), itopk, width,
-            interpret=interpret, merge=merge)
 
         def fcond(state):
             _, _, _, _, nocand, it = state
@@ -795,20 +783,40 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
                 interpret=interpret, merge=merge)
             return bd, bi, bv, pick, nocand, it + 1
 
-        bd, bi, bv, _, _, _ = lax.while_loop(
-            fcond, fbody, (bd, bi, bv, pick, nocand, 0))
-        if merge in ("arena", "arena_smem"):
-            # arena beam is unsorted — one final sort (the XLA path pays a
-            # sort per hop; arena pays it once here)
-            from ..matrix.select_k import _select_k
+        with tracing.scope("cagra.search.hops"):
+            qn = jnp.sum(qf * qf, axis=1, keepdims=True)
+            P = 128
+            bd = jnp.full((m, P), jnp.inf, jnp.float32
+                          ).at[:, :itopk].set(
+                              jnp.maximum(beam_d[:, :itopk] + qn, 0.0))
+            bi = jnp.full((m, P), -1, jnp.int32).at[:, :itopk].set(
+                beam_ids[:, :itopk])
+            bv = jnp.ones((m, P), jnp.int32).at[:, :itopk].set(
+                beam_visited[:, :itopk].astype(jnp.int32))
+            # prime: candidates masked (valid=0) — merge is a no-op re-sort, and
+            # the kernel emits the first hop's picks
+            zero_nbrs = jnp.full((m, cw), -1, jnp.int32)
+            zero_vecs = jnp.zeros((m, cw, d), data.dtype)
+            bd, bi, bv, pick, nocand = cagra_hop(
+                qf, bd, bi, bv, zero_nbrs, zero_vecs,
+                jnp.zeros((m, cw), jnp.int32), itopk, width,
+                interpret=interpret, merge=merge)
 
-            bd, bi = _select_k(bd, bi, itopk, True)
-        out_d = jnp.maximum(bd[:, :k], 0.0)
-        if sqrt_out:
-            out_d = jnp.sqrt(out_d)
-        # slots the (possibly filtered) beam never filled report the shared
-        # empty-slot sentinel: id -1 with the +inf score already in place
-        return out_d, jnp.where(jnp.isinf(out_d), -1, bi[:, :k])
+            bd, bi, bv, _, _, _ = lax.while_loop(
+                fcond, fbody, (bd, bi, bv, pick, nocand, 0))
+        with tracing.scope("cagra.search.select"):
+            if merge in ("arena", "arena_smem"):
+                # arena beam is unsorted — one final sort (the XLA path pays a
+                # sort per hop; arena pays it once here)
+                from ..matrix.select_k import _select_k
+
+                bd, bi = _select_k(bd, bi, itopk, True)
+            out_d = jnp.maximum(bd[:, :k], 0.0)
+            if sqrt_out:
+                out_d = jnp.sqrt(out_d)
+            # slots the (possibly filtered) beam never filled report the shared
+            # empty-slot sentinel: id -1 with the +inf score already in place
+            return out_d, jnp.where(jnp.isinf(out_d), -1, bi[:, :k])
 
     def cond(state):
         _, _, visited, it, done = state
@@ -846,17 +854,19 @@ def _cagra_search(index: CagraIndex, queries, key, k: int, itopk: int,
         done = jnp.all(no_cand)
         return ids, dists, visited, it + 1, done
 
-    beam_ids, beam_d, beam_visited, _, _ = lax.while_loop(
-        cond, body, (beam_ids, beam_d, beam_visited, 0, False)
-    )
+    with tracing.scope("cagra.search.hops"):
+        beam_ids, beam_d, beam_visited, _, _ = lax.while_loop(
+            cond, body, (beam_ids, beam_d, beam_visited, 0, False)
+        )
 
-    out_d = beam_d[:, :k] + jnp.sum(qf * qf, axis=1, keepdims=True)
-    out_d = jnp.maximum(out_d, 0.0)
-    if sqrt_out:
-        out_d = jnp.sqrt(out_d)
-    # slots the (possibly filtered) beam never filled report the shared
-    # empty-slot sentinel: id -1 with the +inf score already in place
-    return out_d, jnp.where(jnp.isinf(out_d), -1, beam_ids[:, :k])
+    with tracing.scope("cagra.search.select"):
+        out_d = beam_d[:, :k] + jnp.sum(qf * qf, axis=1, keepdims=True)
+        out_d = jnp.maximum(out_d, 0.0)
+        if sqrt_out:
+            out_d = jnp.sqrt(out_d)
+        # slots the (possibly filtered) beam never filled report the shared
+        # empty-slot sentinel: id -1 with the +inf score already in place
+        return out_d, jnp.where(jnp.isinf(out_d), -1, beam_ids[:, :k])
 
 
 def resolve_max_iterations(params: SearchParams) -> int:

@@ -186,3 +186,67 @@ def test_fused_knn_names_pad_and_kernel():
                     l2=True, mode="f32", qt=8, nblk=1024, interpret=True)
     # the kernel is named by its pallas_call(name=...)
     assert {"fused_knn.pad", "fused_knn"} <= _names(text)
+
+
+# -- CAGRA search: seed, hops, select ------------------------------------------
+
+CAGRA_STAGES = {"cagra.search.seed", "cagra.search.hops",
+                "cagra.search.select"}
+
+
+@pytest.fixture(scope="module")
+def cagra_index():
+    from raft_tpu.neighbors import cagra
+
+    x = np.random.default_rng(0).random((2000, 16), dtype=np.float32)
+    return x, cagra.build(cagra.IndexParams(intermediate_graph_degree=32,
+                                            graph_degree=16, seed=0), x)
+
+
+@pytest.mark.parametrize("impl", ["xla", "fused_arena"])
+def test_cagra_search_names_its_stages(monkeypatch, cagra_index, impl):
+    from raft_tpu.neighbors import cagra
+
+    monkeypatch.setenv("RAFT_TPU_CAGRA_HOP_INTERPRET", "1")  # the kernel on the CPU
+    x, index = cagra_index
+    texts: list = []
+    _spy(monkeypatch, cagra, "_cagra_search", texts)
+    keep = np.ones(len(x), bool)
+    keep[::7] = False
+    cagra.search(cagra.SearchParams(itopk_size=32, hop_impl=impl), index,
+                 x[:8], 5, sample_filter=keep)
+    (text,) = texts
+    names = _names(text)
+    assert CAGRA_STAGES <= names
+    # the kernel and the arena's closing select_k, innermost in their stages
+    assert ("cagra_hop" in names) == ("select_k" in names) == (impl != "xla")
+
+
+@pytest.mark.parametrize("impl", ["xla", "fused_arena"])
+def test_cagra_stages_change_no_result_bit(monkeypatch, cagra_index, impl):
+    """The same search traced with every stage a no-op serves the same
+    bits: a stage is metadata only."""
+    import contextlib
+    import functools
+
+    from raft_tpu.neighbors import cagra
+
+    monkeypatch.setenv("RAFT_TPU_CAGRA_HOP_INTERPRET", "1")
+    x, index = cagra_index
+    sp = cagra.SearchParams(itopk_size=32, hop_impl=impl)
+    staged = cagra.search(sp, index, x[:16], 10)
+    monkeypatch.setattr(tracing, "scope",
+                        lambda name: contextlib.nullcontext())
+    body = cagra._cagra_search.__wrapped__
+    # a new function object: jit would reuse the staged trace of ``body``
+    plain_fn = jax.jit(functools.wraps(body)(lambda *a, **kw: body(*a, **kw)),
+                       static_argnames=("k", "itopk", "max_iter",
+                                        "search_width", "sqrt_out",
+                                        "seed_pool", "hop_impl"))
+    texts: list = []
+    monkeypatch.setattr(cagra, "_cagra_search", plain_fn)
+    _spy(monkeypatch, cagra, "_cagra_search", texts)
+    plain = cagra.search(sp, index, x[:16], 10)
+    assert not CAGRA_STAGES & _names(texts[0])
+    for a, b in zip(staged, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
