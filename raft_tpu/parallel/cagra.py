@@ -31,8 +31,9 @@ from ..matrix.select_k import _select_k
 from ..obs.instrument import instrument, nrows
 from ..random.rng import as_key
 from ..neighbors.cagra import (CagraIndex, IndexParams, SearchParams, _cagra_search,
-                               estimate_seed_pool, resolve_hop_impl,
-                               resolve_max_iterations, resolve_seed_pool)
+                               _seed_constants, estimate_seed_pool,
+                               resolve_hop_impl, resolve_max_iterations,
+                               resolve_seed_pool, seed_plan)
 from ..neighbors.cagra import build as build_single
 
 __all__ = ["ShardedCagraIndex", "build", "build_merged", "merged_builder",
@@ -205,10 +206,10 @@ def search(comms: Comms, params: SearchParams, index: ShardedCagraIndex,
     sqrt_out = index.metric in (DistanceType.L2SqrtExpanded,
                                 DistanceType.L2SqrtUnexpanded)
     # shared resolution with the single-chip driver: -1 (auto) must not
-    # leak into _cagra_search (a negative pool silently means random
-    # entries), and hop_impl picks the fused Pallas hop when eligible.
-    # Per-shard indexes carry no seed_pool_hint; auto falls to the default.
-    seed_pool = resolve_seed_pool(params)  # _cagra_search clamps to shard rows
+    # leak into seed_plan (a negative pool silently means random entries),
+    # and hop_impl picks the fused Pallas hop when eligible. Per-shard
+    # indexes carry no seed_pool_hint; auto falls to the default.
+    seed_pool = resolve_seed_pool(params)  # seed_plan clamps to shard rows
     hop_impl = resolve_hop_impl(
         params, index.graph.shape[-1], index.dim,
         itemsize=index.dataset.dtype.itemsize)
@@ -251,9 +252,11 @@ def _build_cagra_search_fn(comms: Comms, k: int, itopk: int, max_iter: int,
     def step(data, graph, q, key):
         with tracing.scope("parallel.cagra.local_search"):
             shard = CagraIndex(dataset=data[0], graph=graph[0], metric=metric)
-            d_loc, i_loc = _cagra_search(shard, q, key, k, itopk,
-                                         max_iter, width, sqrt_out, seed_pool,
-                                         hop_impl)
+            pool, n_init = seed_plan(rows, itopk, width, shard.graph_degree,
+                                     seed_pool)
+            seeds = _seed_constants(shard.dataset, key, pool, n_init)
+            d_loc, i_loc = _cagra_search(shard, q, seeds, k, itopk,
+                                         max_iter, width, sqrt_out, hop_impl)
         with tracing.scope("parallel.cagra.merge"):
             i_glob = jnp.where(i_loc >= 0,
                                i_loc + comms.rank().astype(jnp.int32) * rows, i_loc)

@@ -220,6 +220,15 @@ def test_cagra_search_names_its_stages(monkeypatch, cagra_index, impl):
     assert CAGRA_STAGES <= names
     # the kernel and the arena's closing select_k, innermost in their stages
     assert ("cagra_hop" in names) == ("select_k" in names) == (impl != "xla")
+    # the seed stage scores the pool (GEMM, top_k) and re-scores the seeds;
+    # the pool draw and the corpus norms come in as arguments
+    seed_ops = {loc.split("cagra.search.seed/", 1)[1]
+                for loc in re.findall(r'loc\("([^"]*)"', text)
+                if "cagra.search.seed/" in loc}
+    assert {"md,sd->ms/dot_general", "top_k",
+            "md,med->me/dot_general"} <= seed_ops
+    assert "cagra.seed_constants" not in names
+    assert not any("threefry" in n or "random" in n for n in names)
 
 
 @pytest.mark.parametrize("impl", ["xla", "fused_arena"])
@@ -242,7 +251,7 @@ def test_cagra_stages_change_no_result_bit(monkeypatch, cagra_index, impl):
     plain_fn = jax.jit(functools.wraps(body)(lambda *a, **kw: body(*a, **kw)),
                        static_argnames=("k", "itopk", "max_iter",
                                         "search_width", "sqrt_out",
-                                        "seed_pool", "hop_impl"))
+                                        "hop_impl"))
     texts: list = []
     monkeypatch.setattr(cagra, "_cagra_search", plain_fn)
     _spy(monkeypatch, cagra, "_cagra_search", texts)
