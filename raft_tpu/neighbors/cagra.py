@@ -169,20 +169,13 @@ class SearchParams:
     #     pick, beam state VMEM-resident; gathers stay in XLA per the r04
     #     head-to-head) with a threshold-gated arena merge: candidates
     #     insert over the arena's worst only while they beat it, so late
-    #     hops pay ~0 merge passes. Since r06 the insertion loop carries
-    #     its gate in a register and its candidate scores as loop values —
-    #     the r05 profile named the per-candidate SMEM handshake + pool
-    #     scratch round-trips as the ~5 us/query residual between the
-    #     shipped 1.27x and the profiled 1.6x merge-free ceiling, and this
-    #     form removes exactly those terms.
-    #   "fused_arena_smem" — the r05 arena loop kept verbatim (SMEM gate,
-    #     scratch-stashed pool): the control arm for the r06 A/B
-    #     (bench/cagra_hop_ab.py). Measured 1.27x the XLA loop in-process
-    #     at 1M itopk=32, identical recall.
-    #   "fused" — same kernel with the sorted extraction merge (itopk
-    #     unconditional passes); measured NEUTRAL vs XLA — kept as the
-    #     study's control.
-    #   "xla" — the op-at-a-time hop loop (reference shape).
+    #     hops pay ~0 merge passes. The insertion loop carries its gate in
+    #     a register and its candidate scores as loop values (r06); the r05
+    #     arena (SMEM gate, scratch-stashed pool, 1.27x the XLA loop at 1M
+    #     itopk=32) and a sorted-extraction merge (measured NEUTRAL vs XLA)
+    #     were A/B controls no cell ran, and were deleted.
+    #   "xla" — the op-at-a-time hop loop (reference shape): the path for
+    #     shapes the kernel cannot take, and the fused kernel's reference.
     hop_impl: str = "auto"
     # RNG seed (int / RngState / raw key) for the seed-pool draw (ref
     # search_params :118 rand_xor_mask). Determinism contract: the same
@@ -871,7 +864,7 @@ def _cagra_search(index: CagraIndex, queries, seeds: SeedConstants, k: int,
 
         beam_ids, beam_d, beam_visited = dedup_sort(beam_ids, beam_d, beam_visited)
 
-    if hop_impl in ("fused", "fused_arena", "fused_arena_smem"):
+    if hop_impl == "fused_arena":
         # one Pallas launch per hop: scoring+dedup+merge+pick with beam state
         # VMEM-resident (VERDICT r4 #1; ops/cagra_hop.py docstring has the
         # profile-driven rationale). Beam distances carry the FULL ||v-q||^2
@@ -879,8 +872,6 @@ def _cagra_search(index: CagraIndex, queries, seeds: SeedConstants, k: int,
         from ..ops.cagra_hop import cagra_hop, hop_backend_ok
 
         _, interpret = hop_backend_ok()
-        merge = {"fused": "extract", "fused_arena": "arena",
-                 "fused_arena_smem": "arena_smem"}[hop_impl]
         cw = width * deg
 
         def fcond(state):
@@ -907,7 +898,7 @@ def _cagra_search(index: CagraIndex, queries, seeds: SeedConstants, k: int,
                     keep_mask, jnp.maximum(nbrs, 0), axis=0).astype(jnp.int32)
             bd, bi, bv, pick, nocand = cagra_hop(
                 qf, bd, bi, bv, nbrs, vecs, valid, itopk, width,
-                interpret=interpret, merge=merge)
+                interpret=interpret)
             return bd, bi, bv, pick, nocand, it + 1
 
         with tracing.scope("cagra.search.hops"):
@@ -920,24 +911,23 @@ def _cagra_search(index: CagraIndex, queries, seeds: SeedConstants, k: int,
                 beam_ids[:, :itopk])
             bv = jnp.ones((m, P), jnp.int32).at[:, :itopk].set(
                 beam_visited[:, :itopk].astype(jnp.int32))
-            # prime: candidates masked (valid=0) — merge is a no-op re-sort, and
-            # the kernel emits the first hop's picks
+            # prime: candidates masked (valid=0) — the merge inserts nothing,
+            # and the kernel emits the first hop's picks
             zero_nbrs = jnp.full((m, cw), -1, jnp.int32)
             zero_vecs = jnp.zeros((m, cw, d), data.dtype)
             bd, bi, bv, pick, nocand = cagra_hop(
                 qf, bd, bi, bv, zero_nbrs, zero_vecs,
                 jnp.zeros((m, cw), jnp.int32), itopk, width,
-                interpret=interpret, merge=merge)
+                interpret=interpret)
 
             bd, bi, bv, _, _, _ = lax.while_loop(
                 fcond, fbody, (bd, bi, bv, pick, nocand, 0))
         with tracing.scope("cagra.search.select"):
-            if merge in ("arena", "arena_smem"):
-                # arena beam is unsorted — one final sort (the XLA path pays a
-                # sort per hop; arena pays it once here)
-                from ..matrix.select_k import _select_k
+            # the arena beam is unsorted — one final sort (the XLA path pays
+            # a sort per hop; the arena pays it once here)
+            from ..matrix.select_k import _select_k
 
-                bd, bi = _select_k(bd, bi, itopk, True)
+            bd, bi = _select_k(bd, bi, itopk, True)
             out_d = jnp.maximum(bd[:, :k], 0.0)
             if sqrt_out:
                 out_d = jnp.sqrt(out_d)
@@ -1020,21 +1010,19 @@ def resolve_hop_impl(params: SearchParams, graph_degree: int, dim: int,
     of the candidate-block VMEM, widening fused eligibility at high d."""
     from ..ops.cagra_hop import hop_backend_ok, hop_shapes_eligible
 
-    expects(params.hop_impl in ("auto", "xla", "fused", "fused_arena",
-                                "fused_arena_smem"),
-            "hop_impl must be 'auto', 'xla', 'fused', 'fused_arena' or "
-            "'fused_arena_smem', got %r", params.hop_impl)
+    expects(params.hop_impl in ("auto", "xla", "fused_arena"),
+            "hop_impl must be 'auto', 'xla' or 'fused_arena', got %r",
+            params.hop_impl)
     eligible = (hop_backend_ok()[0] and hop_shapes_eligible(
         params.itopk_size, graph_degree, params.search_width, dim,
         itemsize=itemsize))
     if params.hop_impl == "auto":
-        # fused_arena is the measured winner (r05 study):
+        # the fused arena is the measured winner (r05 study):
         # 41-42k vs 32-33k XLA QPS at 1M itopk=32, identical 0.9714 recall
-        # (1.27x in-process); plain "fused" (sorted extraction merge)
-        # measured NEUTRAL and stays as the study's control
+        # (1.27x in-process)
         return "fused_arena" if eligible else "xla"
-    if params.hop_impl in ("fused", "fused_arena", "fused_arena_smem"):
-        expects(eligible, "hop_impl='fused' needs itopk + "
+    if params.hop_impl == "fused_arena":
+        expects(eligible, "hop_impl='fused_arena' needs itopk + "
                 "search_width*graph_degree <= 128, the staged candidate "
                 "block (128*search_width*graph_degree*d_pad*itemsize bytes, "
                 "double-buffered) within the kernel VMEM budget, and a TPU "

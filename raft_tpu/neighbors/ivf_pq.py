@@ -186,7 +186,18 @@ class IndexParams:
 
 @dataclasses.dataclass(frozen=True)
 class SearchParams:
-    """Reference: ivf_pq::search_params (ivf_pq_types.hpp:108-140)."""
+    """Reference: ivf_pq::search_params (ivf_pq_types.hpp:108-140).
+
+    The scan computes Σ_s LUT[s, code_s] (ref compute_similarity's smem
+    gather, ivf_pq_compute_similarity-inl.cuh) as a one-hot MXU contraction,
+    query-major over (query_tile, probe_chunk) tiles: a (T, pc, cap,
+    pq_dim*K) operand XLA fuses the codes gather + compare + cast into,
+    streamed at HBM rate. It is the only formulation because every
+    alternative measured slower or no faster at 1M (r04 PQ scan study) and
+    was deleted: an XLA compare+select chain 0.55x, a Pallas
+    tpu.dynamic_gather (LUT16) kernel 0.73x, and a probe-major order that
+    shared one one-hot per list group neutral (40.7k vs 41.2k QPS).
+    """
 
     n_probes: int = 20
     # "float32" | "bfloat16" | "int8" (ref lut_dtype, ivf_pq_types.hpp:122 —
@@ -194,42 +205,6 @@ class SearchParams:
     # (query, probe) with a symmetric scale and accumulates in int32 on the
     # MXU's int8 path, halving LUT operand bytes again vs bf16)
     lut_dtype: str = "float32"
-    # scan formulation for Σ_s LUT[s, code_s] (ref compute_similarity's smem
-    # gather, ivf_pq_compute_similarity-inl.cuh — a TPU has no smem gather,
-    # so the gather is re-expressed):
-    #   "onehot" — one-hot MXU contraction (the r01+ path, and what "auto"
-    #              picks): a (T, pc, cap, pq_dim*K) operand XLA fuses the
-    #              codes gather + compare + cast into, streamed at HBM rate.
-    #   "pallas" — fused Pallas kernel (ops/pq_scan.py): LUT resident in
-    #              VMEM, codes streamed as int8 planes, tpu.dynamic_gather
-    #              (the hardware LUT16 shuffle) + MXU lane reduction. TPU
-    #              (or interpret) only; 16-wide stages (pq4 / split pq8).
-    #              Measured 0.73x the onehot path at 1M — issue-overhead
-    #              bound (r04 PQ scan study) — kept as an
-    #              option and as the starting point for a grouped scan.
-    #   "select" — the compare+select chain left to XLA: 0.55x onehot at 1M
-    #              (XLA materializes each of the 16 passes); reference impl.
-    #   "auto"   — onehot (fastest measured everywhere).
-    scan_impl: str = "auto"
-    # scan ORDER (orthogonal to scan_impl):
-    #   "tiled"   — query-major (r01-r03): (query_tile, probe_chunk) walks,
-    #               one-hot operand rebuilt per (query, probe) pair.
-    #   "grouped" — probe-major (r04): the batch's (query, probe) pairs sort
-    #               by list id; each group of `group_size` pairs sharing a
-    #               list scores ONE shared one-hot against all its LUTs in
-    #               a real-N MXU matmul (G-way operand amortization). Needs
-    #               k <= capacity. MEASURED NEUTRAL at 1M (40.7k vs the
-    #               tiled path's 41.2k QPS, ~61 pairs/list): the 4x operand
-    #               -traffic cut bought nothing, i.e. the tiled contraction
-    #               was never operand-bound — XLA fuses the one-hot producer
-    #               into the dot (r04 grouped scan). Kept
-    #               as a tested option; the balance may flip at higher
-    #               pairs-per-list ratios or future XLA versions.
-    #   "auto"    — tiled (measured at least as fast everywhere tried).
-    scan_order: str = "auto"
-    # pairs per group for the grouped order (padding waste rises, and
-    # amortization improves, with larger G)
-    group_size: int = 16
     # candidate top-k implementation for the scan's per-chunk + final-merge
     # selects (matrix/select_k.py select_k_impl):
     #   "auto"   — the measured dispatch rule (streaming Pallas selector on
@@ -897,35 +872,6 @@ def _encode(residuals_rot, codebooks, labels, per_cluster: bool, tile: int,
     return codes.reshape(num * tile, -1)[:n]
 
 
-def _select_scores(codes, lut, split: bool):
-    """Σ_s LUT[s, code_s] as 16 compare+select passes per stage — the VPU
-    re-expression of the reference's smem LUT gather (the TPU analogue of
-    ScaNN's SIMD LUT16 shuffle). ``codes`` (..., cap, pq_dim) uint8;
-    ``lut`` (..., pq_dim, K) with K=16 (pq4) or K=32 (nibble-split pq8:
-    stage-1 entries in [..., :16], stage-2 in [..., 16:]).
-
-    Unlike the one-hot MXU contraction this never materializes a
-    (..., cap, pq_dim*K) operand in HBM and never runs an N=1 batched matvec
-    (~1/128 MXU utilization); XLA fuses the compare/select/add chain straight
-    into the score reduction. Accumulation is f32 regardless of the LUT
-    dtype (a bf16 LUT still halves nothing here — entries are register
-    values — but keeps the rounding semantics of the one-hot path).
-    """
-    lutf = lut.astype(jnp.float32)
-    acc = jnp.zeros(codes.shape, jnp.float32)  # (..., cap, pq_dim)
-    if split:
-        hi, lo = codes >> 4, codes & 0xF
-        for kk in range(16):
-            k8 = jnp.uint8(kk)
-            acc = acc + jnp.where(hi == k8, lutf[..., None, :, kk], 0.0)
-            acc = acc + jnp.where(lo == k8, lutf[..., None, :, 16 + kk], 0.0)
-    else:
-        for kk in range(lut.shape[-1]):
-            acc = acc + jnp.where(codes == jnp.uint8(kk),
-                                  lutf[..., None, :, kk], 0.0)
-    return jnp.sum(acc, axis=-1)
-
-
 def _fill_code_lists(codes, ids, labels, n_lists: int, capacity: int,
                      consts=None, sig=None):
     """Scatter codes into padded lists (shared ivf::list scheme). ``sig``
@@ -1202,40 +1148,6 @@ def build(params: IndexParams, dataset, res: Resources | None = None) -> IvfPqIn
         return _extend_stream_f32(index, dataset, None, res=res)
     # x is already the f32 working view (byte data was shifted+upcast above)
     return _extend_f32(index, x, jnp.arange(n, dtype=jnp.int32), res=res)
-
-
-def resolve_scan_impl(params: SearchParams, index: IvfPqIndex, n_codes: int) -> str:
-    """Validate + resolve ``params.scan_impl`` (shared by the single-chip and
-    distributed searches, so both fail with the same clear errors instead of
-    opaque trace-time ones)."""
-    expects(params.scan_impl in ("auto", "onehot", "select", "pallas"),
-            "scan_impl must be 'auto', 'onehot', 'select' or 'pallas', got %r",
-            params.scan_impl)
-    scan_impl = params.scan_impl
-    narrow_stages = index.pq_split or n_codes <= 16
-    if scan_impl == "auto":
-        # the one-hot MXU contraction everywhere: the r04 kernel study
-        # (r04 PQ scan study) measured every alternative
-        # slower at 1M — XLA "select" chain 0.55x, Pallas compare+select
-        # 0.7x, Pallas tpu.dynamic_gather (the hardware LUT16) 0.73x — the
-        # one-hot path fuses gather+compare+cast into the contraction and
-        # saturates HBM, which nothing code-streaming beat in-search
-        scan_impl = "onehot"
-    expects(scan_impl == "onehot" or narrow_stages,
-            "scan_impl=%r needs 16-wide LUT stages (pq_bits=4 or "
-            "nibble-split pq8); this index has %d-entry codebooks",
-            scan_impl, n_codes)
-    expects(scan_impl == "onehot" or params.lut_dtype != "int8",
-            "lut_dtype='int8' is a one-hot-contraction optimization; use "
-            "scan_impl='onehot' (or lut_dtype float32/bfloat16) instead")
-    if scan_impl == "pallas":
-        from ..ops.pq_scan import pq_scan_backend_ok
-
-        ok, _ = pq_scan_backend_ok()
-        expects(ok, "scan_impl='pallas' needs a TPU backend (or "
-                "RAFT_TPU_PQ_SCAN_INTERPRET=1 to opt into interpret mode "
-                "for tests)")
-    return scan_impl
 
 
 def _check_split_consts(index: IvfPqIndex) -> None:
@@ -1617,12 +1529,11 @@ def _extend_stream_f32(index: IvfPqIndex, reader, new_ids=None,
 @functools.partial(
     jax.jit,
     static_argnames=("n_probes", "k", "query_tile", "probe_chunk", "metric",
-                     "codebook_kind", "lut_dtype", "scan_impl", "select_impl"),
+                     "codebook_kind", "lut_dtype", "select_impl"),
 )
 def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: int,
                probe_chunk: int, metric: DistanceType, codebook_kind: str, lut_dtype: str,
-               keep_mask=None, scan_impl: str = "onehot",
-               select_impl: str = "auto"):
+               keep_mask=None, select_impl: str = "auto"):
     m, d = queries.shape
     qf = queries.astype(jnp.float32)
     inner = metric == DistanceType.InnerProduct
@@ -1710,31 +1621,7 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
             # shrinks the contracted axis 16x for exactly that reason.
             codes = index.list_codes[pc]  # (T, pc, cap, pq_dim) gather
             ids = index.list_ids[pc]  # (T, pc, cap)
-            if scan_impl == "pallas":
-                # fused Pallas sweep (ops/pq_scan.py): LUT resident in VMEM,
-                # codes streamed as int8 planes, no one-hot operand at all
-                from ..ops.pq_scan import pq_lut_scan, pq_scan_backend_ok
-
-                _, interp = pq_scan_backend_ok()
-                ct = jnp.bfloat16 if lut_dtype == "bfloat16" else jnp.float32
-                lut_t = jnp.swapaxes(lut, 2, 3).reshape(
-                    query_tile * probe_chunk, n_codes, pq_dim).astype(ct)
-                cflat = codes.reshape(query_tile * probe_chunk, cap, pq_dim)
-                if index.pq_split:
-                    scores = pq_lut_scan(
-                        (cflat >> 4).astype(jnp.int8), lut_t,
-                        codes_lo=(cflat & 0xF).astype(jnp.int8),
-                        interpret=interp)
-                else:
-                    scores = pq_lut_scan(cflat.astype(jnp.int8), lut_t,
-                                         interpret=interp)
-                scores = scores.reshape(query_tile, probe_chunk, cap)
-            elif scan_impl == "select":
-                # compare+select gather (see _select_scores): bf16 rounds the
-                # LUT like the one-hot bf16 mode; accumulation stays f32
-                ct = jnp.bfloat16 if lut_dtype == "bfloat16" else jnp.float32
-                scores = _select_scores(codes, lut.astype(ct), index.pq_split)
-            elif index.pq_split:
+            if index.pq_split:
                 # nibble-split one-hot: stage-1 hit in lanes [0,16), stage-2
                 # in [16,32) — one contraction against the 32-entry LUT sums
                 # LUT1[hi] + LUT2[lo]; the missing cross term rides in
@@ -1749,37 +1636,36 @@ def _pq_search(index: IvfPqIndex, queries, n_probes: int, k: int, query_tile: in
                 oh = (
                     codes[..., None] == jnp.arange(n_codes, dtype=codes.dtype)
                 )  # (T, pc, cap, pq_dim, n_codes)
-            if scan_impl == "onehot":
-                # the contraction dtype follows lut_dtype (0/1 one-hot entries
-                # are exact in any of them):
-                #   float32  — exact LUT values
-                #   bfloat16 — LUT rounded to ~2^-8 relative, half the bytes
-                #   int8     — LUT quantized per (query, probe) with a
-                #              symmetric scale (the reference's fp8 smem LUT
-                #              analogue, detail/fp_8bit.cuh); int32
-                #              accumulation on the int8 MXU path, quarter the
-                #              operand bytes
-                ohf = oh.reshape(query_tile, probe_chunk, cap, pq_dim * n_codes)
-                lutf = lut.reshape(query_tile, probe_chunk, pq_dim * n_codes)
-                if lut_dtype not in ("float32", "bfloat16", "int8"):
-                    raise ValueError(f"unknown lut_dtype {lut_dtype!r}")
-                if lut_dtype == "int8":
-                    amax = jnp.max(jnp.abs(lutf), axis=2, keepdims=True)  # (T,pc,1)
-                    scale = jnp.maximum(amax, 1e-30) / 127.0
-                    lut_q = jnp.clip(jnp.round(lutf / scale), -127, 127).astype(jnp.int8)
-                    acc = lax.dot_general(
-                        ohf.astype(jnp.int8), lut_q,
-                        (((3,), (2,)), ((0, 1), (0, 1))),
-                        preferred_element_type=jnp.int32,
-                    )  # (T, pc, cap) int32
-                    scores = acc.astype(jnp.float32) * scale
-                else:
-                    ct = jnp.bfloat16 if lut_dtype == "bfloat16" else jnp.float32
-                    scores = lax.dot_general(
-                        ohf.astype(ct), lutf.astype(ct),
-                        (((3,), (2,)), ((0, 1), (0, 1))),
-                        preferred_element_type=jnp.float32,
-                    )  # (T, pc, cap)
+            # the contraction dtype follows lut_dtype (0/1 one-hot entries
+            # are exact in any of them):
+            #   float32  — exact LUT values
+            #   bfloat16 — LUT rounded to ~2^-8 relative, half the bytes
+            #   int8     — LUT quantized per (query, probe) with a
+            #              symmetric scale (the reference's fp8 smem LUT
+            #              analogue, detail/fp_8bit.cuh); int32
+            #              accumulation on the int8 MXU path, quarter the
+            #              operand bytes
+            ohf = oh.reshape(query_tile, probe_chunk, cap, pq_dim * n_codes)
+            lutf = lut.reshape(query_tile, probe_chunk, pq_dim * n_codes)
+            if lut_dtype not in ("float32", "bfloat16", "int8"):
+                raise ValueError(f"unknown lut_dtype {lut_dtype!r}")
+            if lut_dtype == "int8":
+                amax = jnp.max(jnp.abs(lutf), axis=2, keepdims=True)  # (T,pc,1)
+                scale = jnp.maximum(amax, 1e-30) / 127.0
+                lut_q = jnp.clip(jnp.round(lutf / scale), -127, 127).astype(jnp.int8)
+                acc = lax.dot_general(
+                    ohf.astype(jnp.int8), lut_q,
+                    (((3,), (2,)), ((0, 1), (0, 1))),
+                    preferred_element_type=jnp.int32,
+                )  # (T, pc, cap) int32
+                scores = acc.astype(jnp.float32) * scale
+            else:
+                ct = jnp.bfloat16 if lut_dtype == "bfloat16" else jnp.float32
+                scores = lax.dot_general(
+                    ohf.astype(ct), lutf.astype(ct),
+                    (((3,), (2,)), ((0, 1), (0, 1))),
+                    preferred_element_type=jnp.float32,
+                )  # (T, pc, cap)
             scores = scores + bias[:, :, None]
             if index.pq_split and not inner:
                 scores = scores + index.list_consts[pc]  # (T, pc, cap)
@@ -1995,192 +1881,6 @@ def _pq_search_funnel(index: IvfPqIndex, queries, n_probes: int, k: int,
     return dists, idx
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_probes", "k", "metric", "codebook_kind", "lut_dtype",
-                     "group_size", "group_chunk", "select_impl"),
-)
-def _pq_search_grouped(index: IvfPqIndex, queries, n_probes: int, k: int,
-                       metric: DistanceType, codebook_kind: str,
-                       lut_dtype: str, keep_mask=None, group_size: int = 16,
-                       group_chunk: int = 32, select_impl: str = "auto"):
-    """Probe-major grouped scan (r04, r04 PQ scan study):
-    the per-(query, probe) one-hot contraction is an N=1 batched matvec that
-    rebuilds a (cap, pq_dim*K) one-hot operand per pair. Here the (query,
-    probe) pairs of the WHOLE batch are sorted by list id and split into
-    groups of ``group_size`` pairs sharing one list, so each group scores
-    ONE one-hot operand against all its queries' LUTs in a single real-N
-    MXU matmul — operand traffic amortizes G ways. The reference reaches
-    the same amortization through smem residency (its kernel pins the LUT
-    per (query, probe) CTA, ivf_pq_compute_similarity-inl.cuh); a TPU has
-    no smem, so sharing swaps sides: codes are shared, LUTs batch.
-
-    Static shapes: padded slots P = m*p + (G-1)*n_lists upper-bounds the
-    per-list round-up; empty groups scan list 0 masked. All reordering is
-    sort/gather-based (no scatters — XLA serializes those on TPU).
-    """
-    m, d = queries.shape
-    qf = queries.astype(jnp.float32)
-    inner = metric == DistanceType.InnerProduct
-    pq_dim, pq_len = index.pq_dim, index.pq_len
-    n_codes = index.codebooks.shape[-2]
-    L = index.list_codes.shape[0]
-    cap = index.capacity
-    G, Gc = group_size, group_chunk
-
-    # ---- stage 1: coarse clusters + rotated queries (as the tiled path) ----
-    cscore = qf @ index.centers.T
-    if not inner:
-        cn = jnp.sum(index.centers * index.centers, axis=1)
-        cscore = cn[None, :] - 2.0 * cscore
-    _, probes = _select_k(cscore, None, n_probes, not inner)  # (m, p)
-    qrot = qf @ index.rotation.T
-
-    # ---- pair grouping (sorted-space, scatter-free) ----
-    mp = m * n_probes
-    pairs = probes.reshape(-1).astype(jnp.int32)           # (mp,) list ids
-    order = jnp.argsort(pairs, stable=True)                # sorted pair -> orig pair
-    sorted_list = jnp.take(pairs, order)
-    counts = jnp.bincount(pairs, length=L)
-    padded = -(-counts // G) * G
-    pstart = jnp.cumsum(padded) - padded                   # padded starts
-    starts = jnp.cumsum(counts) - counts                   # sorted-run starts
-    pos = jnp.arange(mp, dtype=jnp.int32) - jnp.take(starts, sorted_list)
-    slot_sorted = (jnp.take(pstart, sorted_list) + pos).astype(jnp.int32)
-
-    # static bound on padded slots: at most min(L, mp) lists have pairs, each
-    # contributing < G padding (a bound of (G-1)*L would scan mostly dead
-    # groups for small batches on many-list indexes)
-    P = mp + (G - 1) * min(L, mp)
-    n_groups = -(-P // G)
-    n_chunks = -(-n_groups // Gc)
-    # slot -> sorted-pair occupancy, via binary search on the monotonic
-    # slot_sorted (slots without a pair are padding)
-    all_slots = jnp.arange(n_chunks * Gc * G, dtype=jnp.int32)
-    j_of_slot = jnp.searchsorted(slot_sorted, all_slots).astype(jnp.int32)
-    jc = jnp.minimum(j_of_slot, mp - 1)
-    slot_live = (j_of_slot < mp) & (jnp.take(slot_sorted, jc) == all_slots)
-    # slot -> list id (group-constant; list of the sorted run covering it)
-    lend = pstart + padded
-    l_of_slot = (jnp.searchsorted(lend, all_slots, side="right")
-                 .astype(jnp.int32))
-    l_of_slot = jnp.minimum(l_of_slot, L - 1)
-    # slot -> query row (0 for padding, masked later)
-    orig_pair = jnp.take(order, jc)
-    q_of_slot = jnp.where(slot_live, orig_pair // n_probes, 0).astype(jnp.int32)
-
-    cb = index.codebooks.astype(jnp.float32)
-    cb_n2 = jnp.sum(cb * cb, axis=-1)
-    ct = jnp.bfloat16 if lut_dtype == "bfloat16" else jnp.float32
-    q_slot = q_of_slot.reshape(n_chunks, Gc, G)
-    l_slot = l_of_slot.reshape(n_chunks, Gc, G)
-    live_slot = slot_live.reshape(n_chunks, Gc, G)
-    l_group = l_slot[:, :, 0]                              # (n_chunks, Gc)
-
-    def per_chunk(args):
-        qs, ls, lg, live = args  # (Gc, G), (Gc, G), (Gc,), (Gc, G)
-        # ---- LUTs for this chunk's slots ----
-        qr = jnp.take(qrot, qs.reshape(-1), axis=0)        # (Gc*G, d_rot)
-        crot = jnp.take(index.centers_rot, ls.reshape(-1), axis=0)
-        # per-list residual scales: same LUT fold as the tiled path (s for
-        # IP, s^2 for L2 with the residual pre-divided); bias stays raw
-        sc = (jnp.take(index.list_scales, ls.reshape(-1), axis=0)
-              if index.scale_normed else None)             # (Gc*G,) | None
-        if inner:
-            rs = qr.reshape(-1, pq_dim, pq_len)
-            if codebook_kind == "per_subspace":
-                lut = jnp.einsum("nsl,skl->nsk", rs, cb,
-                                 precision=lax.Precision.HIGHEST)
-            else:
-                cbl = jnp.take(cb, ls.reshape(-1), axis=0)
-                lut = jnp.einsum("nsl,nkl->nsk", rs, cbl,
-                                 precision=lax.Precision.HIGHEST)
-            if sc is not None:
-                lut = lut * sc[:, None, None]
-            bias = jnp.einsum("nd,nd->n", qr, crot,
-                              precision=lax.Precision.HIGHEST)
-        else:
-            r = (qr - crot).reshape(-1, pq_dim, pq_len)
-            bias = jnp.sum(r * r, axis=(1, 2))
-            if sc is not None:
-                r = r / sc[:, None, None]
-            if codebook_kind == "per_subspace":
-                dots = jnp.einsum("nsl,skl->nsk", r, cb,
-                                  precision=lax.Precision.HIGHEST)
-                lut = cb_n2[None] - 2.0 * dots
-            else:
-                cbl = jnp.take(cb, ls.reshape(-1), axis=0)
-                dots = jnp.einsum("nsl,nkl->nsk", r, cbl,
-                                  precision=lax.Precision.HIGHEST)
-                lut = jnp.take(cb_n2, ls.reshape(-1), axis=0)[:, None] - 2.0 * dots
-            if sc is not None:
-                lut = lut * (sc * sc)[:, None, None]
-        lutf = lut.reshape(Gc, G, pq_dim * n_codes)
-
-        # ---- shared one-hot per group's list ----
-        codes = jnp.take(index.list_codes, lg, axis=0)     # (Gc, cap, pq_dim)
-        ids = jnp.take(index.list_ids, lg, axis=0)         # (Gc, cap)
-        if index.pq_split:
-            ar16 = jnp.arange(16, dtype=codes.dtype)
-            oh = jnp.concatenate(
-                [(codes >> 4)[..., None] == ar16,
-                 (codes & 0xF)[..., None] == ar16], axis=-1)
-        else:
-            oh = codes[..., None] == jnp.arange(n_codes, dtype=codes.dtype)
-        ohf = oh.reshape(Gc, cap, pq_dim * n_codes)
-
-        # ---- ONE real-N matmul per group: (cap, D) x (D, G) ----
-        if lut_dtype == "int8":
-            amax = jnp.max(jnp.abs(lutf), axis=2, keepdims=True)
-            scale = jnp.maximum(amax, 1e-30) / 127.0
-            lut_q = jnp.clip(jnp.round(lutf / scale), -127, 127).astype(jnp.int8)
-            acc = lax.dot_general(
-                ohf.astype(jnp.int8), lut_q, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.int32)          # (Gc, cap, G)
-            scores = acc.astype(jnp.float32) * jnp.swapaxes(scale, 1, 2)
-        else:
-            scores = lax.dot_general(
-                ohf.astype(ct), jnp.swapaxes(lutf.astype(ct), 1, 2),
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)        # (Gc, cap, G)
-        scores = scores + bias.reshape(Gc, 1, G)
-        if index.pq_split and not inner:
-            scores = scores + jnp.take(index.list_consts, lg, axis=0)[:, :, None]
-        bad = jnp.inf if not inner else -jnp.inf
-        scores = jnp.where(ids[:, :, None] >= 0, scores, bad)
-        sc_t = jnp.swapaxes(scores, 1, 2).reshape(Gc * G, cap)
-        ids_t = jnp.broadcast_to(ids[:, None, :], (Gc, G, cap)
-                                 ).reshape(Gc * G, cap)
-        if keep_mask is not None:
-            from .sample_filter import apply_id_filter
-
-            sc_t = apply_id_filter(sc_t, ids_t, keep_mask, not inner)
-        sv, si = select_k_impl(sc_t, ids_t, k, not inner,
-                               impl=select_impl)          # (Gc*G, k)
-        sv = jnp.where(live.reshape(-1, 1), sv, bad)
-        si = jnp.where(live.reshape(-1, 1), si, -1)
-        return sv, si
-
-    slot_v, slot_i = lax.map(per_chunk, (q_slot, l_slot, l_group, live_slot))
-    slot_v = slot_v.reshape(-1, k)                         # (n_chunks*Gc*G, k)
-    slot_i = slot_i.reshape(-1, k)
-
-    # ---- un-sort: slot results -> per-pair -> per-query merge ----
-    pv = jnp.take(slot_v, slot_sorted, axis=0)             # sorted-pair order
-    pi = jnp.take(slot_i, slot_sorted, axis=0)
-    inv = jnp.argsort(order)                               # orig-pair order
-    pv = jnp.take(pv, inv, axis=0).reshape(m, n_probes * k)
-    pi = jnp.take(pi, inv, axis=0).reshape(m, n_probes * k)
-    dists, idx = select_k_impl(pv, pi, k, not inner, impl=select_impl)
-    if not inner and metric in (DistanceType.L2SqrtExpanded,
-                                DistanceType.L2SqrtUnexpanded):
-        dists = jnp.where(jnp.isfinite(dists),
-                          jnp.sqrt(jnp.maximum(dists, 0.0)), dists)
-    empty = ~jnp.isfinite(dists)
-    idx = jnp.where(empty, -1, idx)
-    return dists, idx
-
-
 @instrument(
     "ivf_pq.search",
     items=lambda a, kw: nrows(a[2] if len(a) > 2 else kw["queries"]),
@@ -2220,7 +1920,6 @@ def search(params: SearchParams, index: IvfPqIndex, queries, k: int,
             "lut_dtype must be 'float32', 'bfloat16' or 'int8', got %r",
             params.lut_dtype)
     n_codes = index.codebooks.shape[-2]
-    scan_impl = resolve_scan_impl(params, index, n_codes)
     expects(params.select_impl in ("auto", "xla", "pallas"),
             "select_impl must be 'auto', 'xla' or 'pallas', got %r",
             params.select_impl)
@@ -2258,22 +1957,7 @@ def search(params: SearchParams, index: IvfPqIndex, queries, k: int,
         from .sample_filter import validate_filter_covers
 
         validate_filter_covers(index, keep_mask)
-    expects(params.scan_order in ("auto", "tiled", "grouped"),
-            "scan_order must be 'auto', 'tiled' or 'grouped', got %r",
-            params.scan_order)
-    scan_order = params.scan_order
-    if scan_order == "auto":
-        # tiled: the grouped order measured neutral at 1M (its 4x operand
-        # -traffic cut bought nothing — the tiled one-hot contraction is not
-        # operand-bound; r04 grouped scan)
-        scan_order = "tiled"
     if use_funnel:
-        expects(scan_order == "tiled",
-                "funnel_widen > 1 rides the tiled scan order; set "
-                "scan_order='tiled' (or 'auto')")
-        expects(scan_impl == "onehot",
-                "funnel_widen > 1 implements the one-hot signature "
-                "contraction; set scan_impl='onehot' (or 'auto')")
         expects(params.lut_dtype != "int8",
                 "lut_dtype='int8' quantizes the PQ LUT; the funnel's "
                 "signature tier is already 1-4 bit — use float32/bfloat16")
@@ -2287,24 +1971,10 @@ def search(params: SearchParams, index: IvfPqIndex, queries, k: int,
             index, queries, n_probes, int(k), k_widen, query_tile,
             probe_chunk, index.metric, index.codebook_kind, params.lut_dtype,
             keep_mask, select_impl=params.select_impl)
-    if scan_order == "grouped":
-        expects(k <= index.capacity,
-                "scan_order='grouped' selects per (pair, list): k=%d must be "
-                "<= capacity=%d", k, index.capacity)
-        expects(scan_impl == "onehot",
-                "scan_order='grouped' implements the one-hot contraction; "
-                "set scan_impl='onehot' (or 'auto')")
-        expects(1 <= params.group_size <= 1024,
-                "group_size must be in [1, 1024], got %d", params.group_size)
-        return _pq_search_grouped(
-            index, queries, n_probes, int(k), index.metric,
-            index.codebook_kind, params.lut_dtype, keep_mask,
-            group_size=int(params.group_size),
-            select_impl=params.select_impl)
     return _pq_search(
         index, queries, n_probes, int(k), query_tile, probe_chunk, index.metric,
         index.codebook_kind, params.lut_dtype,
-        keep_mask, scan_impl=scan_impl, select_impl=params.select_impl,
+        keep_mask, select_impl=params.select_impl,
     )
 
 

@@ -20,12 +20,15 @@ gather, this kernel. Beam state crosses HBM once per hop instead of ~20
 times, and 20 op dispatches collapse into 1.
 
 Layout: beam arrays are (m, 128)-padded (lanes >= itopk carry the empty
-sentinel) so every in-kernel op is full-lane-width; the merge pool packs
-[beam | candidates | pad] into the same 128 lanes with static slice writes.
-Selection is ascending iterative extraction with lowest-id tie-breaks
-(matching the XLA path's two-sort dedup semantics); candidate ids already
-present in the beam are masked before the merge (the beam's copy of a node
-carries the identical exact distance, so keeping it is equivalent).
+sentinel) so every in-kernel op is full-lane-width. The beam is an unsorted
+arena that candidates enter by a threshold-gated insertion, best first with
+lowest-id tie-breaks; the caller sorts it once after the hop loop. A
+candidate id already in the arena is consumed, not inserted (the arena's
+copy of a node carries the identical exact distance). This arena merge is
+the only one. A sorted-extraction merge measured neutral against the XLA
+loop, and the first arena form (gate and candidate pool in SMEM/VMEM
+scratch) was kept only as the control of an A/B no cell ran; both were
+deleted.
 """
 
 from __future__ import annotations
@@ -84,24 +87,9 @@ def hop_shapes_eligible(itopk: int, deg: int, width: int, d: int,
     return 2 * (vec_bytes + q_bytes) <= _HOP_VMEM_BUDGET
 
 
-def _make_hop_kernel(itopk: int, cw: int, width: int, qt: int, dp: int,
-                     profile: str = "full", merge: str = "extract"):
-    """``profile`` carves phases out for the in-kernel profile
-    (bench/cagra_hop_profile.py): "full", "noscore" (skip the distance
-    computation), "nodedup" (skip the beam-membership masks), "nomerge"
-    (skip dedup+extraction — beam passes through, pick still computed),
-    "nogate" (arena merges only: run the insertion loop UNGATED — the
-    full-vs-nogate delta is the threshold gate's measured worth).
-    ``merge``: "extract" (itopk ascending-extraction passes; beam stays
-    sorted), "arena" (threshold-gated insertion into an unsorted arena —
-    the caller sorts once after the loop; r06 form, gate carried in a
-    register and candidate scores carried as loop values), or "arena_smem"
-    (the r05 arena: gate handshake through SMEM, candidate pool stashed in
-    VMEM scratch and re-read per candidate — kept verbatim as the A/B
-    control for the r06 iteration)."""
+def _make_hop_kernel(itopk: int, cw: int, width: int, qt: int):
     def kernel(q_ref, bd_ref, bi_ref, bv_ref, nbr_ref, vec_ref, valid_ref,
-               nbd_ref, nbi_ref, nbv_ref, pick_ref, nocand_ref,
-               pd_ref, pi_ref, pv_ref, go_ref):
+               nbd_ref, nbi_ref, nbv_ref, pick_ref, nocand_ref):
         lane = jax.lax.broadcasted_iota(jnp.int32, (qt, _POOL), 1)
 
         # ---- candidate scoring: direct ||v - q||^2, (qt, cw). The
@@ -111,177 +99,74 @@ def _make_hop_kernel(itopk: int, cw: int, width: int, qt: int, dp: int,
         # and costs far more than the one VPU pass it saves (arena
         # 38k -> 28.5k QPS at 1M).
         nbr = nbr_ref[...]                   # (qt, cw) int32
-        if profile == "noscore":
-            nd = jnp.abs(nbr).astype(jnp.float32)  # fake but well-formed
-        else:
-            q = q_ref[...]                   # (qt, dp)
-            # byte datasets arrive as int8 (a quarter of the f32 DMA bytes
-            # — the hop's vector traffic) and upcast HERE, at the tile
-            # level; 8-bit integers are exact in f32, so the s8 path's
-            # distances match the f32 path's bitwise
-            vecs = vec_ref[...].astype(jnp.float32)  # (qt, cw, dp)
-            diff = vecs - q[:, None, :]
-            nd = jnp.sum(diff * diff, axis=-1)   # (qt, cw)
+        q = q_ref[...]                       # (qt, dp)
+        # byte datasets arrive as int8 (a quarter of the f32 DMA bytes
+        # — the hop's vector traffic) and upcast HERE, at the tile
+        # level; 8-bit integers are exact in f32, so the s8 path's
+        # distances match the f32 path's bitwise
+        vecs = vec_ref[...].astype(jnp.float32)  # (qt, cw, dp)
+        diff = vecs - q[:, None, :]
+        nd = jnp.sum(diff * diff, axis=-1)   # (qt, cw)
         # valid is per-candidate (the XLA side expands the per-pick flags
         # over each pick's deg candidates)
         ok = (nbr >= 0) & (valid_ref[...] > 0)          # (qt, cw)
         nd = jnp.where(ok, nd, _INF)
 
-        # ---- dedup vs the beam: a candidate already in the beam carries
-        # the identical exact distance there — drop the new copy ----
+        # ---- threshold-gated arena merge: the beam is an UNSORTED arena
+        # of itopk slots (sorted once in XLA after the loop); a candidate is
+        # inserted — replacing the arena's current worst — only while the
+        # best remaining candidate beats that worst. Late hops insert ~0-2
+        # candidates, so the whole merge gates off after a couple of
+        # iterations (the fused_knn per-tile-gate insight applied to the
+        # beam), vs itopk unconditional extraction passes. Candidate count
+        # bounds the iterations. A candidate already in the arena carries
+        # the same exact score, so it is consumed at insertion, not
+        # inserted (dedup-on-insert instead of an itopk-pass pre-mask).
+        #
+        # The gate lives in a REGISTER (lax.cond carries it across
+        # iterations as a loop value — no SMEM write+read handshake
+        # serializing the VPU per candidate), candidate scores ride the
+        # fori_loop carry (vregs, no pool-scratch round trips), candidate
+        # ids are the already-loaded nbr (never mutated), and the one
+        # any(improve) reduction both closes the gate and masks the writes.
         bi = bi_ref[...]                     # (qt, _POOL)
-        if profile == "nomerge":
-            nbd_ref[...] = bd_ref[...]
-            nbi_ref[...] = bi
-            nbv_ref[...] = bv_ref[...]
-            _emit_pick(itopk, width, qt, lane, nbd_ref, nbi_ref, nbv_ref,
-                       pick_ref, nocand_ref)
-            return
-        arena = merge in ("arena", "arena_smem") and profile in ("full",
-                                                                 "nogate")
-        if profile != "nodedup" and not arena:
-            for b in range(itopk):
-                nd = jnp.where(nbr == bi[:, b:b + 1], _INF, nd)
+        nbd_ref[...] = bd_ref[...]
+        nbi_ref[...] = bi
+        nbv_ref[...] = bv_ref[...]
+        itmask = lane < itopk
 
-        if arena:
-            # ---- threshold-gated arena merge: the beam is an UNSORTED
-            # arena of itopk slots (sorted once in XLA after the loop); a
-            # candidate is inserted — replacing the arena's current worst —
-            # only while the best remaining candidate beats that worst.
-            # Late hops insert ~0-2 candidates, so the whole merge gates
-            # off after a couple of iterations (the fused_knn per-tile-gate
-            # insight applied to the beam), vs itopk unconditional
-            # extraction passes. Candidate count bounds the iterations.
-            nbd_ref[...] = bd_ref[...]
-            nbi_ref[...] = bi
-            nbv_ref[...] = bv_ref[...]
-            if merge == "arena_smem":
-                # r05 form (the A/B control): gate handshake through an
-                # SMEM scalar, candidate pool stashed in scratch and
-                # re-read per candidate — the ~5 us/query residual the r05
-                # profile named ("gated-loop scalar checks and pool I/O")
-                pd_ref[:, :cw] = nd
-                pi_ref[:, :cw] = nbr
-                go_ref[0] = 1
-                for t in range(cw):
-                    def _insert(t=t):
-                        ad = nbd_ref[...]
-                        admask = jnp.where(lane < itopk, ad, _NEG)
-                        worst = jnp.max(admask, axis=1, keepdims=True)
-                        cd = pd_ref[:, :cw]
-                        best = jnp.min(cd, axis=1, keepdims=True)
-                        improve = best < worst              # (qt, 1)
-                        go_ref[0] = jnp.any(improve).astype(jnp.int32)
+        def _insert_step(_, carry):
+            go, cd = carry
 
-                        @pl.when(jnp.any(improve))
-                        def _apply():
-                            cdv = pd_ref[:, :cw]
-                            civ = pi_ref[:, :cw]
-                            bid = jnp.min(jnp.where(cdv <= best, civ, _BIG),
-                                          axis=1, keepdims=True)
-                            # dedup HERE instead of a 32-pass pre-mask: a
-                            # candidate already in the arena carries the
-                            # same exact score — consume it, don't insert
-                            ai = nbi_ref[...]
-                            dup = jnp.any((ai == bid) & (lane < itopk),
-                                          axis=1, keepdims=True)
-                            ins = improve & jnp.logical_not(dup)
-                            # arena slot to evict: the worst entry, highest
-                            # lane on ties (any one copy)
-                            wsel = (admask >= worst)
-                            wlane = jnp.max(jnp.where(wsel, lane, -1),
-                                            axis=1, keepdims=True)
-                            at = ins & (lane == wlane)
-                            nbd_ref[...] = jnp.where(at, best, ad)
-                            nbi_ref[...] = jnp.where(at, bid, ai)
-                            nbv_ref[...] = jnp.where(at, 0, nbv_ref[...])
-                            # consume the candidate (all copies of its id)
-                            pd_ref[:, :cw] = jnp.where(
-                                improve & (civ == bid), _INF, cdv)
+            def _live():
+                ad = nbd_ref[...]
+                admask = jnp.where(itmask, ad, _NEG)
+                worst = jnp.max(admask, axis=1, keepdims=True)
+                best = jnp.min(cd, axis=1, keepdims=True)
+                improve = best < worst              # (qt, 1)
+                bid = jnp.min(jnp.where(cd <= best, nbr, _BIG),
+                              axis=1, keepdims=True)
+                ai = nbi_ref[...]
+                dup = jnp.any((ai == bid) & itmask, axis=1, keepdims=True)
+                ins = improve & jnp.logical_not(dup)
+                # arena slot to evict: the worst entry, highest lane on
+                # ties (any one copy)
+                wsel = (admask >= worst)
+                wlane = jnp.max(jnp.where(wsel, lane, -1), axis=1,
+                                keepdims=True)
+                at = ins & (lane == wlane)
+                # masked writes: rows whose improve is false keep their
+                # arena untouched, so no inner when-branch
+                nbd_ref[...] = jnp.where(at, best, ad)
+                nbi_ref[...] = jnp.where(at, bid, ai)
+                nbv_ref[...] = jnp.where(at, 0, nbv_ref[...])
+                # consume the candidate (all copies of its id)
+                cd2 = jnp.where(improve & (nbr == bid), _INF, cd)
+                return jnp.any(improve).astype(jnp.int32), cd2
 
-                    if profile == "nogate":
-                        _insert()
-                    else:
-                        pl.when(go_ref[0] == 1)(_insert)
-            else:
-                # r06 form: the gate lives in a REGISTER (lax.cond carries
-                # it across iterations as a loop value — no SMEM write+read
-                # handshake serializing the VPU per candidate), candidate
-                # scores ride the fori_loop carry (vregs, no pool-scratch
-                # round trips), candidate ids are the already-loaded nbr
-                # (never mutated), and the one any(improve) reduction both
-                # closes the gate and masks the writes — the r05 loop paid
-                # it twice plus two scratch re-reads per candidate. The
-                # insertion math (tie-breaks, dedup-on-insert, eviction
-                # lane) is unchanged from arena_smem.
-                itmask = lane < itopk
+            return jax.lax.cond(go == 1, _live, lambda: (jnp.int32(0), cd))
 
-                def _insert_step(_, carry):
-                    go, cd = carry
-
-                    def _live():
-                        ad = nbd_ref[...]
-                        admask = jnp.where(itmask, ad, _NEG)
-                        worst = jnp.max(admask, axis=1, keepdims=True)
-                        best = jnp.min(cd, axis=1, keepdims=True)
-                        improve = best < worst              # (qt, 1)
-                        bid = jnp.min(jnp.where(cd <= best, nbr, _BIG),
-                                      axis=1, keepdims=True)
-                        ai = nbi_ref[...]
-                        dup = jnp.any((ai == bid) & itmask, axis=1,
-                                      keepdims=True)
-                        ins = improve & jnp.logical_not(dup)
-                        wsel = (admask >= worst)
-                        wlane = jnp.max(jnp.where(wsel, lane, -1), axis=1,
-                                        keepdims=True)
-                        at = ins & (lane == wlane)
-                        # masked writes: rows whose improve is false keep
-                        # their arena untouched, so no inner when-branch
-                        nbd_ref[...] = jnp.where(at, best, ad)
-                        nbi_ref[...] = jnp.where(at, bid, ai)
-                        nbv_ref[...] = jnp.where(at, 0, nbv_ref[...])
-                        cd2 = jnp.where(improve & (nbr == bid), _INF, cd)
-                        return jnp.any(improve).astype(jnp.int32), cd2
-
-                    if profile == "nogate":
-                        return _live()
-                    return jax.lax.cond(go == 1, _live,
-                                        lambda: (jnp.int32(0), cd))
-
-                jax.lax.fori_loop(0, cw, _insert_step, (jnp.int32(1), nd))
-        else:
-            # ---- merge pool: [beam | candidates | +inf pad], one row ----
-            pd_ref[...] = bd_ref[...]
-            pi_ref[...] = bi
-            pv_ref[...] = bv_ref[...]
-            pd_ref[:, itopk:itopk + cw] = nd
-            pi_ref[:, itopk:itopk + cw] = nbr
-            pv_ref[:, itopk:itopk + cw] = jnp.zeros((qt, cw), jnp.int32)
-            pd_ref[:, itopk + cw:] = jnp.full((qt, _POOL - itopk - cw), _INF,
-                                              jnp.float32)
-            pi_ref[:, itopk + cw:] = jnp.full((qt, _POOL - itopk - cw), -1,
-                                              jnp.int32)
-            pv_ref[:, itopk + cw:] = jnp.ones((qt, _POOL - itopk - cw),
-                                              jnp.int32)
-            # ---- ascending extraction with lowest-id ties: the in-VMEM
-            # form of the XLA path's lexsort+sort dedup merge ----
-            nbd_ref[...] = jnp.full((qt, _POOL), _INF, jnp.float32)
-            nbi_ref[...] = jnp.full((qt, _POOL), -1, jnp.int32)
-            nbv_ref[...] = jnp.ones((qt, _POOL), jnp.int32)
-            for t in range(itopk):
-                pdv = pd_ref[...]
-                mn = jnp.min(pdv, axis=1, keepdims=True)
-                sel = pdv <= mn                          # winners incl. ties
-                amid = jnp.min(jnp.where(sel, pi_ref[...], _BIG), axis=1,
-                               keepdims=True)
-                hit = (pi_ref[...] == amid) & sel
-                wv = jnp.min(jnp.where(hit, pv_ref[...], _BIG), axis=1,
-                             keepdims=True)
-                nbd_ref[:, t] = mn[:, 0]
-                nbi_ref[:, t] = jnp.where(mn[:, 0] < _INF, amid[:, 0], -1)
-                nbv_ref[:, t] = jnp.minimum(wv[:, 0], 1)
-                # mask every copy of the chosen id (kills in-row dups too)
-                pd_ref[...] = jnp.where(pi_ref[...] == amid, _INF, pdv)
+        jax.lax.fori_loop(0, cw, _insert_step, (jnp.int32(1), nd))
 
         _emit_pick(itopk, width, qt, lane, nbd_ref, nbi_ref, nbv_ref,
                    pick_ref, nocand_ref)
@@ -310,16 +195,15 @@ def _emit_pick(itopk, width, qt, lane, nbd_ref, nbi_ref, nbv_ref, pick_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("itopk", "width", "qt",
-                                             "interpret", "profile", "merge"))
+                                             "interpret"))
 def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
               itopk: int, width: int = 1, qt: int = 128,
-              interpret: bool = False, profile: str = "full",
-              merge: str = "extract"):
+              interpret: bool = False):
     """One fused CAGRA hop over the whole query batch.
 
     ``queries`` (m, d) f32; ``beam_d/beam_i/beam_v`` (m, 128) padded beam
-    state (distances f32 ascending, ids i32, visited i32; lanes >= itopk are
-    +inf/-1/1); ``nbrs`` (m, cw) i32 candidate ids for cw = width*degree
+    state (the unsorted arena: distances f32, ids i32, visited i32; lanes
+    >= itopk are +inf/-1/1); ``nbrs`` (m, cw) i32 candidate ids for cw = width*degree
     (-1 = none); ``vecs`` (m, cw, d) their vectors — f32, or int8 for byte
     datasets (upcast in-kernel at the tile level: quarter the DMA bytes,
     bitwise-identical distances); ``valid`` (m, cw) i32 —
@@ -329,11 +213,6 @@ def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
     Returns (beam_d, beam_i, beam_v, pick (m, width) i32 clipped >= 0,
     no_cand (m, width) i32). Beam distances are full ||v - q||^2.
     """
-    if merge not in ("extract", "arena", "arena_smem"):
-        raise ValueError(f"merge must be 'extract', 'arena' or 'arena_smem', "
-                         f"got {merge!r}")
-    if profile not in ("full", "noscore", "nodedup", "nomerge", "nogate"):
-        raise ValueError(f"unknown profile {profile!r}")
     m, d = queries.shape
     cw = nbrs.shape[1]
     dp = -(-d // 128) * 128
@@ -353,7 +232,7 @@ def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
     spec2 = lambda w: pl.BlockSpec((qt, w), lambda i: (i, 0),
                                    memory_space=pltpu.VMEM)
     outs = pl.pallas_call(
-        _make_hop_kernel(itopk, cw, width, qt, dp, profile, merge),
+        _make_hop_kernel(itopk, cw, width, qt),
         grid=grid,
         in_specs=[spec2(dp), spec2(_POOL), spec2(_POOL), spec2(_POOL),
                   spec2(cw),
@@ -368,12 +247,6 @@ def cagra_hop(queries, beam_d, beam_i, beam_v, nbrs, vecs, valid,
             jax.ShapeDtypeStruct((mp, _POOL), jnp.int32),
             jax.ShapeDtypeStruct((mp, width), jnp.int32),
             jax.ShapeDtypeStruct((mp, width), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qt, _POOL), jnp.float32),   # merge pool distances
-            pltpu.VMEM((qt, _POOL), jnp.int32),     # merge pool ids
-            pltpu.VMEM((qt, _POOL), jnp.int32),     # merge pool visited
-            pltpu.SMEM((1,), jnp.int32),            # arena insertion gate
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),

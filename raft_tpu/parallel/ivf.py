@@ -262,19 +262,14 @@ def search_pq(comms: Comms, params, index, queries, k: int,
     expects(params.lut_dtype in ("float32", "bfloat16", "int8"),
             "lut_dtype must be 'float32', 'bfloat16' or 'int8', got %r",
             params.lut_dtype)
-    # same validation + resolution as the single-chip search (auto = the
-    # one-hot contraction, fastest measured — r04 scan study),
-    # and the same clear error for a pq_split index missing its cross terms
-    from ..neighbors.ivf_pq import _check_split_consts, resolve_scan_impl
+    # the same clear error as the single-chip search for a pq_split index
+    # missing its cross terms
+    from ..neighbors.ivf_pq import _check_split_consts
 
     _check_split_consts(index)
-    scan_impl = resolve_scan_impl(params, index, n_codes)
     expects(not index.scale_normed,
             "distributed PQ search does not shard list_scales yet; a "
             "residual_scale_norm index is single-chip only")
-    expects(params.scan_order in ("auto", "tiled"),
-            "the distributed search runs the tiled scan order; "
-            "scan_order=%r is single-chip only", params.scan_order)
 
     mesh, axis = comms.mesh, comms.axis
     cb_arg = (shard_along(mesh, axis, index.codebooks) if per_cluster
@@ -293,29 +288,27 @@ def search_pq(comms: Comms, params, index, queries, k: int,
     fn = _pq_search_fn(comms, int(n_probes), int(k), int(query_tile),
                        int(probe_chunk), index.metric, index.codebook_kind,
                        int(index.pq_bits), float(index.split_factor),
-                       bool(index.pq_split), params.lut_dtype, scan_impl)
+                       bool(index.pq_split), params.lut_dtype)
     return fn(*args)
 
 
 def _pq_search_fn(comms: Comms, n_probes: int, k: int, query_tile: int,
                   probe_chunk: int, metric, codebook_kind: str, pq_bits: int,
-                  split_factor: float, pq_split: bool, lut_dtype: str,
-                  scan_impl: str):
+                  split_factor: float, pq_split: bool, lut_dtype: str):
     """Memoized jitted PQ-search program (see _flat_search_fn); the
     rotation travels as a replicated argument, not a closure constant, so
     two indexes of the same config share one compiled program."""
     key = (comms, "pq", n_probes, k, query_tile, probe_chunk, metric,
-           codebook_kind, pq_bits, split_factor, pq_split, lut_dtype,
-           scan_impl)
+           codebook_kind, pq_bits, split_factor, pq_split, lut_dtype)
     return _PROGRAMS.get_or_build(key, lambda: _build_pq_search_fn(
         comms, n_probes, k, query_tile, probe_chunk, metric, codebook_kind,
-        pq_bits, split_factor, pq_split, lut_dtype, scan_impl))
+        pq_bits, split_factor, pq_split, lut_dtype))
 
 
 def _build_pq_search_fn(comms: Comms, n_probes: int, k: int, query_tile: int,
                         probe_chunk: int, metric, codebook_kind: str,
                         pq_bits: int, split_factor: float, pq_split: bool,
-                        lut_dtype: str, scan_impl: str):
+                        lut_dtype: str):
     from ..neighbors.ivf_pq import IvfPqIndex, _pq_search
 
     size = comms.size()
@@ -335,7 +328,7 @@ def _build_pq_search_fn(comms: Comms, n_probes: int, k: int, query_tile: int,
                 shard, q, n_probes, k,
                 query_tile=query_tile, probe_chunk=probe_chunk,
                 metric=metric, codebook_kind=codebook_kind,
-                lut_dtype=lut_dtype, scan_impl=scan_impl)
+                lut_dtype=lut_dtype)
         with tracing.scope("parallel.ivf.merge"):
             d_all = comms.allgather(d_loc)
             i_all = comms.allgather(i_loc)

@@ -39,8 +39,8 @@ __all__ = ["tuned_search_params", "search_fn", "make_searcher", "attach",
 _PARAM_FIELDS = {
     "brute_force": frozenset(),
     "ivf_flat": frozenset({"n_probes"}),
-    "ivf_pq": frozenset({"n_probes", "lut_dtype", "scan_impl", "scan_order",
-                         "group_size", "select_impl", "funnel_widen"}),
+    "ivf_pq": frozenset({"n_probes", "lut_dtype", "select_impl",
+                         "funnel_widen"}),
     "cagra": frozenset({"itopk_size", "max_iterations", "search_width",
                         "seed_pool", "hop_impl"}),
 }

@@ -143,11 +143,11 @@ class TestSeedPool:
 
 class TestFusedHop:
     """The fused Pallas hop kernel (ops/cagra_hop.py, VERDICT r4 #1) must
-    reproduce the XLA hop loop: same beam semantics (ascending dedup merge,
-    lowest-id ties, visited tracking), so same neighbor sets and distances
-    up to summation order."""
+    reproduce the XLA hop loop: same beam semantics (dedup, lowest-id ties,
+    visited tracking; the arena is sorted once after the loop), so same
+    neighbor sets and distances up to summation order."""
 
-    @pytest.mark.parametrize("impl", ["fused", "fused_arena", "fused_arena_smem"])
+    @pytest.mark.parametrize("impl", ["fused_arena"])
     def test_matches_xla_loop(self, index, data, monkeypatch, impl):
         monkeypatch.setenv("RAFT_TPU_CAGRA_HOP_INTERPRET", "1")
         x, q = data
@@ -165,7 +165,7 @@ class TestFusedHop:
                                    np.sort(np.asarray(d_x), 1),
                                    rtol=1e-4, atol=1e-4)
 
-    @pytest.mark.parametrize("impl", ["fused", "fused_arena", "fused_arena_smem"])
+    @pytest.mark.parametrize("impl", ["fused_arena"])
     def test_recall_on_clustered(self, monkeypatch, impl):
         monkeypatch.setenv("RAFT_TPU_CAGRA_HOP_INTERPRET", "1")
         x, _ = make_blobs(3000, 24, n_clusters=30, cluster_std=0.5, seed=2)
@@ -197,7 +197,7 @@ class TestFusedHop:
         np.testing.assert_allclose(np.asarray(d_f), d_true, rtol=1e-4,
                                    atol=1e-4)
 
-    @pytest.mark.parametrize("impl", ["fused", "fused_arena", "fused_arena_smem"])
+    @pytest.mark.parametrize("impl", ["fused_arena"])
     def test_matches_xla_loop_width2(self, index, data, monkeypatch, impl):
         """search_width=2: two picks per hop, candidate block 2*deg — must
         still track the XLA loop."""
@@ -220,10 +220,21 @@ class TestFusedHop:
 
         _, q = data
         # itopk 64 + 3*24 = 136 > 128: pool does not fit one register row
-        with pytest.raises(RaftError, match="hop_impl='fused'"):
+        with pytest.raises(RaftError, match="hop_impl='fused_arena'"):
             cagra.search(cagra.SearchParams(
-                itopk_size=64, search_width=3, hop_impl="fused"),
+                itopk_size=64, search_width=3, hop_impl="fused_arena"),
                 index, q, k=5)
+
+    @pytest.mark.parametrize("impl", ["fused", "fused_arena_smem"])
+    def test_deleted_hop_impls_rejected(self, impl):
+        """The sorted-extraction and r05 arena hops were deleted: naming
+        them fails loudly with the accepted values, never falls back."""
+        from raft_tpu.core import RaftError
+
+        with pytest.raises(RaftError,
+                           match="'auto', 'xla' or 'fused_arena'"):
+            cagra.resolve_hop_impl(
+                cagra.SearchParams(itopk_size=32, hop_impl=impl), 12, 24)
 
 
 class TestSeedPoolAuto:

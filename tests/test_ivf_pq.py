@@ -390,74 +390,112 @@ def test_int8_lut(rng):
     assert rec8 > rec32 - 0.1, (rec8, rec32)
 
 
-class TestScanImpls:
-    """The scan formulations (SearchParams.scan_impl) must agree: the one-hot
-    MXU contraction, the XLA compare+select chain, and the Pallas
-    dynamic-gather kernel (interpret mode on the CPU test platform) are three
-    spellings of the same Σ_s LUT[s, code_s] (r04 scan study)."""
+def _probed_lists(idx, q, n_probes, inner=False):
+    """(m, n_probes) probed list ids, by the coarse select in float64."""
+    cen = np.asarray(idx.centers, np.float64)
+    qd = np.asarray(q, np.float64)
+    cd = -(qd @ cen.T) if inner else (
+        (cen * cen).sum(1)[None, :] - 2.0 * qd @ cen.T)
+    return np.argsort(cd, axis=1, kind="stable")[:, :n_probes]
 
+
+def _decode_list(idx, qrot, lst, lut_dtype):
+    """(ids, float64 ||q_rot - c_rot - decode(code)||^2, LUT error bound)
+    for every stored row of list ``lst``: the one-hot scan's score,
+    recomputed from the stored codes (pq4: one codeword per subspace;
+    nibble-split pq8: the hi and lo stage codewords summed). The bound is
+    what rounding the LUT to ``lut_dtype`` may add: each summed entry moves
+    by at most 2^-8 of its magnitude (bfloat16) or half the symmetric int8
+    step amax/127."""
+    ids = np.asarray(idx.list_ids)[lst]
+    live = ids >= 0
+    codes = np.asarray(idx.list_codes)[lst][live].astype(np.int64)
+    cb = np.asarray(idx.codebooks, np.float64)         # (pq_dim, K, pq_len)
+    sub = np.arange(idx.pq_dim)
+    if idx.pq_split:
+        dec = cb[sub, codes >> 4] + cb[sub, 16 + (codes & 15)]
+    else:
+        dec = cb[sub, codes]                           # (n, pq_dim, pq_len)
+    resid = (qrot - np.asarray(idx.centers_rot, np.float64)[lst]
+             ).reshape(idx.pq_dim, 1, idx.pq_len)
+    dist = ((resid[:, 0][None] - dec) ** 2).sum(axis=(1, 2))
+    lut = (cb * cb).sum(-1) - 2.0 * (resid * cb).sum(-1)  # (pq_dim, K)
+    n_terms = idx.pq_dim * (2 if idx.pq_split else 1)
+    bound = {"float32": 0.0, "bfloat16": 2.0 ** -8,
+             "int8": 1.0 / 254.0}[lut_dtype] * n_terms * np.abs(lut).max()
+    return ids[live], dist, bound
+
+
+@pytest.fixture(scope="module")
+def onehot_indexes(data):
+    x, _ = data
+    return {bits: ivf_pq.build(ivf_pq.IndexParams(
+        n_lists=32, pq_dim=8, pq_bits=bits, seed=0), x) for bits in (4, 8)}
+
+
+class TestOneHotScan:
+    """The one-hot MXU contraction is the only scan: its scores are the
+    decoded distances of the stored codes, in every LUT dtype and in the
+    nibble-split pq8 form, and its filter epilogue never serves a banned
+    row."""
+
+    @pytest.mark.parametrize("lut", ["float32", "bfloat16", "int8"])
     @pytest.mark.parametrize("bits", [4, 8])
-    def test_impls_agree(self, data, bits, monkeypatch):
-        monkeypatch.setenv("RAFT_TPU_PQ_SCAN_INTERPRET", "1")
-        x, q = data
-        idx = ivf_pq.build(
-            ivf_pq.IndexParams(n_lists=32, pq_dim=8, pq_bits=bits, seed=0), x)
-        outs = {}
-        for impl in ("onehot", "select", "pallas"):
-            d, i = ivf_pq.search(
-                ivf_pq.SearchParams(n_probes=8, scan_impl=impl), idx, q, 10)
-            outs[impl] = (np.asarray(d), np.asarray(i))
-        d0, i0 = outs["onehot"]
-        for impl in ("select", "pallas"):
-            d, i = outs[impl]
-            # tie-robust: the formulations sum scores in different orders, so
-            # near-tied candidates may swap ranks — compare id SETS per row
-            # and the sorted distances, not positional ids
-            for r in range(i.shape[0]):
-                assert set(i[r].tolist()) == set(i0[r].tolist()), (impl, r)
-            np.testing.assert_allclose(np.sort(d, 1), np.sort(d0, 1),
-                                       rtol=1e-5, atol=1e-4, err_msg=impl)
+    def test_scores_match_numpy_decode(self, data, onehot_indexes, bits,
+                                       lut):
+        _, q = data
+        idx = onehot_indexes[bits]
+        assert idx.codebook_kind == "per_subspace"
+        assert idx.pq_split == (bits == 8)
+        n_probes, k = 8, 10
+        d, i = ivf_pq.search(ivf_pq.SearchParams(
+            n_probes=n_probes, lut_dtype=lut), idx, q, k)
+        d, i = np.asarray(d, np.float64), np.asarray(i)
+        qrot = np.asarray(q, np.float64) @ np.asarray(idx.rotation,
+                                                      np.float64).T
+        probes = _probed_lists(idx, q, n_probes)
+        for r in range(len(q)):
+            parts = [_decode_list(idx, qrot[r], lst, lut)
+                     for lst in probes[r]]
+            ids = np.concatenate([p[0] for p in parts])
+            want = np.concatenate([p[1] for p in parts])
+            tol = (max(p[2] for p in parts)
+                   # f32 accumulation of large cancelling terms (||r||^2
+                   # bias, LUT entries, pq8 cross constants)
+                   + 1e-2 + 1e-5 * want.max())
+            by_id = dict(zip(ids.tolist(), want.tolist()))
+            assert (i[r] >= 0).all(), r
+            for c in range(k):
+                assert i[r, c] in by_id, (r, c)   # served from a probed list
+                assert abs(d[r, c] - by_id[i[r, c]]) <= tol, (r, c, lut)
+            # tie-robust top-k: every served id is within the scan's error
+            # of the true k-th decoded distance, and every id clearly
+            # inside the true top k is served
+            kth = np.sort(want)[k - 1]
+            served = set(i[r].tolist())
+            assert all(by_id[j] <= kth + 2 * tol for j in served), r
+            assert set(ids[want < kth - 2 * tol].tolist()) <= served, r
 
-    @pytest.mark.parametrize("S", [24, 96, 192])
-    def test_odd_lane_widths_padded(self, S, monkeypatch):
-        """pq_dim values that neither divide nor are a multiple of 128 (e.g.
-        96, 24 — reachable via pq_bits=4 builds) must route through the
-        zero-LUT lane padding, not hand Mosaic a non-128-aligned lane dim
-        (r04 advisor finding). Direct kernel parity vs the numpy sum."""
-        import jax.numpy as jnp
-
-        from raft_tpu.ops.pq_scan import pq_lut_scan
-
-        rng = np.random.default_rng(0)
-        B, cap = 3, 40
-        codes = rng.integers(0, 16, (B, cap, S), dtype=np.int8)
-        lut = rng.normal(size=(B, 16, S)).astype(np.float32)
-        got = np.asarray(pq_lut_scan(
-            jnp.asarray(codes), jnp.asarray(lut), interpret=True))
-        want = np.take_along_axis(
-            lut[:, :, None, :].transpose(0, 2, 1, 3),
-            codes[:, :, None, :].astype(np.int64), axis=2
-        )[:, :, 0, :].sum(-1)
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
-
-    def test_narrow_stage_guard(self, data):
-        from raft_tpu.core import RaftError
-
+    @pytest.mark.parametrize("metric", ["sqeuclidean", "inner_product"])
+    def test_sample_filter_never_serves_banned(self, data, metric):
         x, q = data
         idx = ivf_pq.build(ivf_pq.IndexParams(
-            n_lists=16, pq_dim=8, pq_bits=8, pq8_split=False, seed=0), x)
-        with pytest.raises(RaftError, match="16-wide"):
-            ivf_pq.search(ivf_pq.SearchParams(n_probes=8, scan_impl="select"),
-                          idx, q, 10)
-
-    def test_int8_lut_needs_onehot(self, data):
-        from raft_tpu.core import RaftError
-
-        x, q = data
-        idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=32, pq_dim=8, seed=0), x)
-        with pytest.raises(RaftError, match="one-hot"):
-            ivf_pq.search(ivf_pq.SearchParams(
-                n_probes=8, lut_dtype="int8", scan_impl="select"), idx, q, 10)
+            n_lists=32, pq_dim=8, metric=metric, seed=0), x)
+        keep = np.ones(len(x), bool)
+        keep[::3] = False
+        k = 10
+        _, got = ivf_pq.search(ivf_pq.SearchParams(n_probes=8), idx, q, k,
+                               sample_filter=keep)
+        got = np.asarray(got)
+        banned = set(np.nonzero(~keep)[0].tolist())
+        assert not (set(got[got >= 0].ravel().tolist()) & banned)
+        # each row fills to k while its probed lists hold k surviving rows
+        lids = np.asarray(idx.list_ids)
+        probes = _probed_lists(idx, q, 8, inner=metric == "inner_product")
+        for r, row in enumerate(got):
+            ids = lids[probes[r]]
+            surviving = int(keep[ids[ids >= 0]].sum())
+            assert (row >= 0).sum() == min(k, surviving), (r, surviving)
 
     def test_split_consts_validated(self, data):
         import dataclasses
@@ -475,61 +513,6 @@ class TestScanImpls:
             ivf_pq.search(ivf_pq.SearchParams(n_probes=8), broken, q, 10)
         with pytest.raises(RaftError, match="list_consts"):
             ivf_pq.extend(broken, x[:8])
-
-
-class TestGroupedScan:
-    """scan_order='grouped' (probe-major, shared one-hot per list group) must
-    agree with the tiled order across metrics, bit widths, LUT dtypes and
-    filters — same candidates scored by the same quantizer, only the batching
-    differs (r04 grouped scan)."""
-
-    @pytest.mark.parametrize("bits", [4, 8])
-    def test_matches_tiled(self, data, bits):
-        x, q = data
-        idx = ivf_pq.build(
-            ivf_pq.IndexParams(n_lists=32, pq_dim=8, pq_bits=bits, seed=0), x)
-        for lut in ("float32", "int8"):
-            d1, i1 = ivf_pq.search(ivf_pq.SearchParams(
-                n_probes=8, lut_dtype=lut, scan_order="tiled"), idx, q, 10)
-            d2, i2 = ivf_pq.search(ivf_pq.SearchParams(
-                n_probes=8, lut_dtype=lut, scan_order="grouped"), idx, q, 10)
-            i1, i2 = np.asarray(i1), np.asarray(i2)
-            overlap = np.mean([len(set(a) & set(b)) / 10
-                               for a, b in zip(i1.tolist(), i2.tolist())])
-            assert overlap > 0.98, (bits, lut, overlap)  # near-ties may swap
-            np.testing.assert_allclose(
-                np.sort(np.asarray(d1), 1), np.sort(np.asarray(d2), 1),
-                rtol=1e-3, atol=1e-2)
-
-    def test_inner_product_and_filter(self, data):
-        x, q = data
-        idxip = ivf_pq.build(ivf_pq.IndexParams(
-            n_lists=32, pq_dim=8, metric="inner_product", seed=0), x)
-        _, i1 = ivf_pq.search(ivf_pq.SearchParams(
-            n_probes=8, scan_order="tiled"), idxip, q, 10)
-        _, i2 = ivf_pq.search(ivf_pq.SearchParams(
-            n_probes=8, scan_order="grouped"), idxip, q, 10)
-        overlap = np.mean([len(set(a) & set(b)) / 10 for a, b in
-                           zip(np.asarray(i1).tolist(), np.asarray(i2).tolist())])
-        assert overlap > 0.98, overlap
-
-        keep = np.ones(len(x), bool)
-        keep[::3] = False
-        idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=32, pq_dim=8, seed=0), x)
-        _, ig = ivf_pq.search(ivf_pq.SearchParams(
-            n_probes=8, scan_order="grouped"), idx, q, 10, sample_filter=keep)
-        ig = np.asarray(ig)
-        banned = set(np.nonzero(~keep)[0].tolist())
-        assert not (set(ig[ig >= 0].ravel().tolist()) & banned)
-
-    def test_k_capacity_guard(self, data):
-        from raft_tpu.core import RaftError
-
-        x, q = data
-        idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=32, pq_dim=8, seed=0), x)
-        with pytest.raises(RaftError, match="capacity"):
-            ivf_pq.search(ivf_pq.SearchParams(
-                n_probes=32, scan_order="grouped"), idx, q, idx.capacity + 1)
 
 
 class TestByteDatasets:
@@ -694,19 +677,6 @@ class TestResidualScaleNorm:
                 want = float(((resid - scales[l] * decode) ** 2).sum())
                 np.testing.assert_allclose(d_got[r, c], want, rtol=2e-3,
                                            atol=2e-3)
-
-    def test_grouped_order_matches_tiled(self, rng):
-        x, q, _ = self._skewed(rng, n=3000, ncl=16)
-        idx = ivf_pq.build(ivf_pq.IndexParams(
-            n_lists=16, pq_bits=4, pq_dim=8, residual_scale_norm=True,
-            seed=0), x)
-        sp_t = ivf_pq.SearchParams(n_probes=4, scan_order="tiled")
-        sp_g = ivf_pq.SearchParams(n_probes=4, scan_order="grouped")
-        d_t, i_t = ivf_pq.search(sp_t, idx, q[:64], 5)
-        d_g, i_g = ivf_pq.search(sp_g, idx, q[:64], 5)
-        np.testing.assert_array_equal(np.asarray(i_t), np.asarray(i_g))
-        np.testing.assert_allclose(np.asarray(d_t), np.asarray(d_g),
-                                   rtol=1e-5, atol=1e-5)
 
     def test_pq8_split_consts_carry_scale(self, rng):
         """pq_split stores the 2*cb1·cb2 cross term per vector; with scale
@@ -971,14 +941,6 @@ class TestQuantFunnel:
         with pytest.raises(RaftError):
             ivf_pq.search(ivf_pq.SearchParams(n_probes=8, funnel_widen=0),
                           funnel, q, k=10)
-        with pytest.raises(RaftError, match="tiled"):
-            ivf_pq.search(
-                ivf_pq.SearchParams(n_probes=8, funnel_widen=2,
-                                    scan_order="grouped"), funnel, q, k=10)
-        with pytest.raises(RaftError, match="one-hot|onehot"):
-            ivf_pq.search(
-                ivf_pq.SearchParams(n_probes=8, funnel_widen=2,
-                                    scan_impl="select"), funnel, q, k=10)
         with pytest.raises(RaftError, match="int8"):
             ivf_pq.search(
                 ivf_pq.SearchParams(n_probes=8, funnel_widen=2,

@@ -700,8 +700,6 @@ def _assert_same(got, want):
 _LIST_KINDS = {
     "ivf_pq.tiled": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0),
                      dict(n_probes=4)),
-    "ivf_pq.grouped": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0),
-                       dict(n_probes=4, scan_order="grouped")),
     "ivf_pq.funnel": (ivf_pq, dict(n_lists=8, pq_dim=8, seed=0,
                                    fast_scan="1bit"),
                       dict(n_probes=4, funnel_widen=4)),

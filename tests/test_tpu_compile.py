@@ -65,7 +65,7 @@ def test_cagra_hop_arena_itopk32(one_chip):
 
     m, deg, d = 1_000, 32, 128
     i32 = jnp.int32
-    fn = functools.partial(cagra_hop, itopk=32, width=1, merge="arena")
+    fn = functools.partial(cagra_hop, itopk=32, width=1)
     text = _compile_text(fn, _spec(one_chip, (m, d)),
                          _spec(one_chip, (m, 128)),
                          _spec(one_chip, (m, 128), i32),

@@ -226,11 +226,9 @@ kd, kq = jax.random.split(jax.random.key(0))
 x = jax.random.uniform(kd, (2000, 16), jnp.float32)
 q = jax.random.uniform(kq, (64, 16), jnp.float32)
 idx = ivf_pq.build(ip, x)
-before = (ivf_pq._pq_search._cache_size(),
-          ivf_pq._pq_search_grouped._cache_size())
+before = ivf_pq._pq_search._cache_size()
 ivf_pq.search(sp, idx, q, 7)
-after = (ivf_pq._pq_search._cache_size(),
-         ivf_pq._pq_search_grouped._cache_size())
+after = ivf_pq._pq_search._cache_size()
 assert after == before, ("production k=7 search re-traced after a k=7 "
                          "warmup", before, after)
 print("WARMK_OK")
