@@ -20,15 +20,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import tracing
 from ..core.errors import expects
+from ..core.held import HeldByArray, concrete
 from ..core.resources import Resources, default_resources
 from ..distance.pairwise import _PRECISIONS, _choose_tile, _pairwise, _pad_to_tiles
 from ..distance.types import DistanceType, resolve_metric
 from ..matrix.select_k import select_k
 from ..obs import mem as obs_mem
+from ..obs import metrics as _metrics
 from ..obs.instrument import dtype_of, instrument, nrows
 
-__all__ = ["knn", "knn_merge_parts", "BruteForce"]
+__all__ = ["knn", "knn_merge_parts", "search", "BruteForce"]
 
 # metrics the fused Pallas kernel handles natively (ops/fused_knn.py);
 # everything else stays on the XLA GEMM + top_k path
@@ -42,7 +45,7 @@ _FUSED_L2 = {
 
 def _as_signed(x):
     """uint8 -> int8 by the -128 shift (L2-invariant; ip callers correct via
-    row_bias); int8 passes through. The reference instantiates int8_t and
+    the row operand's bias); int8 passes through. The reference instantiates int8_t and
     uint8_t kernels separately (cpp/src/neighbors/*_int8_t_*.cu /
     *_uint8_t_*.cu); on TPU the MXU's integer path is s8 x s8, so uint8
     rides the same kernel shifted."""
@@ -69,11 +72,13 @@ def _coerce_queries(data_kind: str, queries):
     return queries
 
 
-def _bf_knn_s8(dataset, queries, k, metric, keep_mask):
+def _bf_knn_s8(dataset, queries, k, metric, keep_mask, rows=None):
     """int8 MXU dispatch (~2x bf16 peak, 1-byte operand DMAs). Distances are
-    EXACT integers for d <= ~340 (see ops/fused_knn mode='s8')."""
+    EXACT integers for d <= ~340 (see ops/fused_knn mode='s8'). ``rows``
+    is :func:`_rows_of` of the dataset (default: computed per call)."""
     from ..ops.fused_knn import fused_backend_ok, fused_knn
 
+    rows = rows or _rows_of(dataset, None)
     _, interpret = fused_backend_ok()
     shifted = dataset.dtype == jnp.uint8
     ds = _as_signed(dataset)
@@ -81,17 +86,16 @@ def _bf_knn_s8(dataset, queries, k, metric, keep_mask):
     if metric in _FUSED_L2:
         return fused_knn(ds, qs, k, metric="l2", mode="s8",
                          keep_mask=keep_mask, sqrt=_FUSED_L2[metric],
-                         interpret=interpret)
+                         row_operand=rows("l2"), interpret=interpret)
     # inner product: q·v = q'·v' + 128·Σv' + 128·Σq' + 128²·d for shifted
-    # operands — the Σv' term rides the kernel's row-bias operand, the
+    # operands — the Σv' term rides the kernel's row operand, the
     # per-query constant is added outside
     if not shifted:
         return fused_knn(ds, qs, k, metric="ip", mode="s8",
                          keep_mask=keep_mask, interpret=interpret)
     d = dataset.shape[1]
-    row_bias = -128.0 * jnp.sum(ds.astype(jnp.float32), axis=1)
     sim, idx = fused_knn(ds, qs, k, metric="ip", mode="s8",
-                         keep_mask=keep_mask, row_bias=row_bias,
+                         keep_mask=keep_mask, row_operand=rows("ip_shift"),
                          interpret=interpret)
     qconst = (128.0 * jnp.sum(qs.astype(jnp.float32), axis=1, keepdims=True)
               + 16384.0 * d)
@@ -112,16 +116,19 @@ def _fused_eligible(metric, k, n, d, mode, compute):
     )
 
 
-def _bf_knn_fused(dataset, queries, k, metric, compute, keep_mask):
-    """Route to the fused Pallas kernel (scores never leave VMEM)."""
+def _bf_knn_fused(dataset, queries, k, metric, compute, keep_mask,
+                  rows=None):
+    """Route to the fused Pallas kernel (scores never leave VMEM); ``rows``
+    as for :func:`_bf_knn_s8`."""
     from ..ops.fused_knn import fused_backend_ok, fused_knn
 
+    rows = rows or _rows_of(dataset, None)
     mode = {"float32": "f32", "float32x3": "f32x3", "bfloat16": "bf16"}[compute]
     _, interpret = fused_backend_ok()
     if metric in _FUSED_L2:
         return fused_knn(dataset, queries, k, metric="l2", mode=mode,
                          keep_mask=keep_mask, sqrt=_FUSED_L2[metric],
-                         interpret=interpret)
+                         row_operand=rows("l2"), interpret=interpret)
     if metric == DistanceType.InnerProduct:
         return fused_knn(dataset, queries, k, metric="ip", mode=mode,
                          keep_mask=keep_mask, interpret=interpret)
@@ -135,6 +142,75 @@ def _bf_knn_fused(dataset, queries, k, metric, compute, keep_mask):
                          interpret=interpret)
     dist = jnp.where(jnp.isinf(sim), jnp.inf, 1.0 - sim)
     return dist, idx
+
+
+# -- the row operand and its holder ------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("kind",))
+def _row_operand(dataset, kind: str):
+    """What the fused kernel subtracts from each row's score, over the rows
+    as the kernel reads them (uint8 shifted into int8): ``kind="l2"`` the
+    squared norms, ``"ip_shift"`` the shifted uint8 inner product's row
+    bias. One program of its own (device stage ``brute_force.norms``)."""
+    with tracing.scope("brute_force.norms"):
+        x = _as_signed(dataset).astype(jnp.float32)
+        if kind == "l2":
+            return jnp.sum(x ** 2, axis=1)
+        return -128.0 * jnp.sum(x, axis=1)
+
+
+def _operand_kind(metric, dtype) -> str | None:
+    """The row operand a fused search of ``metric`` over a dataset of
+    ``dtype`` reads, or None when it reads none."""
+    mt = resolve_metric(metric)
+    if mt in _FUSED_L2:
+        return "l2"
+    if mt == DistanceType.InnerProduct and dtype == jnp.uint8:
+        return "ip_shift"
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _norms_total():
+    return _metrics.counter(
+        "raft_tpu_brute_force_norms_total",
+        "brute-force row norms by whether a search found them held "
+        "(labels: result=hit|miss)")
+
+
+class _NormCache(HeldByArray):
+    """Row operands per dataset array, by kind
+    (:class:`~raft_tpu.core.held.HeldByArray`: a dataset that is replaced
+    is a new array and a miss, and the operands of an array are freed with
+    it)."""
+
+    def get(self, dataset, kind: str, *, hit: bool = True):
+        """The operand of ``kind`` held for ``dataset``, computed on a miss;
+        ``hit`` counts a found operand (a search does, a warm-up does not)."""
+        if not concrete(dataset):
+            return _row_operand(dataset, kind=kind)  # traced or host data
+        with self._lock:
+            entries = self._entries(dataset)
+            out = entries.get(kind)
+            if out is None:
+                if _metrics._enabled:
+                    _norms_total().inc(result="miss")
+                out = entries[kind] = _row_operand(dataset, kind=kind)
+            elif hit and _metrics._enabled:
+                _norms_total().inc(result="hit")
+            return out
+
+
+_NORMS = _NormCache()
+
+
+def _rows_of(dataset, index):
+    """``kind -> row operand`` for a fused search of ``dataset``: the
+    operand ``index`` holds when ``dataset`` is its dataset array, else
+    computed for this call."""
+    if index is not None and dataset is index.dataset:
+        return functools.partial(_NORMS.get, dataset)
+    return functools.partial(_row_operand, dataset)
 
 
 @functools.partial(
@@ -218,8 +294,18 @@ def knn(dataset, queries, k: int, metric="sqeuclidean", metric_arg: float = 2.0,
     On TPU, L2/inner-product/cosine searches with k ≤ 64, n ≥ 4096 and
     64 ≤ d ≤ 4096 dispatch to the fused Pallas kernel (ops/fused_knn.py;
     smaller d would mostly multiply 128-lane padding) — same neighbor sets;
-    within-1-ULP distance ties may order differently.
+    within-1-ULP distance ties may order differently. The kernel's row
+    norms are computed per call here; an index holds them
+    (:class:`BruteForce`, :func:`search`).
     Returns (distances (m, k), indices (m, k))."""
+    return _knn(dataset, queries, k, metric, metric_arg, sample_filter, mode,
+                compute, res)
+
+
+def _knn(dataset, queries, k, metric, metric_arg, sample_filter, mode,
+         compute, res, index=None):
+    """:func:`knn`, reading the row norms ``index`` holds when
+    ``dataset`` is its dataset array."""
     from .sample_filter import resolve_filter
 
     res = res or default_resources()
@@ -261,13 +347,15 @@ def knn(dataset, queries, k: int, metric="sqeuclidean", metric_arg: float = 2.0,
                     and (mt in _FUSED_L2 or mt == DistanceType.InnerProduct)
                     and fused_backend_ok()[0]
                     and shapes_eligible(n, dataset.shape[1], int(k))):
-                return _bf_knn_s8(dataset, queries, int(k), mt, keep_mask)
+                return _bf_knn_s8(dataset, queries, int(k), mt, keep_mask,
+                                  _rows_of(dataset, index))
         dataset = dataset.astype(jnp.float32)
         queries = queries.astype(jnp.float32)
     if compute == "int8":
         compute = "float32"  # explicit int8 on a non-integer/fallback path
     if _fused_eligible(mt, int(k), n, dataset.shape[1], mode, compute):
-        return _bf_knn_fused(dataset, queries, int(k), mt, compute, keep_mask)
+        return _bf_knn_fused(dataset, queries, int(k), mt, compute, keep_mask,
+                             _rows_of(dataset, index))
     if compute == "float32x3":
         compute = "float32"  # XLA fallback has no compensated mode
     # outer tile bounds the (tile, n) score block; inner tile bounds the
@@ -284,6 +372,25 @@ def knn(dataset, queries, k: int, metric="sqeuclidean", metric_arg: float = 2.0,
             inner_tile * n * (dataset.shape[1] + 2) * 4))
     return _bf_knn(dataset, queries, int(k), mt, float(metric_arg), tile, inner_tile,
                    keep_mask, approx=mode == "approx", compute=compute)
+
+
+@instrument(
+    "brute_force.search",
+    items=lambda a, kw: nrows(a[2] if len(a) > 2 else kw["queries"]),
+    labels=lambda a, kw: {"k": a[3] if len(a) > 3 else kw["k"]},
+)
+@auto_convert_output
+def search(params, index: "BruteForce", queries, k: int, sample_filter=None,
+           res: Resources | None = None):
+    """Exact kNN over a built index (reference: brute_force::search over a
+    brute_force::index), reading the row norms the index holds
+    (:meth:`BruteForce.hold_norms`): on the fused path the search is one
+    program. ``params`` must be None (brute force has no search knobs; the
+    argument keeps the other index kinds' signature)."""
+    expects(index.dataset is not None, "index is not built")
+    expects(params is None, "brute_force has no search params")
+    return _knn(index.dataset, queries, k, index.metric, index.metric_arg,
+                sample_filter, "exact", "float32", res, index=index)
 
 
 def knn_merge_parts(part_dists, part_ids, k: int | None = None, select_min: bool = True):
@@ -307,8 +414,12 @@ def knn_merge_parts(part_dists, part_ids, k: int | None = None, select_min: bool
 
 class BruteForce:
     """Index-style wrapper (reference: brute_force::index,
-    neighbors/brute_force_types.hpp — stores the dataset and optional
-    precomputed norms)."""
+    neighbors/brute_force_types.hpp — stores the dataset and its
+    precomputed norms). The norms (an L2 metric's ``|y|^2``, or a uint8
+    inner product's row bias; nothing for other metrics) are computed once
+    per dataset array, in a program of their own, at build and load, and
+    held with that array (:class:`_NormCache`): a dataset replaced in place
+    is a new array, whose norms the next search computes."""
 
     def __init__(self, metric="sqeuclidean", metric_arg: float = 2.0):
         self.metric = metric
@@ -347,16 +458,27 @@ class BruteForce:
             self.dataset = chunked.device_materialize(dataset,
                                                       kind="brute_force")
         else:
-            need = arr.shape[0] * arr.shape[1] * min(arr.dtype.itemsize, 4)
+            # the dataset, plus 4 bytes a row for its held norms
+            n, d = arr.shape
+            need = n * (d * min(arr.dtype.itemsize, 4) + 4)
             obs_mem.gate(res, need, site="build",
                          detail=f"brute_force {arr.shape[0]}x{arr.shape[1]}")
             self.dataset = jnp.asarray(dataset)
+        self.hold_norms()
         obs_mem.account_index(self)  # ledger hook (docs/observability.md)
         return self
 
-    def search(self, queries, k: int, res: Resources | None = None):
-        expects(self.dataset is not None, "index is not built")
-        return knn(self.dataset, queries, k, self.metric, self.metric_arg, res=res)
+    def hold_norms(self):
+        """The row norms held for the current dataset, computed if it has
+        none (None when the metric reads none)."""
+        kind = _operand_kind(self.metric, self.dataset.dtype)
+        return None if kind is None else _NORMS.get(self.dataset, kind,
+                                                    hit=False)
+
+    def search(self, queries, k: int, sample_filter=None,
+               res: Resources | None = None):
+        return search(None, self, queries, k, sample_filter=sample_filter,
+                      res=res)
 
 
 def write_index(f, index: BruteForce) -> None:
@@ -389,6 +511,7 @@ def read_index(f) -> BruteForce:
     idx = BruteForce(metric=metric, metric_arg=metric_arg)
     idx.dataset = jnp.asarray(deserialize_mdspan(f))
     idx.tuned = deserialize_tuned(f, ver)
+    idx.hold_norms()
     return idx
 
 

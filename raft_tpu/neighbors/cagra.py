@@ -27,12 +27,9 @@ from __future__ import annotations
 
 from ..config import auto_convert_output
 
-import collections
 import dataclasses
 import functools
-import threading
 import time
-import weakref
 from typing import NamedTuple
 
 import jax
@@ -42,6 +39,7 @@ from jax import lax
 
 from ..core import tracing
 from ..core.errors import expects
+from ..core.held import HeldByArray, concrete
 from ..core.logger import logger
 from ..core.resources import Resources, default_resources
 from ..core.serialize import (check_header, deserialize_mdspan, deserialize_scalar,
@@ -711,46 +709,28 @@ def _seed_constants_total():
         "(labels: result=hit|miss)")
 
 
-class _SeedCache:
-    """Seed constants per index dataset, keyed by ``(seed, pool, n_init)``.
-    The dataset is held weakly: an index dropped in a compaction swap frees
-    its constants with it, and a new dataset never finds another's norms.
-    Each dataset keeps its ``PER_DATASET`` most recently used entries."""
+class _SeedCache(HeldByArray):
+    """Seed constants per index dataset, keyed by ``(seed, pool, n_init)``
+    (:class:`~raft_tpu.core.held.HeldByArray`: the dataset is held weakly,
+    so an index dropped in a compaction swap frees its constants with it,
+    and a new dataset never finds another's norms). Each dataset keeps its
+    ``PER_DATASET`` most recently used entries."""
 
     PER_DATASET = 4
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._by_data: dict[int, tuple] = {}  # id -> (weakref, OrderedDict)
-
-    def _forget(self, ident: int):
-        def drop(ref):
-            # runs as the dataset is freed, before its id can be reused
-            slot = self._by_data.get(ident)
-            if slot is not None and slot[0] is ref:
-                self._by_data.pop(ident, None)
-
-        return drop
 
     def get(self, dataset, seed, pool: int, n_init: int) -> SeedConstants:
         # an int seed names its key; any other seed is read back as its
         # key's bytes (an RngState advances once per search, as it did
         # when every search drew its own pool)
         key = None if isinstance(seed, int) else as_key(seed)
-        if not (_concrete(dataset) and (key is None or _concrete(key))):
+        if not (concrete(dataset) and (key is None or concrete(key))):
             return self._compute(dataset, seed if key is None else key,
                                  pool, n_init)  # traced or host data
         tag = (seed if key is None else
                np.asarray(jax.random.key_data(key)).tobytes())
         entry = (tag, pool, n_init)
         with self._lock:
-            ident = id(dataset)
-            slot = self._by_data.get(ident)
-            if slot is None or slot[0]() is not dataset:
-                slot = (weakref.ref(dataset, self._forget(ident)),
-                        collections.OrderedDict())
-                self._by_data[ident] = slot
-            entries = slot[1]
+            entries = self._entries(dataset)
             out = entries.get(entry)
             if out is not None:
                 entries.move_to_end(entry)
@@ -769,12 +749,6 @@ class _SeedCache:
             _seed_constants_total().inc(result="miss")
         return _seed_constants_jit(dataset, as_key(seed), pool=pool,
                                    n_init=n_init)
-
-
-def _concrete(x) -> bool:
-    """A device array with values (not a tracer, not a host array that
-    could be written in place)."""
-    return isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
 
 
 _SEEDS = _SeedCache()

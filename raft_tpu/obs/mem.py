@@ -454,8 +454,10 @@ def _index_kind_and_leaves(index):
     from ..neighbors import brute_force, cagra, ivf_flat, ivf_pq
 
     if isinstance(index, brute_force.BruteForce):
-        return "brute_force", ([] if index.dataset is None
-                               else [index.dataset])
+        # the dataset and the row norms held with it
+        return "brute_force", ([] if index.dataset is None else
+                               [index.dataset,
+                                *brute_force._NORMS.held(index.dataset)])
     for kind, cls in (("ivf_flat", ivf_flat.IvfFlatIndex),
                       ("ivf_pq", ivf_pq.IvfPqIndex),
                       ("cagra", cagra.CagraIndex)):
@@ -605,7 +607,8 @@ def plan(kind: str, params=None, rows: int = 0, dim: int = 0, *,
 
     if kind == "brute_force":
         bk["dataset"] = rows * dim * item
-        build_peak = bk["dataset"] + (f32_copy if item != 4 else 0)
+        bk["norms"] = rows * 4  # held for L2 metrics (the default)
+        build_peak = sum(bk.values()) + (f32_copy if item != 4 else 0)
     elif kind == "ivf_flat":
         from ..neighbors import ivf_flat
 

@@ -18,10 +18,17 @@ only while its row-maximum still beats the running k-th best (``tau``),
 which skips most extraction work once the running top-k tightens after the
 first few blocks.  Running top-k state lives in VMEM scratch that persists
 across the dataset-block walk; only the final (QT, k) values and indices
-leave the chip.  Bounds padding and sample-filter masks are folded into the
-norms operand (one fused subtract) instead of iota/compare/select passes,
-and bf16-mode operands are cast OUTSIDE the kernel (half the DMA bytes, no
-per-block VPU cast).
+leave the chip.  Sample-filter masks and the padded tail of the norms operand
+are folded into that operand (one fused subtract) instead of
+iota/compare/select passes, and bf16-mode operands are cast OUTSIDE the
+kernel (half the DMA bytes, no per-block VPU cast).  A corpus whose feature
+dim is a whole number of 128-lane rows is read unpadded: the grid walks
+``cdiv(n, NBLK)`` blocks and the final block, which overhangs the corpus,
+forces its out-of-range columns out inside the kernel, whatever its rows
+hold. Other feature dims are zero-padded, with the row count, per call.
+The wrapper is one program: the keep-mask fold, the kernel, the query norms
+and the distances. The corpus norms come from the caller (an index holds
+them: neighbors/brute_force.BruteForce), or the program computes them.
 
 Profiling notes (v5e, 100k x 128, k=10, 10k-query batches; details and
 QPS-with-controls in r03 fused-kernel engineering notes):
@@ -148,7 +155,9 @@ def _scores(q, y, mode):
                                preferred_element_type=jnp.float32)
 
 
-def _make_kernel(k, nblk, qt, mode, l2):
+def _make_kernel(k, nblk, qt, mode, l2, n_valid):
+    """``n_valid``: the corpus rows when the final dataset block overhangs
+    them (an unpadded corpus with ``n % nblk != 0``), else None."""
     def kernel(q_ref, y_ref, yn_ref, out_v_ref, out_i_ref,
                run_v, run_i, s_ref, cand_v, cand_i, go_ref):
         j = pl.program_id(1)
@@ -166,9 +175,16 @@ def _make_kernel(k, nblk, qt, mode, l2):
         # block to per-128-lane maxima measured 5x SLOWER: every (qt, nseg)
         # narrow-lane intermediate costs a vreg relayout on TPU; all hot ops
         # here deliberately stay (qt, nblk)-wide.)
-        s = (2.0 * dots if l2 else dots) - yn_ref[:]
-        s_ref[:] = s
+        s_ref[:] = (2.0 * dots if l2 else dots) - yn_ref[:]
         cols = jax.lax.broadcasted_iota(jnp.int32, (qt, nblk), 1) + j * nblk
+
+        if n_valid is not None:
+            @pl.when(j == nb - 1)
+            def _tail():
+                # the rows past the corpus hold whatever the block buffer
+                # held (NaN included): their scores, not the penalty, are
+                # forced out, on this block only
+                s_ref[:] = jnp.where(cols < n_valid, s_ref[:], _NEG)
 
         tau = run_v[:, k - 1:k]
         go_ref[0] = 1
@@ -207,21 +223,33 @@ def _make_kernel(k, nblk, qt, mode, l2):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "l2", "mode", "qt", "nblk", "interpret"))
+    static_argnames=("k", "l2", "mode", "qt", "nblk", "interpret", "sqrt"))
 def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
-                    interpret):
+                    interpret, sqrt=False):
+    """The whole search as one program: ``yn`` (n,) f32 is the row operand
+    the scores subtract (``|y|^2`` for L2, plus any row bias; None: the L2
+    norms are computed here, nothing for ip); ``keep`` (n,) or None the
+    sample filter. Returns the distances and ids of :func:`fused_knn`."""
     n, d = dataset.shape
+    if yn is None and l2:
+        with tracing.scope("brute_force.norms"):
+            yn = jnp.sum(dataset.astype(jnp.float32) ** 2, axis=1)
     m = queries.shape[0]
     n_pad = -(-n // nblk) * nblk
     m_pad = -(-m // qt) * qt
     d_pad = -(-d // 128) * 128
+    # a lane-aligned corpus is read as it is: the kernel's final block
+    # overhangs it and masks the overhang; any other corpus is padded here
+    n_rows = n if d_pad == d else n_pad
     # bf16 mode: cast once here, outside the kernel — the per-block VPU cast
     # inside the kernel was costing more than the narrower MXU pass saved
     # (measured bf16 SLOWER than f32 with in-kernel casts), and bf16 operands
     # also halve the per-step DMA bytes
     io_t = {"bf16": jnp.bfloat16, "s8": jnp.int8}.get(mode, jnp.float32)
     with tracing.scope("fused_knn.pad"):
-        ds = jnp.pad(dataset.astype(io_t), ((0, n_pad - n), (0, d_pad - d)))
+        ds = dataset.astype(io_t)
+        if (n_rows, d_pad) != (n, d):
+            ds = jnp.pad(ds, ((0, n_rows - n), (0, d_pad - d)))
         qs = jnp.pad(queries.astype(io_t), ((0, m_pad - m), (0, d_pad - d)))
         base = yn if yn is not None else jnp.zeros((n,), jnp.float32)
         if keep is not None:
@@ -229,13 +257,15 @@ def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
             # with |y|^2 beyond ~4e37, and an inf norm turns the kernel's
             # masked arithmetic into NaN — pin filtered rows at the finite
             # sentinel so masking stays magnitude-independent
-            base = jnp.minimum(base + jnp.where(keep, 0.0, _MASK_PENALTY),
-                               _MASK_PENALTY)
+            base = jnp.minimum(
+                base + jnp.where(keep.astype(bool), 0.0, _MASK_PENALTY),
+                _MASK_PENALTY)
         ynp = jnp.pad(base, (0, n_pad - n),
                       constant_values=_MASK_PENALTY).reshape(1, n_pad)
     grid = (m_pad // qt, n_pad // nblk)
 
-    kern = _make_kernel(k, nblk, qt, mode, l2)
+    kern = _make_kernel(k, nblk, qt, mode, l2,
+                        n if n_rows % nblk else None)
     out_v, out_i = pl.pallas_call(
         kern,
         grid=grid,
@@ -265,11 +295,21 @@ def _fused_knn_impl(dataset, queries, yn, keep, k, l2, mode, qt, nblk,
         interpret=interpret,
         name="fused_knn",
     )(qs, ds, ynp)
-    return out_v[:m], out_i[:m]
+    out_v, out_i = out_v[:m], out_i[:m]
+    empty = out_v <= _NEG / 2
+    out_i = jnp.where(empty, -1, out_i)
+    if not l2:
+        return jnp.where(empty, -jnp.inf, out_v), out_i  # larger = closer
+    qn = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1, keepdims=True)
+    dist = jnp.maximum(qn - out_v, 0.0)
+    if sqrt:
+        dist = jnp.sqrt(dist)
+    return jnp.where(empty, jnp.inf, dist), out_i
 
 
 def fused_knn(dataset, queries, k, *, metric="l2", mode="f32", keep_mask=None,
-              sqrt=False, row_bias=None, qt=128, nblk=4096, interpret=False):
+              sqrt=False, row_operand=None, qt=128, nblk=4096,
+              interpret=False):
     """Exact brute-force kNN via the fused Pallas kernel.
 
     ``metric``: "l2" (squared euclidean; ``sqrt=True`` for euclidean) or
@@ -278,9 +318,11 @@ def fused_knn(dataset, queries, k, *, metric="l2", mode="f32", keep_mask=None,
     inputs (the caller normalizes, as distance/pairwise._cosine does).
 
     ``mode="s8"`` requires int8 inputs (uint8 callers shift by -128 first —
-    L2 is shift-invariant; see brute_force._as_signed). ``row_bias`` (n,)
-    f32 is subtracted from every row's score before selection — the hook ip
-    callers use to restore uint8 inner products from shifted operands
+    L2 is shift-invariant; see brute_force._as_signed). ``row_operand`` (n,)
+    f32 is what every row's score subtracts: ``|y|^2`` for l2 (an index
+    passes the norms it holds; None computes them in the search program)
+    and nothing for ip (None), plus any row bias — the hook ip callers use
+    to restore uint8 inner products from shifted operands
     (q·v = q'·v' + 128·Σv' + const(q), where the Σv' term is the row bias
     with sign flipped).
 
@@ -303,32 +345,12 @@ def fused_knn(dataset, queries, k, *, metric="l2", mode="f32", keep_mask=None,
                 "mode='s8' requires int8 operands (shift uint8 by -128 "
                 "first), got %s/%s", dataset.dtype, queries.dtype)
     l2 = metric == "l2"
-    # device stage brute_force.norms. Called eagerly, these are programs of
-    # their own (jit_integer_pow, jit__reduce_sum) that carry no scope;
-    # benchmark/stages.py maps them to the stage by program name
-    with tracing.scope("brute_force.norms"):
-        yn = (jnp.sum(dataset.astype(jnp.float32) ** 2, axis=1) if l2
-              else None)
-    if row_bias is not None:
-        rb = jnp.asarray(row_bias, jnp.float32)
-        expects(rb.shape == (n,), "row_bias must be (n,)")
-        yn = rb if yn is None else yn + rb
-    keep = None if keep_mask is None else jnp.asarray(keep_mask).astype(bool)
+    expects(row_operand is None or row_operand.shape == (n,),
+            "row_operand must be (n,)")
     # shrink the dataset block if the feature dim would blow the VMEM budget
     # (in whole 128-lane segments so the invariant above survives the shrink)
     while nblk > 512 and (qt + nblk) * max(d, 128) * 4 + qt * nblk * 4 > 24 * 2**20:
         nblk = (nblk // 2 // 128) * 128
-    out_v, out_i = _fused_knn_impl(dataset, queries, yn, keep, int(k),
-                                   l2, mode, qt, nblk, interpret)
-    empty = out_v <= _NEG / 2
-    out_i = jnp.where(empty, -1, out_i)
-    if l2:
-        qn = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1, keepdims=True)
-        dist = jnp.maximum(qn - out_v, 0.0)
-        if sqrt:
-            dist = jnp.sqrt(dist)
-        dist = jnp.where(empty, jnp.inf, dist)
-    else:
-        dist = out_v                                  # similarity, larger=closer
-        dist = jnp.where(empty, -jnp.inf, dist)
-    return dist, out_i
+    keep = None if keep_mask is None else jnp.asarray(keep_mask)
+    return _fused_knn_impl(dataset, queries, row_operand, keep, int(k),
+                           l2, mode, qt, nblk, interpret, sqrt=bool(sqrt))

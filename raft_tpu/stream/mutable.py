@@ -229,17 +229,12 @@ def _recover_store(kind, sealed, data_kind):
 def _sealed_search(cfg, view, queries, k, res=None):
     """Search the sealed side through its published view (see
     :func:`_publish_sealed`): ``(live list view, None)`` for IVF kinds,
-    ``(sealed, slot keep-mask)`` otherwise."""
-    from ..neighbors import brute_force
-
+    ``(sealed, slot keep-mask)`` otherwise. Each kind's module
+    ``search`` reads what its index holds (brute force: its row norms)."""
     sealed, keep_mask = view
     if metrics._enabled:
         _c_sealed_filter().inc(1, name=cfg.name,
                                layout="list" if keep_mask is None else "ids")
-    if cfg.kind == "brute_force":
-        return brute_force.knn(sealed.dataset, queries, k, cfg.metric,
-                               cfg.metric_arg, sample_filter=keep_mask,
-                               res=res)
     return cfg.module.search(cfg.search_params, sealed, queries, k,
                              sample_filter=keep_mask, res=res)
 
@@ -601,8 +596,10 @@ class MutableIndex:
 
             if kind == "brute_force":
                 # BruteForce is not a pytree — move its dataset in place
-                # (the wrap takes ownership of the sealed index anyway)
+                # (the wrap takes ownership of the sealed index anyway);
+                # the moved array holds norms of its own
                 sealed.dataset = jax.device_put(sealed.dataset, device)
+                sealed.hold_norms()
             else:
                 sealed = jax.device_put(sealed, device)
         if kind in ("ivf_flat", "ivf_pq"):
@@ -1491,6 +1488,7 @@ class MutableIndex:
                     if cfg.kind == "brute_force":
                         new_sealed.dataset = jax.device_put(
                             new_sealed.dataset, cfg.device)
+                        new_sealed.hold_norms()
                     else:
                         new_sealed = jax.device_put(new_sealed, cfg.device)
             # materialize before the swap (BruteForce is not a pytree —

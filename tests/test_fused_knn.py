@@ -207,3 +207,52 @@ def test_compute_modes_recall(data):
         di = np.asarray(di)
         overlap = np.mean([len(set(di[r]) & set(ri[r])) / K for r in range(M)])
         assert overlap > (0.999 if mode == "f32x3" else 0.95), (mode, overlap)
+
+
+# -- an unpadded corpus: the ragged final block --------------------------------
+
+_RAGGED_N = [(3 * 512 + 1, 512), (3 * 512 + 127, 512), (4 * 512, 512),
+             (5000, 8192)]
+_RAGGED_CASES = (
+    [(n, nblk, keep, "nan") for n, nblk in _RAGGED_N
+     for keep in ("none", "half", "under_k")]
+    + [(3 * 512 + 1, 512, "none", "huge"), (5000, 8192, "half", "huge")])
+
+
+@pytest.mark.parametrize("n,nblk,keep,fill", _RAGGED_CASES)
+def test_ragged_final_block(monkeypatch, n, nblk, keep, fill):
+    """With d a multiple of 128 the kernel reads the corpus unpadded over
+    ``cdiv(n, nblk)`` blocks. The interpreter fills the final block's rows
+    past the corpus with NaN ("nan"), or here with 1e37 ("huge": scores
+    that overflow to +inf and would win every slot unmasked); either way no
+    such row may reach the answer, and the answer is the XLA path's."""
+    import jax
+    from jax._src.pallas import primitives
+
+    if fill == "huge":
+        real = primitives.uninitialized_value
+        monkeypatch.setattr(
+            primitives, "uninitialized_value",
+            lambda shape, dtype: (jnp.full(shape, 1e37, dtype)
+                                  if jnp.issubdtype(dtype, jnp.floating)
+                                  else real(shape, dtype)))
+        jax.clear_caches()  # no program traced under the other fill
+    assert np.isnan(primitives.uninitialized_value((), jnp.float32)) == (
+        fill == "nan")
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.random((n, 128), np.float32))
+    q = jnp.asarray(rng.random((24, 128), np.float32))
+    mask = {"none": None, "half": rng.random(n) < 0.5,
+            "under_k": np.arange(n) >= n - 4}[keep]
+    mask = None if mask is None else jnp.asarray(mask)
+    dv, di = fused_knn(x, q, K, metric="l2", keep_mask=mask, nblk=nblk,
+                       interpret=True)
+    if fill == "huge":
+        jax.clear_caches()
+    rd, ri = _bf_knn(x, q, K, DistanceType.L2Expanded, 2.0, 24, 24, mask)
+    di = np.asarray(di)
+    assert ((di >= 0) & (di < n) | (di == -1)).all()
+    if keep == "under_k":
+        assert (di[:, 4:] == -1).all() and np.isinf(np.asarray(dv)[:, 4:]).all()
+        assert all(set(r[:4]) == set(range(n - 4, n)) for r in di)
+    assert_knn_equiv(dv, di, rd, ri)

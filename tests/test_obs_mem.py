@@ -472,10 +472,11 @@ def test_plan_breakdown_and_unknown_kind():
     from raft_tpu.core.errors import RaftError
 
     p = obs_mem.plan("brute_force", None, 1000, 32)
-    assert p["index_bytes"] == 1000 * 32 * 4 == p["breakdown"]["dataset"]
+    assert p["breakdown"] == {"dataset": 1000 * 32 * 4, "norms": 1000 * 4}
+    assert p["index_bytes"] == 1000 * 32 * 4 + 1000 * 4
     assert p["build_peak_bytes"] >= p["index_bytes"]
     assert obs_mem.plan("brute_force", None, 1000, 32,
-                        dtype="int8")["index_bytes"] == 1000 * 32
+                        dtype="int8")["index_bytes"] == 1000 * 32 + 1000 * 4
     with pytest.raises(RaftError):
         obs_mem.plan("nope", None, 10, 10)
 
@@ -653,12 +654,13 @@ class TestBudgetGate:
     def test_brute_force_gate_sizes_from_host_view(self, rng):
         """Review regression: the brute-force build gate prices the f32
         STORED bytes from the host view (before any device upload) — an
-        f64 numpy input must not double the gate's ask."""
+        f64 numpy input must not double the gate's ask — plus the 4 bytes
+        a row of the norms the index holds."""
         from raft_tpu.neighbors import brute_force
 
         x64 = rng.random((256, 8))  # float64 host array
         used = obs_mem.totals()["device_bytes"]
-        need_f32 = 256 * 8 * 4
+        need_f32 = 256 * 8 * 4 + 256 * 4
         idx = brute_force.BruteForce().build(
             x64, res=Resources(memory_budget_bytes=used + need_f32))
         assert str(idx.dataset.dtype) == "float32"
