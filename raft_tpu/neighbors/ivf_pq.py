@@ -76,6 +76,15 @@ def _quant_funnel_total():
 
 
 @functools.lru_cache(maxsize=None)
+def _scan_plan_total():
+    return metrics.counter(
+        "raft_tpu_ivf_pq_scan_plan_total",
+        "ivf_pq.search dispatches by scan plan: n_probes / probe_chunk "
+        "sequential scan steps per query tile (labels: query_tile, "
+        "probe_chunk)")
+
+
+@functools.lru_cache(maxsize=None)
 def _quant_bytes_per_row():
     return metrics.gauge(
         "raft_tpu_quant_scan_bytes_per_row",
@@ -992,10 +1001,12 @@ def build(params: IndexParams, dataset, res: Resources | None = None) -> IvfPqIn
     n_train = min(max_train, n)
     key, ks = jax.random.split(key)
     if n_train < n:
-        train_idx = jax.random.choice(ks, n, (n_train,), replace=False)
-        # take_rows: jnp.take in-core, a host page-gather off the reader
-        # streamed — SAME indices, bit-equal rows (core/chunked docstring)
-        xt = chunked.take_rows(x, train_idx)
+        with tracing.range("ivf_pq.build.trainset"):
+            train_idx = jax.random.choice(ks, n, (n_train,), replace=False)
+            # take_rows: jnp.take in-core, a host page-gather off the
+            # reader streamed — SAME indices, bit-equal rows
+            # (core/chunked docstring)
+            xt = chunked.take_rows(x, train_idx)
     else:
         xt = chunked.materialize(x) if stream else x
     tile = _choose_tile(n_train, params.n_lists, 1, res.workspace_bytes)
@@ -1407,26 +1418,27 @@ def _extend_stream_f32(index: IvfPqIndex, reader, new_ids=None,
         # capacity policy over the FULL label vector — identical to the
         # in-core call (ivf_pq never spatial-splits: sub-lists must share
         # their parent's center for the codes to stay valid)
-        sf = index.split_factor if split_factor is None else split_factor
-        labels, rep, n_lists2, capacity, _ = bound_capacity(
-            labels, index.n_lists, sf)
-        centers, centers_rot = index.centers, index.centers_rot
-        codebooks = index.codebooks
-        list_scales, sig_scales = index.list_scales, index.sig_scales
-        if rep is not None:
-            centers = jnp.asarray(np.repeat(np.asarray(centers), rep,
-                                            axis=0))
-            centers_rot = jnp.asarray(np.repeat(np.asarray(centers_rot),
-                                                rep, axis=0))
-            if index.codebook_kind == "per_cluster":
-                codebooks = jnp.asarray(np.repeat(np.asarray(codebooks),
-                                                  rep, axis=0))
-            if index.scale_normed:
-                list_scales = jnp.asarray(
-                    np.repeat(np.asarray(list_scales), rep, axis=0))
-            if index.has_fast_scan:
-                sig_scales = jnp.asarray(
-                    np.repeat(np.asarray(sig_scales), rep, axis=0))
+        with tracing.range("ivf_pq.extend.capacity_stream"):
+            sf = index.split_factor if split_factor is None else split_factor
+            labels, rep, n_lists2, capacity, _ = bound_capacity(
+                labels, index.n_lists, sf)
+            centers, centers_rot = index.centers, index.centers_rot
+            codebooks = index.codebooks
+            list_scales, sig_scales = index.list_scales, index.sig_scales
+            if rep is not None:
+                centers = jnp.asarray(np.repeat(np.asarray(centers), rep,
+                                                axis=0))
+                centers_rot = jnp.asarray(np.repeat(np.asarray(centers_rot),
+                                                    rep, axis=0))
+                if index.codebook_kind == "per_cluster":
+                    codebooks = jnp.asarray(np.repeat(np.asarray(codebooks),
+                                                      rep, axis=0))
+                if index.scale_normed:
+                    list_scales = jnp.asarray(
+                        np.repeat(np.asarray(list_scales), rep, axis=0))
+                if index.has_fast_scan:
+                    sig_scales = jnp.asarray(
+                        np.repeat(np.asarray(sig_scales), rep, axis=0))
 
         # ---- pass B: per-chunk residual -> encode -> scatter ----------
         # Gathers run against the REPEATED arrays with POST-split labels:
@@ -1951,6 +1963,9 @@ def search(params: SearchParams, index: IvfPqIndex, queries, k: int,
         budget_bytes=res.workspace_bytes,
         max_query_tile=128,
     )
+    if metrics.enabled():
+        _scan_plan_total().inc(1, query_tile=query_tile,
+                               probe_chunk=probe_chunk)
 
     keep_mask = resolve_filter(sample_filter)
     if keep_mask is not None:

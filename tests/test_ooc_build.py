@@ -131,6 +131,34 @@ def test_npy_memmap_parity_ivf_pq(rng, tmp_path):
     _assert_bit_equal(incore, streamed, "(ivf_pq .npy vs in-core)")
 
 
+def test_u8bin_memmap_parity_ivf_pq_uint8(rng, tmp_path):
+    """The IVF-PQ parity contract for a byte corpus read from a raw
+    ``.u8bin``-style file, as a BIGANN deployment builds: the s8 shift and
+    f32 upcast per chunk, the trainset gather (a 10% trainset, so it runs)
+    and the per-chunk encode and fill give the in-core build's centres,
+    codebooks, codes and ids, bit for bit."""
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors import ivf_pq
+
+    n, d = 20_000, 32
+    centers = rng.uniform(30, 225, (64, d))
+    data = np.clip(np.rint(centers[rng.integers(0, 64, n)]
+                           + 10.0 * rng.standard_normal((n, d))),
+                   0, 255).astype(np.uint8)
+    raw = tmp_path / "base.u8bin"
+    data.tofile(raw)
+
+    params = ivf_pq.IndexParams(n_lists=64, pq_dim=16,
+                                kmeans_trainset_fraction=0.1, seed=4)
+    incore = ivf_pq.build(params, jnp.asarray(data))
+    reader = chunked.ChunkedReader.from_file(
+        raw, dtype=np.uint8, shape=(n, d), chunk_rows=4096)
+    streamed = ivf_pq.build(params, reader)
+    assert incore.data_kind == streamed.data_kind == "uint8"
+    _assert_bit_equal(incore, streamed, "(ivf_pq uint8 .u8bin vs in-core)")
+
+
 def test_memmap_parity_brute_force_uint8(rng, tmp_path):
     """Dataset-resident kinds stream too: brute force materializes the
     reader chunk-by-chunk into ONE device array — bit-equal rows, and
